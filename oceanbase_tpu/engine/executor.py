@@ -72,6 +72,7 @@ from ..sql.logical import (
     Window,
     output_schema,
     setop_schema,
+    unique_key_sets,
     window_out_type,
 )
 
@@ -394,7 +395,9 @@ class Executor:
     def __init__(self, catalog, unique_keys=None, default_rows_estimate=1 << 16,
                  stats=None, device_budget=None, chunk_rows=None):
         self.catalog = catalog
-        self.unique_keys = unique_keys or {}
+        # kept by reference (not `or {}`): the server hands in its live,
+        # initially empty registry and fills it as tables are created
+        self.unique_keys = unique_keys if unique_keys is not None else {}
         self.default_rows_estimate = default_rows_estimate
         # share/stats.StatsManager: NDV/histogram-backed cardinalities for
         # static capacities (None = heuristic constants)
@@ -1309,9 +1312,8 @@ class Executor:
             # a routed sorted projection keeps the base table's rows (and
             # so its unique keys) under the '#sp:' name
             base = node.table.split("#sp:", 1)[0]
-            uks = tuple(self.unique_keys.get(node.table, ())) + tuple(
-                self.unique_keys.get(base, ())
-            )
+            uks = unique_key_sets(self.unique_keys, node.table) \
+                + unique_key_sets(self.unique_keys, base)
             key_cols = {
                 n.split(".", 1)[1] for n in names if n.startswith(node.alias + ".")
             }

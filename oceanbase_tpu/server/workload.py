@@ -44,6 +44,7 @@ import threading
 import time
 import weakref
 from bisect import bisect_left
+from collections import deque
 from dataclasses import dataclass, field
 
 from ..share.metrics import DEFAULT_BUCKETS, Histogram
@@ -318,11 +319,15 @@ class _SessionFold:
                 self._reg._merge_states(states)
 
     def __del__(self):
-        # a dropped session flushes its tail so no completed statement
-        # is ever lost (guarded: interpreter teardown order is arbitrary)
+        # A dropped session's tail must not be lost, but a finalizer runs
+        # inside whatever allocation tripped the collector — including one
+        # made while this very thread holds the registry or metrics lock
+        # (seen as a tier-1 hang: _merge_states -> GC -> __del__ -> flush
+        # -> _merge_states). So it takes no lock: it parks the
+        # accumulator, alive again, for flush_all to fold.
         try:
-            self.flush()
-        except Exception:  # noqa: BLE001
+            self._reg._orphans.append(self)
+        except Exception:  # noqa: BLE001 - interpreter teardown order
             pass
 
 
@@ -338,6 +343,9 @@ class StatementSummaryRegistry:
         self._lock = threading.Lock()
         self._map: dict[str, StatementSummary] = {}
         self._accs: list = []  # weakrefs to live _SessionFold
+        # accumulators whose session was collected with a tail unfolded
+        # (deque: append/popleft need no lock, see _SessionFold.__del__)
+        self._orphans: deque = deque()
         self._clock = clock
         self._metrics = metrics
         self._seq = 0
@@ -430,6 +438,8 @@ class StatementSummaryRegistry:
                 dead += 1
                 continue
             acc.flush()
+        while self._orphans:
+            self._orphans.popleft().flush()
         if dead:
             with self._lock:
                 self._accs = [r for r in self._accs if r() is not None]

@@ -137,6 +137,18 @@ class Window(LogicalOp):
     ]
 
 
+def unique_key_sets(unique_keys: dict, table: str) -> tuple:
+    """Every declared unique key of `table`, each a tuple of column
+    names. A catalog entry is either one key (``("a", "b")``, what the
+    server records for a primary key) or several (``(("a", "b"),
+    ("c",))``, what the TPC-H suite declares); planner and executor both
+    read it through here so neither spelling is silently ignored."""
+    uk = unique_keys.get(table) or ()
+    if uk and isinstance(uk[0], str):
+        return (tuple(uk),)
+    return tuple(tuple(k) for k in uk)
+
+
 def output_schema(op: LogicalOp) -> Schema:
     """Schema of an operator's output (qualified names)."""
     if isinstance(op, Scan):

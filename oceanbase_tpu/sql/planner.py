@@ -41,6 +41,7 @@ from .logical import (
     TopN,
     Window,
     output_schema,
+    unique_key_sets,
 )
 
 _sub_counter = itertools.count()
@@ -195,7 +196,9 @@ class Planner:
         # share/stats.StatsManager (None = heuristic-only estimates)
         self.stats = stats
         # table -> unique key column tuple (DISTINCT elimination)
-        self.unique_keys = unique_keys or {}
+        # kept by reference (not `or {}`): the server hands in its live,
+        # initially empty registry and fills it as tables are created
+        self.unique_keys = unique_keys if unique_keys is not None else {}
         self.ctes: dict[str, A.Select] = {}
         # plain views: name -> defining SELECT text (shared MUTABLE dict —
         # the server's DDL updates it in place). Expanded at plan time;
@@ -223,10 +226,9 @@ class Planner:
         while isinstance(node, Filter):
             node = node.child
         if isinstance(node, Scan):
-            uk = self.unique_keys.get(node.table)
-            if uk:
-                qual = {f"{node.alias}.{c}" for c in uk}
-                return qual <= srcs
+            return any(
+                {f"{node.alias}.{c}" for c in uk} <= srcs
+                for uk in unique_key_sets(self.unique_keys, node.table))
         return False
 
     # -- cardinality estimates (stats-backed with heuristic fallback) --
@@ -902,12 +904,13 @@ class Planner:
         if isinstance(op, JoinOp) and op.kind == "left":
             rnames = set(output_schema(op.right).names())
             if not (rnames & needed) and isinstance(op.right, Scan):
-                uk = self.unique_keys.get(op.right.table)
                 rk = {
                     k.name for k in op.right_keys if isinstance(k, E.ColRef)
                 }
-                if uk and {f"{op.right.alias}.{c}" for c in uk} == rk \
-                        and len(rk) == len(op.right_keys):
+                if len(rk) == len(op.right_keys) and any(
+                        {f"{op.right.alias}.{c}" for c in uk} == rk
+                        for uk in unique_key_sets(
+                            self.unique_keys, op.right.table)):
                     return self._eliminate_left_joins(op.left, needed)
         # whole-row operators consume every child column implicitly
         if isinstance(op, (Distinct, SetOp)):
@@ -1369,8 +1372,7 @@ class Planner:
                 n = ts.ndv_of(col)
                 if n:
                     return float(n)
-            uk = self.unique_keys.get(t)
-            if uk and tuple(uk) == (col,):
+            if (col,) in unique_key_sets(self.unique_keys, t):
                 return float(self.catalog[t].nrows or 1)
             return None
 
