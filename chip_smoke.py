@@ -1,0 +1,791 @@
+#!/usr/bin/env python3
+"""Chip smoke: the served path, once, on the device JAX gives this process.
+
+MySQL wire -> AsyncMySqlFrontend -> DbSession.sql -> plan cache -> one
+jitted device program -> result frame, at deployment size:
+
+  analytic       TPC-H (all eight tables, --sf, default 10) loaded with
+                 direct_load into DDL-created tables; Q6/Q1/Q14/Q3 against
+                 the plain numpy references, the other compiling queries
+                 once each.
+  transactional  kv table (1M x sf rows): distinct-key point reads, solo
+                 and concurrent (batcher buckets), then INSERT / UPDATE /
+                 DELETE / a two-table BEGIN..COMMIT, each acknowledged and
+                 read back from a second connection against a dict.
+  vector         (100k x sf) x 128 float32, IVF lists 1024 / nprobe 32 at
+                 full size; filtered and unfiltered kNN, recall@10 against
+                 brute-force numpy.
+  --chips 4      only this: TPC-H Q1/Q6/Q3 under `set ob_px_dop = 4` on
+                 the four-device mesh against the same statements at
+                 dop 0.
+
+The script never chooses a device. `ok` is true and the exit code 0 only
+when that device is a TPU and every phase passed; held to the CPU it is a
+rehearsal (needs an explicit --sf), reports "platform": "cpu" and exits 1.
+Every earlier stdout line is one JSON object (set-up seconds, per-statement
+smoke readings, counters); the last line is the verdict:
+
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Times printed here are smoke readings, not benchmark numbers.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import socket
+import struct
+import sys
+import threading
+import time
+import traceback
+
+import numpy as np
+
+DEFAULT_SF = {1: 10.0, 4: 3.0}
+K = 10  # kNN limit
+VEC_DIM = 128
+# statement-path degradations that must not be what made a phase pass
+ZERO_DELTA = (
+    "px fallbacks",
+    "stmt degraded chunked",
+    "stmt degraded host",
+    "device OOM retries",
+    "plan artifact prime error",
+)
+# TPC-H queries beyond the four headline ones that the no-chip compile
+# sweep (tools/compile_sweep.py, table in CHANGES.md PR 22) shows
+# compiling for v5e in under ~20 s at SF 0.01
+OTHER_QUERIES = (2, 4, 5, 7, 8, 10, 11, 12, 13, 16, 17, 18, 19, 20, 21, 22)
+
+
+def emit(obj: dict) -> None:
+    print(json.dumps(obj, default=float), flush=True)
+
+
+class WireError(RuntimeError):
+    pass
+
+
+class WireClient:
+    """Blocking MySQL protocol-41 client: login + COM_QUERY text results."""
+
+    def __init__(self, port: int):
+        self.sock = socket.create_connection(("127.0.0.1", port), timeout=600)
+        self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+        self._read()  # greeting
+        caps = 0x0200 | 0x8000  # PROTOCOL_41 | SECURE_CONNECTION
+        self._send(struct.pack("<IIB23x", caps, 1 << 24, 33)
+                   + b"root\x00" + b"\x00", seq=1)
+        if self._read()[0] != 0x00:
+            raise WireError("login refused")
+
+    def close(self) -> None:
+        self.sock.close()
+
+    def _read_n(self, n: int) -> bytes:
+        buf = bytearray()
+        while len(buf) < n:
+            c = self.sock.recv(n - len(buf))
+            if not c:
+                raise WireError("peer closed the connection")
+            buf += c
+        return bytes(buf)
+
+    def _read(self) -> bytes:
+        head = self._read_n(4)
+        return self._read_n(int.from_bytes(head[:3], "little"))
+
+    def _send(self, payload: bytes, seq: int = 0) -> None:
+        self.sock.sendall(
+            len(payload).to_bytes(3, "little") + bytes([seq]) + payload)
+
+    @staticmethod
+    def _lenenc(buf: bytes, pos: int) -> tuple[int, int]:
+        f = buf[pos]
+        if f < 251:
+            return f, pos + 1
+        width = {0xFC: 2, 0xFD: 3, 0xFE: 8}[f]
+        return (int.from_bytes(buf[pos + 1:pos + 1 + width], "little"),
+                pos + 1 + width)
+
+    def query(self, sql: str):
+        """Rows (tuples of str | None) for a result set, the affected-row
+        count for an OK packet; an ERR packet raises WireError."""
+        self._send(b"\x03" + sql.encode())
+        first = self._read()
+        if first[0] == 0xFF:
+            code = int.from_bytes(first[1:3], "little")
+            raise WireError(
+                f"ERR {code}: {first[9:].decode(errors='replace')} "
+                f"<- {sql[:120]!r}")
+        if first[0] == 0x00:
+            return self._lenenc(first, 1)[0]
+        ncols = self._lenenc(first, 0)[0]
+        for _ in range(ncols):
+            self._read()  # column definitions
+        self._read()  # EOF
+        rows = []
+        while True:
+            pkt = self._read()
+            if pkt[0] == 0xFE and len(pkt) < 9:
+                return rows
+            pos, row = 0, []
+            for _ in range(ncols):
+                if pkt[pos] == 0xFB:
+                    row.append(None)
+                    pos += 1
+                else:
+                    ln, pos = self._lenenc(pkt, pos)
+                    row.append(pkt[pos:pos + ln].decode())
+                    pos += ln
+            rows.append(tuple(row))
+
+
+class CompileMeter:
+    """Backend (XLA) compiles of this process, as JAX's own monitoring
+    reports them: count and seconds, read as deltas around a statement."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self._lock = threading.Lock()
+        self.count = 0
+        self.seconds = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event: str, seconds: float, **_kw) -> None:
+        if event == self.EVENT:
+            with self._lock:
+                self.count += 1
+                self.seconds += seconds
+
+    def read(self) -> tuple[int, float]:
+        with self._lock:
+            return self.count, self.seconds
+
+
+class Ctx:
+    """What every phase shares: the database, its wire port, the scale."""
+
+    def __init__(self, db, port: int, sf: float, seed: int):
+        self.db = db
+        self.port = port
+        self.sf = sf
+        self.seed = seed
+        self.compiles = CompileMeter()
+        self._clients: list[WireClient] = []
+
+    def connect(self) -> WireClient:
+        c = WireClient(self.port)
+        self._clients.append(c)
+        return c
+
+    def close_clients(self) -> None:
+        for c in self._clients:
+            c.close()
+        self._clients.clear()
+
+    def timed(self, client: WireClient, sql: str):
+        """(result, reading): the statement over the wire plus what the
+        server's audit ring recorded for it."""
+        n0, s0 = self.compiles.read()
+        t0 = time.perf_counter()
+        out = client.query(sql)
+        wall = time.perf_counter() - t0
+        n1, s1 = self.compiles.read()
+        rec = next((r for r in reversed(self.db.audit.records())
+                    if r.sql == sql), None)
+        reading = {"wall_s": wall, "xla_compiles": n1 - n0,
+                   "xla_compile_s": s1 - s0}
+        if rec is not None:
+            reading.update(
+                h2d_bytes=rec.transfer_bytes,
+                plan_cache_hit=rec.plan_cache_hit,
+                fast_path=rec.is_fast_path, batched=rec.is_batched,
+                retries=rec.retry_cnt)
+        return out, reading
+
+
+def close_to(got, want, rel: float = 1e-9, abs_: float = 1e-6) -> bool:
+    return abs(float(got) - float(want)) <= abs_ + rel * abs(float(want))
+
+
+# ---------------------------------------------------------------- analytic
+
+def tpch_ddl(name: str) -> str:
+    """CREATE TABLE text for one TPC-H table, from the repo's schema (all
+    columns NOT NULL per the spec, primary key from the suite)."""
+    from oceanbase_tpu.models.tpch import schema as S
+    from oceanbase_tpu.models.tpch.sql_suite import UNIQUE_KEYS
+
+    cols = ", ".join(f"{f.name} {str(f.dtype).rstrip('?')} not null"
+                     for f in S.TABLES[name].fields)
+    pk = ", ".join(UNIQUE_KEYS[name][0])
+    return f"create table {name} ({cols}, primary key ({pk}))"
+
+
+def table_columns(tb) -> dict:
+    """A generated Table as direct_load input: VARCHAR columns as strings
+    (direct_load owns the table's dictionary), the rest as stored."""
+    from oceanbase_tpu.core.dtypes import TypeKind
+
+    out = {}
+    for f in tb.schema.fields:
+        a = tb.data[f.name]
+        if f.dtype.kind is TypeKind.VARCHAR:
+            a = np.asarray(tb.dicts[f.name].values())[a]
+        out[f.name] = a
+    return out
+
+
+def load_tpch(ctx: Ctx, client: WireClient, names, refs_fn) -> dict:
+    """Generate from --seed, take the plain references on the generated
+    arrays, then DDL over the wire + direct_load, table by table (each
+    generated table is dropped once loaded: host memory is the limit at
+    SF 10). Returns (refs, set-up seconds)."""
+    from oceanbase_tpu.models.tpch import datagen
+    from oceanbase_tpu.server.direct_load import direct_load
+
+    setup = {}
+    t0 = time.perf_counter()
+    tables = datagen.generate(ctx.sf, ctx.seed)
+    setup["datagen_s"] = time.perf_counter() - t0
+    rows = {n: int(tables[n].nrows) for n in names}
+    t0 = time.perf_counter()
+    refs = refs_fn(tables)
+    setup["references_s"] = time.perf_counter() - t0
+    load = {}
+    for name in names:
+        client.query(tpch_ddl(name))
+        t0 = time.perf_counter()
+        tb = tables.pop(name)
+        n = direct_load(ctx.db, name, table_columns(tb))
+        del tb
+        if n != rows[name]:
+            raise AssertionError(f"direct_load {name}: {n} != {rows[name]}")
+        load[name] = time.perf_counter() - t0
+    tables.clear()
+    setup["direct_load_s"] = load
+    # first touch over the wire: the catalog snapshot scan of each table
+    touch = {}
+    for name in names:
+        t0 = time.perf_counter()
+        got = int(client.query(f"select count(*) from {name}")[0][0])
+        touch[name] = time.perf_counter() - t0
+        if got != rows[name]:
+            raise AssertionError(f"count(*) {name}: {got} != {rows[name]}")
+    setup["first_touch_s"] = touch
+    emit({"setup": "tpch", "sf": ctx.sf, "rows": rows, **setup})
+    return refs
+
+
+def headline_refs(tables) -> dict:
+    from oceanbase_tpu.models.tpch.queries import (
+        q1_numpy_fast,
+        q3_cpu,
+        q6_numpy,
+        q14_cpu,
+    )
+
+    li = tables["lineitem"]
+    q1 = q1_numpy_fast(li)
+    rf, ls = li.dicts["l_returnflag"], li.dicts["l_linestatus"]
+    q1_rows = {}
+    for key in np.flatnonzero(q1["count"]):
+        q1_rows[(rf.decode_one(int(key) // len(ls)),
+                 ls.decode_one(int(key) % len(ls)))] = (
+            q1["sum_qty"][key] / 100, q1["sum_price"][key] / 100,
+            q1["sum_dp"][key] / 1e4, q1["sum_ch"][key] / 1e6,
+            int(q1["count"][key]))
+    return {
+        6: q6_numpy(li),
+        1: q1_rows,
+        14: q14_cpu(tables["part"], li),
+        3: q3_cpu(tables["customer"], tables["orders"], li),
+    }
+
+
+def check_headline(q: int, rows, ref) -> str | None:
+    """None when the wire rows equal the plain reference, else why not."""
+    if q == 6:
+        return None if close_to(rows[0][0], ref) else f"{rows} != {ref}"
+    if q == 14:
+        return (None if close_to(rows[0][0], ref, abs_=1e-6)
+                else f"{rows} != {ref}")
+    if q == 1:
+        if len(rows) != len(ref):
+            return f"{len(rows)} groups != {len(ref)}"
+        for r in rows:
+            want = ref.get((r[0], r[1]))
+            if want is None:
+                return f"unexpected group {r[:2]}"
+            got = (r[2], r[3], r[4], r[5], r[9])
+            if not all(close_to(g, w) for g, w in zip(got, want)):
+                return f"group {r[:2]}: {got} != {want}"
+        return None
+    if q == 3:
+        if len(rows) != len(ref):
+            return f"{len(rows)} rows != {len(ref)}"
+        for r, (okey, rev, _odate, prio) in zip(rows, ref):
+            if (int(r[0]) != okey or not close_to(r[1], rev, abs_=1e-2)
+                    or int(r[3]) != prio):
+                return f"{r} != {(okey, rev, prio)}"
+        return None
+    raise ValueError(q)
+
+
+def resident(ctx: Ctx, text: str) -> bool:
+    """The statement's cached plan is one whole-table device program,
+    not the chunk-streamed route the upload guard picks when it thinks
+    the inputs exceed the device budget."""
+    entry, _ = ctx.db.engine.cached_entry(text)
+    return entry is not None and type(entry.prepared).__name__ == "PreparedPlan"
+
+
+def phase_analytic(ctx: Ctx) -> dict:
+    from oceanbase_tpu.models.tpch import schema as S
+    from oceanbase_tpu.models.tpch.sql_suite import QUERIES
+
+    c = ctx.connect()
+    refs = load_tpch(ctx, c, list(S.TABLES), headline_refs)
+    bad = []
+    for q in (6, 1, 14, 3):
+        text = QUERIES[q]
+        readings = {}
+        # cold compiles the plan program; the first re-execution is the
+        # one the plan profiler runs as per-operator stages; the third is
+        # the warm fused dispatch
+        for label in ("cold", "second", "warm"):
+            rows, readings[label] = ctx.timed(c, text)
+            why = check_headline(q, rows, refs[q])
+            if why is not None:
+                bad.append(f"Q{q} {label}: {why}")
+        res = resident(ctx, text)
+        if not res:
+            bad.append(f"Q{q}: plan is not device-resident")
+        emit({"stmt": f"tpch q{q}", "resident": res, **readings})
+    for q in OTHER_QUERIES:
+        rows, reading = ctx.timed(c, QUERIES[q])
+        emit({"stmt": f"tpch q{q}", "rows": len(rows), "cold": reading})
+    if bad:
+        raise AssertionError("; ".join(bad))
+    return {"compared": ["q6", "q1", "q14", "q3"],
+            "ran_once": [f"q{q}" for q in OTHER_QUERIES]}
+
+
+# ----------------------------------------------------------- transactional
+
+def kv_value(k):
+    return (np.asarray(k, dtype=np.int64) * 7919 + 13) % 1_000_003
+
+
+def phase_transactional(ctx: Ctx) -> dict:
+    from oceanbase_tpu.server.direct_load import direct_load
+
+    n = max(20_000, int(1_000_000 * ctx.sf))
+    rng = np.random.default_rng(ctx.seed + 1)
+    c1, c2 = ctx.connect(), ctx.connect()
+    c1.query("create table kv (id int primary key, k int, v int, grp int)")
+    c1.query("create table kv2 (id int primary key, v int)")
+    t0 = time.perf_counter()
+    ids = np.arange(1, n + 1, dtype=np.int64)
+    k = rng.permutation(n).astype(np.int64)  # distinct: one row per key
+    direct_load(ctx.db, "kv", {"id": ids, "k": k, "v": kv_value(k),
+                               "grp": ids % 16})
+    direct_load(ctx.db, "kv2", {"id": np.arange(1000), "v": np.arange(1000)})
+    load_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    if int(c1.query("select count(*) from kv")[0][0]) != n:
+        raise AssertionError("kv count(*) mismatch after direct_load")
+    emit({"setup": "kv", "rows": n, "direct_load_s": load_s,
+          "first_touch_s": time.perf_counter() - t0})
+
+    keys = rng.choice(n, size=8 + 200 + 8 * 16, replace=False)
+    dml_keys, solo_keys, conc_keys = keys[:8], keys[8:208], keys[208:]
+    oracle: dict[int, int | None] = {}
+
+    def want(key: int):
+        return oracle.get(key, int(kv_value(key)))
+
+    def read(client, key: int):
+        rows = client.query(f"select v from kv where k = {key}")
+        return int(rows[0][0]) if rows else None
+
+    bad = []
+    # solo point reads on distinct keys (the result cache cannot answer)
+    walls = []
+    for i, key in enumerate(solo_keys):
+        rows, reading = ctx.timed(c1, f"select v from kv where k = {int(key)}")
+        got = int(rows[0][0]) if rows else None
+        if got != want(int(key)):
+            bad.append(f"point read k={key}: {got} != {want(int(key))}")
+        walls.append(reading["wall_s"])
+        if i in (0, 1, 2):
+            emit({"stmt": "point read", "nth": i, **reading})
+    emit({"stmt": "point read", "count": len(walls),
+          "warm_median_s": float(np.median(walls[3:])),
+          "warm_max_s": float(np.max(walls[3:]))})
+
+    # concurrent point reads: 8 connections in lock step, so the batcher
+    # sees same-plan statements inside one window
+    snap0 = ctx.db.metrics.counters_snapshot()
+    clients = [ctx.connect() for _ in range(8)]
+    barrier = threading.Barrier(len(clients))
+    errors: list[str] = []
+
+    def lane(i: int) -> None:
+        try:
+            for key in conc_keys[i::len(clients)]:
+                barrier.wait(timeout=600)
+                got = read(clients[i], int(key))
+                if got != want(int(key)):
+                    errors.append(f"lane {i} k={key}: {got}")
+        except Exception as e:  # noqa: BLE001 - reported, then the phase fails
+            barrier.abort()
+            errors.append(f"lane {i}: {type(e).__name__}: {e}")
+
+    t0 = time.perf_counter()
+    threads = [threading.Thread(target=lane, args=(i,))
+               for i in range(len(clients))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=900)
+    if any(t.is_alive() for t in threads):
+        errors.append("concurrent lanes did not finish")
+    bad.extend(errors)
+    snap1 = ctx.db.metrics.counters_snapshot()
+    batch = {name: snap1.get(name, 0) - snap0.get(name, 0)
+             for name in ("stmt batched dispatches", "stmt batched statements",
+                          "stmt batch solo", "stmt batch dispatch errors")}
+    emit({"stmt": "concurrent point reads", "connections": len(clients),
+          "statements": len(conc_keys), "wall_s": time.perf_counter() - t0,
+          **batch, "batched_compiles": ctx.db.engine.executor.batched_compiles})
+    if batch["stmt batched dispatches"] < 1:
+        bad.append("the batcher formed no bucket")
+
+    # DML: acknowledged on c1, read back on c2, checked against the dict
+    def acked(sql: str, affected: int) -> float:
+        t0 = time.perf_counter()
+        got = c1.query(sql)
+        if got != affected:
+            bad.append(f"{sql!r}: affected {got} != {affected}")
+        return time.perf_counter() - t0
+
+    def read_back(label: str, key: int) -> None:
+        got = read(c2, key)
+        if got != want(key):
+            bad.append(f"{label} read back k={key}: {got} != {want(key)}")
+
+    ki, ku, kd, kt = (int(x) for x in dml_keys[:4])
+    dml = {}
+    new_k = n + 17
+    dml["insert_s"] = acked(
+        f"insert into kv values ({n + 1}, {new_k}, 4242, 3)", 1)
+    oracle[new_k] = 4242
+    read_back("insert", new_k)
+    dml["update_s"] = acked(f"update kv set v = 777001 where k = {ku}", 1)
+    oracle[ku] = 777001
+    read_back("update", ku)
+    dml["delete_s"] = acked(f"delete from kv where k = {kd}", 1)
+    oracle[kd] = None
+    read_back("delete", kd)
+    read_back("untouched", ki)
+    # two tables in one transaction; uncommitted writes stay invisible
+    t0 = time.perf_counter()
+    c1.query("begin")
+    acked(f"update kv set v = 555002 where k = {kt}", 1)
+    acked("insert into kv2 values (5000, 42)", 1)
+    read_back("before commit", kt)
+    if c2.query("select v from kv2 where id = 5000"):
+        bad.append("kv2 row visible before commit")
+    c1.query("commit")
+    dml["two_table_tx_s"] = time.perf_counter() - t0
+    oracle[kt] = 555002
+    read_back("commit", kt)
+    rows = c2.query("select v from kv2 where id = 5000")
+    if not rows or int(rows[0][0]) != 42:
+        bad.append(f"kv2 read back after commit: {rows}")
+    emit({"stmt": "dml", "replicas": ctx.db.cluster.n_nodes, **dml})
+    if bad:
+        raise AssertionError("; ".join(bad[:8]))
+    return {"point_reads": len(solo_keys) + len(conc_keys), "dml_checked": 7}
+
+
+# ------------------------------------------------------------------ vector
+
+def vec_literal(q) -> str:
+    return "[" + ",".join(f"{v:.5f}" for v in q) + "]"
+
+
+def knn_text(table: str, q, where: str = "") -> str:
+    return (f"select id from {table} {where}"
+            f"order by vec_l2(emb, '{vec_literal(q)}') limit {K}")
+
+
+def clustered_vectors(rng, n: int):
+    """The ANNBENCH_r04 shape: 256 Gaussian clusters in 128-d."""
+    centers = rng.normal(size=(256, VEC_DIM)).astype(np.float32) * 4
+    return (centers[rng.integers(0, 256, n)]
+            + rng.normal(size=(n, VEC_DIM)).astype(np.float32))
+
+
+def recall_at_k(ctx, client, table, x, ids, queries, where="") -> dict:
+    """Distinct embeddings through the wire against brute-force numpy."""
+    hits, walls = 0, []
+    x2 = np.einsum("ij,ij->i", x, x)
+    for q in queries:
+        rows, reading = ctx.timed(client, knn_text(table, q, where))
+        walls.append(reading["wall_s"])
+        d2 = x2 - 2.0 * (x @ q)
+        truth = set(ids[np.argpartition(d2, K)[:K]].tolist())
+        hits += len(truth & {int(r[0]) for r in rows})
+    return {"recall_at_10": hits / (K * len(queries)),
+            "first_s": walls[0], "median_s": float(np.median(walls[1:]))}
+
+
+def phase_vector(ctx: Ctx) -> dict:
+    from oceanbase_tpu.core.dtypes import DataType, Field, Schema, TypeKind
+    from oceanbase_tpu.core.table import Table
+    from oceanbase_tpu.storage.vector_index import register_vector_index
+
+    n = max(20_000, int(100_000 * ctx.sf))
+    lists = 1024 if n >= 1_000_000 else 64
+    nprobe = lists // 32
+    rng = np.random.default_rng(ctx.seed + 2)
+    c = ctx.connect()
+    bad = []
+    counters0 = ctx.db.metrics.counters_snapshot()
+
+    # (a) the served DDL path at the size it takes: a DDL-created table,
+    # batched INSERT over the wire, CREATE VECTOR INDEX ... WITH
+    n_ddl = 4000
+    xs = clustered_vectors(rng, n_ddl)
+    t0 = time.perf_counter()
+    c.query(f"create table docs_ddl (id int primary key, grp int, "
+            f"emb vector({VEC_DIM}))")
+    for lo in range(0, n_ddl, 500):
+        c.query("insert into docs_ddl values " + ", ".join(
+            f"({i}, {i % 10}, '{vec_literal(xs[i])}')"
+            for i in range(lo, lo + 500)))
+    c.query("create vector index ix_ddl on docs_ddl (emb) "
+            "with (lists = 16, nprobe = 4)")
+    ddl_s = time.perf_counter() - t0
+    qs = xs[rng.choice(n_ddl, 4, replace=False)] + rng.normal(
+        size=(4, VEC_DIM)).astype(np.float32) * 0.05
+    small = recall_at_k(ctx, c, "docs_ddl", xs, np.arange(n_ddl), qs)
+    emit({"stmt": "knn docs_ddl", "rows": n_ddl, "insert_and_index_s": ddl_s,
+          **small})
+    if small["recall_at_10"] < 0.9:
+        bad.append(f"docs_ddl recall {small['recall_at_10']}")
+
+    # (b) the ANNBENCH shape. Neither direct_load nor INSERT takes a
+    # VECTOR column at this size, so the rows enter as a preloaded
+    # read-only catalog table (the tools/ann_smoke.py door) and the index
+    # spec is registered the way CREATE VECTOR INDEX registers it
+    t0 = time.perf_counter()
+    x = clustered_vectors(rng, n)
+    ids = np.arange(n, dtype=np.int64)
+    grp = ids % 10
+    ctx.db.catalog["docs"] = Table("docs", Schema((
+        Field("id", DataType(TypeKind.INT64)),
+        Field("grp", DataType(TypeKind.INT64)),
+        Field("emb", DataType.vector(VEC_DIM)),
+    )), {"id": ids, "grp": grp, "emb": x})
+    ctx.db._vector_specs.setdefault("docs", {})["emb"] = (lists, nprobe)
+    register_vector_index(ctx.db.catalog, "docs", "emb",
+                          lists=lists, nprobe=nprobe)
+    gen_s = time.perf_counter() - t0
+    queries = x[rng.choice(n, 12, replace=False)] + rng.normal(
+        size=(12, VEC_DIM)).astype(np.float32) * 0.05
+    plan = "\n".join(r[0] for r in c.query(
+        "explain " + knn_text("docs", queries[0])))
+    unf = recall_at_k(ctx, c, "docs", x, ids, queries[:6])
+    mask = grp < 5
+    fil = recall_at_k(ctx, c, "docs", x[mask], ids[mask], queries[6:],
+                      "where grp < 5 ")
+    counters1 = ctx.db.metrics.counters_snapshot()
+    probes = counters1.get("ann probes", 0) - counters0.get("ann probes", 0)
+    emit({"stmt": "knn docs", "rows": n, "dim": VEC_DIM, "lists": lists,
+          "nprobe": nprobe, "datagen_s": gen_s,
+          "ivf_routed": "ANN IVF probe" in plan, "ann_probes": probes,
+          "unfiltered": unf, "filtered": fil})
+    for label, r in (("unfiltered", unf), ("filtered", fil)):
+        if r["recall_at_10"] < 0.9:
+            bad.append(f"docs {label} recall {r['recall_at_10']}")
+    if probes <= 0:
+        bad.append("'ann probes' did not move: the IVF route never ran")
+    if bad:
+        raise AssertionError("; ".join(bad))
+    return {"recall": {"docs_ddl": small["recall_at_10"],
+                       "unfiltered": unf["recall_at_10"],
+                       "filtered": fil["recall_at_10"]}}
+
+
+# ----------------------------------------------------------------- 4 chips
+
+def row_sharded_bytes_per_device() -> dict:
+    """Bytes of live row-sharded arrays on each device, as JAX holds them
+    (not the residency ledger's total / n)."""
+    import jax
+    from jax.sharding import NamedSharding
+
+    per: dict[str, int] = {}
+    for a in jax.live_arrays():
+        sh = a.sharding
+        if isinstance(sh, NamedSharding) and any(sh.spec):
+            for s in a.addressable_shards:
+                per[str(s.device)] = per.get(str(s.device), 0) + s.data.nbytes
+    return per
+
+
+def phase_px(ctx: Ctx) -> dict:
+    import jax
+
+    from oceanbase_tpu.models.tpch.sql_suite import QUERIES
+
+    c = ctx.connect()
+    load_tpch(ctx, c, ["customer", "orders", "lineitem"], lambda _t: None)
+    bad = []
+    snap0 = ctx.db.metrics.counters_snapshot()
+    for q in (6, 1, 3):
+        text = QUERIES[q]
+        c.query("set ob_px_dop = 4")
+        px_rows, cold = ctx.timed(c, text)
+        _, warm = ctx.timed(c, text)
+        c.query("set ob_px_dop = 0")
+        one_rows, one = ctx.timed(c, text)
+        same = len(px_rows) == len(one_rows) and all(
+            a == b or (a is not None and b is not None and close_to(a, b))
+            for ra, rb in zip(px_rows, one_rows) for a, b in zip(ra, rb)
+            if not (a == b))
+        if not same:
+            bad.append(f"Q{q}: dop 4 {px_rows[:2]} != dop 0 {one_rows[:2]}")
+        emit({"stmt": f"tpch q{q} px", "rows": len(px_rows), "equal": same,
+              "dop4_cold": cold, "dop4_warm": warm, "dop0_cold": one})
+    snap1 = ctx.db.metrics.counters_snapshot()
+    px = ctx.db._px_executor()
+    mesh_devices = {str(d) for d in px.mesh.devices.flat}
+    per_dev = row_sharded_bytes_per_device()
+    emit({"px": "mesh", "mesh_devices": sorted(mesh_devices),
+          "row_sharded_bytes_per_device": per_dev,
+          "ledger_per_device_bytes": px.residency.per_device_bytes(),
+          "px_executions": snap1.get("px executions", 0)
+          - snap0.get("px executions", 0),
+          "collectives": {k: v - snap0.get(k, 0) for k, v in snap1.items()
+                          if k.startswith("px collective")}})
+    if len(mesh_devices) != 4 or len(jax.devices()) < 4:
+        bad.append(f"mesh holds {len(mesh_devices)} distinct devices")
+    if set(per_dev) != mesh_devices or min(per_dev.values(), default=0) <= 0:
+        bad.append(f"row-sharded inputs live on {sorted(per_dev)} only")
+    if bad:
+        raise AssertionError("; ".join(bad))
+    return {"compared": ["q6", "q1", "q3"]}
+
+
+# -------------------------------------------------------------------- main
+
+def cache_entries(path: str | None) -> int | None:
+    if not path or not os.path.isdir(path):
+        return 0 if path else None
+    return sum(1 for f in os.listdir(path) if f.endswith("-cache"))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--sf", type=float, default=None,
+                    help="TPC-H scale factor (default 10; 3 with --chips 4); "
+                         "kv rows and vectors scale with it")
+    ap.add_argument("--seed", type=int, default=19920101)
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args()
+
+    import jax
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    on_chip = device["platform"] == "tpu"
+    if not on_chip and args.sf is None:
+        print("chip_smoke: JAX found no accelerator "
+              f"({device['platform']}); a CPU rehearsal needs an explicit "
+              "--sf", file=sys.stderr)
+        return 2
+    if len(devs) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} needs {args.chips} devices, "
+              f"JAX reports {len(devs)}", file=sys.stderr)
+        return 2
+    sf = args.sf if args.sf is not None else DEFAULT_SF[args.chips]
+
+    from oceanbase_tpu.native import load as load_native
+    from oceanbase_tpu.server.async_front import AsyncMySqlFrontend
+    from oceanbase_tpu.server.database import Database
+    from oceanbase_tpu.share.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    emit({"device": device, "sf": sf, "seed": args.seed, "chips": args.chips,
+          "jax": jax.__version__, "compile_cache_dir": cache_dir,
+          "compile_cache_entries": cache_entries(cache_dir),
+          "native_codec": load_native("codec") is not None})
+
+    db = Database(n_nodes=3, n_ls=2)
+    front = AsyncMySqlFrontend(db).start()
+    ctx = Ctx(db, front.port, sf, args.seed)
+    emit({"governor_budget_bytes": db.governor.budget,
+          "device_bytes_limit": (devs[0].memory_stats() or {}).get(
+              "bytes_limit"),
+          "replicas": db.cluster.n_nodes})
+    counters0 = db.metrics.counters_snapshot()
+    phases = ([("px", phase_px)] if args.chips == 4 else
+              [("analytic", phase_analytic),
+               ("transactional", phase_transactional),
+               ("vector", phase_vector)])
+    passed = True
+    try:
+        for name, fn in phases:
+            t0 = time.perf_counter()
+            try:
+                detail, ok = fn(ctx), True
+            except Exception as e:  # noqa: BLE001 - a failed phase fails the run
+                traceback.print_exc()
+                detail, ok = {"error": f"{type(e).__name__}: {e}"[:2000]}, False
+            passed &= ok
+            emit({"phase": name, "ok": ok,
+                  "seconds": time.perf_counter() - t0, **detail})
+        counters1 = db.metrics.counters_snapshot()
+        deltas = {k: counters1.get(k, 0) - counters0.get(k, 0)
+                  for k in ZERO_DELTA}
+        if any(deltas.values()):
+            passed = False
+        pc = db.plan_cache.stats
+        ex = db.engine.executor
+        emit({"degradation_deltas": deltas,
+              "plan_cache": {"hits": pc.hits, "misses": pc.misses,
+                             "fast_hits": pc.fast_hits,
+                             "fast_misses": pc.fast_misses},
+              "compiles": {"plan": ex.compiles, "narrow": ex.narrow_compiles,
+                           "batched": ex.batched_compiles,
+                           "xla": ctx.compiles.read()[0],
+                           "xla_seconds": ctx.compiles.read()[1]},
+              "result_cache_hits": counters1.get("result cache hits", 0),
+              "memory_stats_peak_bytes": {
+                  str(d): (d.memory_stats() or {}).get("peak_bytes_in_use")
+                  for d in devs[:args.chips]},
+              "compile_cache_entries": cache_entries(cache_dir)})
+    finally:
+        ctx.close_clients()
+        front.stop()
+        db.close()
+    ok = passed and on_chip
+    sys.stderr.flush()
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

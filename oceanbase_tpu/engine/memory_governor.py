@@ -59,20 +59,23 @@ _EST_ROW_BYTES = 128
 
 
 def detect_device_budget() -> int:
-    """Best-effort HBM detection: jax device memory_stats when the
-    backend exposes it (TPU/GPU), else the synthetic CPU budget."""
-    try:
-        import jax
+    """Auto-sized budget: a fraction of the HBM the first device reports.
+    Only a CPU backend, which reports none, gets the synthetic budget; on
+    any other platform a limit that cannot be read raises, because a
+    made-up 2 GiB there would quietly route resident-sized scans through
+    chunk streaming (Executor.prepare's upload guard)."""
+    import jax
 
-        dev = jax.devices()[0]
-        stats = getattr(dev, "memory_stats", None)
-        if callable(stats):
-            limit = (stats() or {}).get("bytes_limit", 0)
-            if limit:
-                return int(limit * AUTO_HBM_FRACTION)
-    except Exception:
-        pass
-    return int(os.environ.get("OB_TPU_SYNTHETIC_HBM", SYNTHETIC_CPU_BUDGET))
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return int(os.environ.get("OB_TPU_SYNTHETIC_HBM",
+                                  SYNTHETIC_CPU_BUDGET))
+    limit = (dev.memory_stats() or {}).get("bytes_limit", 0)
+    if not limit:
+        raise RuntimeError(
+            f"{dev.platform} device {dev} reports no memory bytes_limit; "
+            "set ob_device_memory_limit explicitly")
+    return int(limit * AUTO_HBM_FRACTION)
 
 
 def derive_chunk_rows(budget_bytes: int, default_rows: int,

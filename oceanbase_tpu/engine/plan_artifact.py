@@ -282,27 +282,12 @@ class PlanArtifactStore:
         return st
 
     def _enable_xla_cache(self) -> None:
-        """Point the process-global XLA persistent compilation cache into
-        the store: backend compiles of deserialized programs (and of
-        fresh compiles on this node) persist next to the artifacts."""
-        try:
-            jax.config.update(
-                "jax_compilation_cache_dir", os.path.join(self.root, "xla"))
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_enable_xla_caches", "all")
-            except Exception:
-                pass  # knob spelling varies across jax versions
-            # jax latches "no cache dir" on the first compile of the
-            # process; without a reset the updates above are ignored
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass  # cache stays off; artifacts still skip the retrace
+        """Backend compiles of deserialized programs (and of fresh
+        compiles on this node) persist in the process-global XLA cache,
+        so a warm boot's round-trip compile is a disk read."""
+        from ..share.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     # ----------------------------------------------------------- priming
     def _prime_async(self, blob, in_avals, proto, leaves) -> None:

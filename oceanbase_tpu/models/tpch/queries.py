@@ -208,3 +208,56 @@ def q1_numpy(lineitem):
                 )
             )
     return rows
+
+
+def _day(s: str) -> int:
+    return int(np.datetime64(s, "D").astype(int))
+
+
+def q3_cpu(cust, orders, li):
+    """Plain numpy Q3 (top 10 by revenue): [orderkey, revenue, orderdate,
+    shippriority] rows, revenue from the integer domain."""
+    cut = _day("1995-03-15")
+    seg = cust.dicts["c_mktsegment"].encode_one("BUILDING", add=False)
+    ckeys = cust.data["c_custkey"][np.asarray(cust.data["c_mktsegment"]) == seg]
+    om = (np.asarray(orders.data["o_orderdate"]) < cut) & np.isin(
+        orders.data["o_custkey"], ckeys
+    )
+    okeys = orders.data["o_orderkey"][om]  # ascending (generator invariant)
+    odate = orders.data["o_orderdate"][om]
+    oprio = orders.data["o_shippriority"][om]
+    lm = np.asarray(li.data["l_shipdate"]) > cut
+    lok = li.data["l_orderkey"][lm]
+    pos = np.searchsorted(okeys, lok)
+    pos_c = np.minimum(pos, len(okeys) - 1)
+    hit = len(okeys) > 0
+    sel = (okeys[pos_c] == lok) if hit else np.zeros(len(lok), bool)
+    rev = (
+        li.data["l_extendedprice"][lm][sel].astype(np.int64)
+        * (100 - li.data["l_discount"][lm][sel].astype(np.int64))
+    )
+    gkey = pos_c[sel]
+    sums = np.zeros(len(okeys), np.int64)
+    np.add.at(sums, gkey, rev)
+    nz = np.nonzero(sums)[0]
+    order = np.lexsort((odate[nz], -sums[nz]))[:10]
+    top = nz[order]
+    return [
+        [int(okeys[i]), sums[i] / 1e4, int(odate[i]), int(oprio[i])]
+        for i in top
+    ]
+
+
+def q14_cpu(part, li):
+    """Plain numpy Q14 (promo revenue share, percent)."""
+    lm = (np.asarray(li.data["l_shipdate"]) >= _day("1995-09-01")) & (
+        np.asarray(li.data["l_shipdate"]) < _day("1995-10-01")
+    )
+    pk = li.data["l_partkey"][lm]
+    rev = li.data["l_extendedprice"][lm].astype(np.int64) * (
+        100 - li.data["l_discount"][lm].astype(np.int64)
+    )
+    types = np.array(part.dicts["p_type"].values())
+    promo_code = np.char.startswith(types, "PROMO")
+    is_promo = promo_code[np.asarray(part.data["p_type"])][pk - 1]
+    return float(100.0 * rev[is_promo].sum() / max(rev.sum(), 1))

@@ -11,6 +11,7 @@ where no compiler is available (pure wheel installs, sandboxes).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,14 +21,22 @@ _LOCK = threading.Lock()
 _LIBS: dict[str, ctypes.CDLL | None] = {}
 
 
+_FLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17"]
+
+
 def _build(name: str) -> str | None:
     src = os.path.join(_DIR, f"{name}.cpp")
-    so = os.path.join(_DIR, f"_{name}.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(src):
+    # the object's name carries what it was built from (source + flags),
+    # and the flags name no CPU: a tree copied to another machine either
+    # finds an object that is valid there or builds its own
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + " ".join(_FLAGS).encode()).hexdigest()[:12]
+    so = os.path.join(_DIR, f"_{name}.{digest}.so")
+    if os.path.exists(so):
         return so
     tmp = so + f".tmp.{os.getpid()}"
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           "-o", tmp, src]
+    cmd = ["g++", *_FLAGS, "-o", tmp, src]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, so)  # atomic: concurrent builders race benignly

@@ -24,7 +24,8 @@ import threading
 import pytest
 
 from oceanbase_tpu.engine.memory_governor import (
-    MemoryGovernor, Reservation, derive_chunk_rows)
+    AUTO_HBM_FRACTION, SYNTHETIC_CPU_BUDGET, MemoryGovernor, Reservation,
+    derive_chunk_rows, detect_device_budget)
 from oceanbase_tpu.server import Database
 from oceanbase_tpu.server.database import TenantUnit
 from oceanbase_tpu.server.sentinel import HealthSentinel, evaluate_window
@@ -149,6 +150,37 @@ def test_derive_chunk_rows_bounds():
     assert derive_chunk_rows(0, 1 << 20) == 4096  # floor: forward progress
     assert derive_chunk_rows(1 << 40, 65536) == 65536  # cap: the default
     assert derive_chunk_rows(128 * 10_000, 1 << 20) == 10_000
+
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform = platform
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform, stats, want", [
+    ("cpu", None, SYNTHETIC_CPU_BUDGET),
+    ("tpu", {"bytes_limit": 16 << 30}, int((16 << 30) * AUTO_HBM_FRACTION)),
+    ("tpu", {}, RuntimeError),
+    ("tpu", None, RuntimeError),
+])
+def test_detect_device_budget_is_synthetic_only_on_cpu(
+        monkeypatch, platform, stats, want):
+    """A chip whose limit cannot be read must not get the CPU's made-up
+    2 GiB: that would route resident-sized scans into chunk streaming."""
+    import jax
+
+    monkeypatch.delenv("OB_TPU_SYNTHETIC_HBM", raising=False)
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [_FakeDevice(platform, stats)])
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            detect_device_budget()
+    else:
+        assert detect_device_budget() == want
 
 
 class _Boom(Exception):
