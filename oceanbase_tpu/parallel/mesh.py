@@ -11,8 +11,6 @@ methods (hierarchical exchanges).
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -20,43 +18,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 SHARD_AXIS = "shard"
 
 
-def _resolve_shard_map():
-    """Locate shard_map and its replication-check keyword for the
-    installed jax.
-
-    jax >= 0.6 exposes shard_map at top level with a check_vma kwarg;
-    0.4.x keeps it in jax.experimental with the check_rep spelling. The
-    intent ("statically verify output replication") is the same — only
-    location and keyword differ. Rather than guessing the kwarg from the
-    location (which silently rotted once: top-level shard_map briefly
-    shipped while still spelling check_rep), inspect the actual
-    signature and pick whichever spelling it accepts; if a future
-    release drops both, degrade to not forwarding the flag at all.
-    tests/test_mesh_spmd.py pins this resolution against the pinned jax
-    so drift surfaces as a test failure, not a TypeError at query time.
-    """
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # pragma: no cover - C-level callable
-        params = {}
-    kw = next((k for k in ("check_vma", "check_rep") if k in params), None)
-    return fn, kw
-
-
-_shard_map, _SM_CHECK_KW = _resolve_shard_map()
-
-
 def shard_map_compat(f, *, mesh, in_specs, out_specs,
                      check_replication=True):
-    """Version-portable shard_map: every SPMD program in the engine (and
-    its tests) routes through here instead of spelling the jax API."""
-    check = {} if _SM_CHECK_KW is None else {_SM_CHECK_KW: check_replication}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **check)
+    """The one spelling of shard_map in the engine and its tests: every
+    SPMD program routes through here (`jax.shard_map`, whose replication
+    check is `check_vma`)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 def mesh_signature(mesh: Mesh) -> tuple:

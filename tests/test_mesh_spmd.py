@@ -189,28 +189,17 @@ def test_collective_counters_fold_into_metrics(env):
 # --------------------------------------------------------- compat shim
 
 def test_shard_map_shim_tracks_pinned_jax():
-    """Version-drift canary for the compat shim: the resolved entry point
-    must be the one this jax actually ships, and the replication-check
-    kwarg the shim passes must exist in its signature. A jax upgrade
+    """Drift canary: the shim spells the installed jax's API —
+    `jax.shard_map` with the `check_vma` replication check. A jax upgrade
     that renames either fails HERE, not deep inside a lowering."""
     import inspect
 
-    fn, kw = mesh_mod._resolve_shard_map()
-    assert fn is mesh_mod._shard_map
-    assert kw == mesh_mod._SM_CHECK_KW
-    if hasattr(jax, "shard_map"):
-        assert fn is jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as exp_sm
-
-        assert fn is exp_sm
-    params = inspect.signature(fn).parameters
-    assert kw in (None, "check_vma", "check_rep")
-    if kw is not None:
-        assert kw in params
-    else:
-        # None is only legal when NEITHER spelling exists
-        assert "check_vma" not in params and "check_rep" not in params
+    assert "check_vma" in inspect.signature(jax.shard_map).parameters
+    mesh = make_mesh(2)
+    f = mesh_mod.shard_map_compat(
+        lambda x: jax.lax.psum(x, mesh_mod.SHARD_AXIS), mesh=mesh,
+        in_specs=mesh_mod.P(mesh_mod.SHARD_AXIS), out_specs=mesh_mod.P())
+    assert int(jax.jit(f)(np.arange(2))[0]) == 1
 
 
 def test_mesh_signature_identifies_geometry():
