@@ -57,6 +57,7 @@ from ..ops.join import (
     sort_build_side,
 )
 from ..ops.sort import sort_indices
+from ..ops.window import peer_ends, segment_starts
 from ..sql.logical import (
     Aggregate,
     Distinct,
@@ -2577,14 +2578,9 @@ class Executor:
         pos = jnp.arange(n, dtype=jnp.int64)
         # runs are delimited by value (and deadness) changes — NOT side
         new_run = _run_boundaries((sdead,) + tuple(svals))
-        run_start = jax.lax.cummax(jnp.where(new_run, pos, 0))
-        # exclusive run end = start of the NEXT run (suffix-min of marked
-        # positions, shifted one left)
-        marked = jnp.where(new_run, pos, n)
-        suffix_min = jax.lax.cummin(marked[::-1])[::-1]
-        run_end = jnp.concatenate(
-            [suffix_min[1:], jnp.full(1, n, dtype=jnp.int64)]
-        )
+        run_start = segment_starts(new_run)
+        # exclusive run end = start of the NEXT run
+        run_end = peer_ends(new_run) + 1
         is_left = sside == 0
         cum_left = jnp.cumsum(is_left.astype(jnp.int64))
 

@@ -290,7 +290,12 @@ def sort_groupby(
     null-skipping); rows outside `mask` never contribute.
     """
     from .sort import rebuild_i64, split_sort_key
-    from .window import peer_ends, segmented_cumsum, segmented_scan_minmax
+    from .window import (
+        peer_ends,
+        segment_starts,
+        segmented_cumsum,
+        segmented_scan_minmax,
+    )
 
     n = key_cols[0].shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
@@ -328,8 +333,7 @@ def sort_groupby(
     new_seg = new_seg | jnp.concatenate(
         [jnp.ones(1, jnp.bool_), sdead[1:] != sdead[:-1]]
     )
-    pos = jnp.arange(n, dtype=jnp.int64)
-    seg_start = jax.lax.cummax(jnp.where(new_seg, pos, 0))
+    seg_start = segment_starts(new_seg)
     seg_end = peer_ends(new_seg)
 
     # ONE packed row-gather brings every agg value/mask into sorted order

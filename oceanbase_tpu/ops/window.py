@@ -40,21 +40,33 @@ def boundaries(sorted_keys: list[jnp.ndarray]) -> jnp.ndarray:
     return new
 
 
+def row_positions(n: int) -> jnp.ndarray:
+    """0..n-1 in the narrowest dtype that holds a row position. Running
+    max/min over POSITIONS (segment starts, peer ends, run bounds) scan
+    these, and the width matters on the chip: a 64-bit cummax/cummin
+    lowers to a variadic (u32, u32) reduce-window, and two of those in
+    one program crash the installed v5e compiler (SIGSEGV in its
+    tpu-reduce-window-rewriter pass — TPC-H Q15, whose plan holds two
+    copies of the revenue0 group-by). 32-bit scans compile, and cost
+    half the lanes."""
+    return jnp.arange(n, dtype=jnp.int32 if n < 2**31 else jnp.int64)
+
+
 def segment_starts(new_seg: jnp.ndarray) -> jnp.ndarray:
     """Index of the segment's first row, per row (int64)."""
-    idx = jnp.arange(new_seg.shape[0], dtype=jnp.int64)
-    return lax.cummax(jnp.where(new_seg, idx, 0))
+    idx = row_positions(new_seg.shape[0])
+    return lax.cummax(jnp.where(new_seg, idx, 0)).astype(jnp.int64)
 
 
 def peer_ends(new_peer: jnp.ndarray) -> jnp.ndarray:
     """Index of the peer group's last row, per row (int64)."""
     n = new_peer.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int64)
+    idx = row_positions(n)
     arr = jnp.where(new_peer, idx, n)
     # min over j >= i of boundary positions, then shift to "strictly after"
     suffix_min = lax.cummin(arr[::-1])[::-1]
-    after = jnp.concatenate([suffix_min[1:], jnp.full(1, n, dtype=jnp.int64)])
-    return after - 1
+    after = jnp.concatenate([suffix_min[1:], jnp.full(1, n, dtype=idx.dtype)])
+    return (after - 1).astype(jnp.int64)
 
 
 def segmented_cumsum(values: jnp.ndarray, seg_start: jnp.ndarray) -> jnp.ndarray:

@@ -203,3 +203,24 @@ def test_sort_and_topn(rng):
     assert np.asarray(valid).all()
     got_top = [(a[i], b[i]) for i in np.asarray(top)]
     assert got_top == want[:10]
+
+
+@pytest.mark.parametrize("name", ["segment_starts", "peer_ends"])
+def test_position_scans_are_32_bit(name):
+    """Segment starts / peer ends scan row POSITIONS, so the running
+    max/min must stay 32-bit: two 64-bit cummax/cummin in one program
+    crash the installed v5e compiler (TPC-H Q15). The int64 result dtype
+    the callers index with is kept by a cast AFTER the scan."""
+    from oceanbase_tpu.ops import window
+
+    new_seg = jnp.asarray(np.array([1, 0, 0, 1, 0, 1, 1, 0], bool))
+    fn = getattr(window, name)
+    out = fn(new_seg)
+    assert out.dtype == jnp.int64
+    want = {"segment_starts": [0, 0, 0, 3, 3, 5, 6, 6],
+            "peer_ends": [2, 2, 2, 4, 4, 5, 7, 7]}[name]
+    assert np.asarray(out).tolist() == want
+    scans = [e for e in jax.make_jaxpr(fn)(new_seg).eqns
+             if e.primitive.name in ("cummax", "cummin")]
+    assert scans and all(
+        e.outvars[0].aval.dtype == jnp.int32 for e in scans)
