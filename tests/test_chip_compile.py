@@ -1,0 +1,184 @@
+"""The one file that compiles for the chip: the served path's main device
+programs, lowered against a described (not attached) `v5e:2x2` and handed
+to the installed TPU compiler. No chip is involved and nothing runs there;
+what this guards is that the compiler still ACCEPTS these programs — a
+refusal is an exception here, a crash (TPC-H Q15 before PR 22) kills the
+worker, and either fails the file.
+
+Rules (on-chip-measurement guide, section 2): the topology is described
+inside a module-scoped fixture of this file, never at import, in a skipif
+or in a parametrize; every compile happens in the test's own process (the
+worker that described the topology holds the TPU library); the persistent
+compilation cache is off around the compiles (an entry compiled for a
+described chip cannot be read back without one). The CPU runs first, so
+each program is compiled at its settled capacities.
+"""
+
+import jax
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache as cc
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from oceanbase_tpu.core.dtypes import DataType, Field, Schema, TypeKind
+from oceanbase_tpu.core.table import Table
+from oceanbase_tpu.engine import Session
+from oceanbase_tpu.engine.executor import packed_width
+from oceanbase_tpu.models.tpch import datagen
+from oceanbase_tpu.models.tpch.sql_suite import QUERIES, UNIQUE_KEYS
+from oceanbase_tpu.parallel.mesh import SHARD_AXIS, make_mesh
+from oceanbase_tpu.parallel.px import PxExecutor
+from oceanbase_tpu.sql.parser import parse
+from oceanbase_tpu.sql.plan_cache import bind, parameterize
+from oceanbase_tpu.storage.vector_index import register_vector_index
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """shapes(tree): every array leaf as a ShapeDtypeStruct placed on the
+    described chip (row-sharded leaves keep their spec on its mesh)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
+
+    def on_chip(a):
+        sh = getattr(a, "sharding", None)
+        if isinstance(sh, NamedSharding):
+            sh = NamedSharding(mesh, sh.spec)
+        else:
+            sh = one_chip
+        return jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
+                                    if not hasattr(a, "dtype") else a.dtype,
+                                    sharding=sh)
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield lambda tree: jax.tree_util.tree_map(on_chip, tree), mesh
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def tpch():
+    return datagen.generate(0.01)
+
+
+def _compile(fn, *shapes):
+    compiled = fn.lower(*shapes).compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+    return compiled
+
+
+def _entry(sess, text):
+    """Run once on the CPU (capacities settle), hand back the cached
+    plan with its bound parameter block."""
+    sess.sql(text).rows()
+    entry, qp = sess.cached_entry(text)
+    assert entry is not None
+    return entry.prepared, qp
+
+
+@pytest.mark.parametrize("q", [6, 1, 14, 3, 15])
+def test_tpch_plan_compiles_for_v5e(chip, tpch, q):
+    shapes, _ = chip
+    sess = Session(tpch, unique_keys=UNIQUE_KEYS)
+    prepared, qp = _entry(sess, QUERIES[q])
+    _compile(prepared.jitted, shapes(prepared._inputs()), shapes(qp))
+
+
+@pytest.fixture(scope="module")
+def point_read():
+    n = 20_000
+    ids = np.arange(1, n + 1)
+    k = np.random.default_rng(7).permutation(n)
+    I32 = DataType.int32()
+    kv = Table.from_pydict("kv", Schema((
+        Field("id", I32), Field("k", I32), Field("v", I32),
+        Field("grp", I32))), {"id": ids, "k": k, "v": k % 977, "grp": ids % 16})
+    sess = Session({"kv": kv}, unique_keys={"kv": (("id",),)})
+    prepared, qp = _entry(sess, "select v from kv where k = 17")
+    return prepared, qp
+
+
+def test_point_read_compiles_for_v5e(chip, point_read):
+    shapes, _ = chip
+    prepared, qp = point_read
+    _compile(prepared.jitted, shapes(prepared._inputs()), shapes(qp))
+
+
+def test_batched_bucket_compiles_for_v5e(chip, point_read):
+    """The batcher's pow2 bucket: the plan vmapped over the packed
+    parameter block (PreparedPlan.run_batched_host)."""
+    shapes, _ = chip
+    prepared, qp = point_read
+    prepared.run_batched_host(np.stack([np.asarray(qp)] * 4))
+    qblock = np.zeros((4, packed_width(prepared._qparam_spec)), np.int64)
+    _compile(prepared._batched[4], shapes(prepared._inputs()), shapes(qblock))
+
+
+def test_narrow_frame_compiles_for_v5e(chip, point_read):
+    """Whole-statement fusion: plan program + result-frame gather in one
+    executable (PreparedPlan._build_narrow), the warm served dispatch."""
+    shapes, _ = chip
+    prepared, qp = point_read
+    assert prepared._narrow, "the CPU run did not take the fused frame"
+    for fn in prepared._narrow.values():
+        _compile(fn, shapes(prepared._inputs()), shapes(qp))
+
+
+def test_filtered_knn_compiles_for_v5e(chip):
+    """Predicate + IVF probe + re-rank + top-k in one program."""
+    shapes, _ = chip
+    n, d = 20_000, 128
+    rng = np.random.default_rng(4)
+    centers = rng.normal(size=(256, d)).astype(np.float32) * 4
+    x = centers[rng.integers(0, 256, n)] + rng.normal(
+        size=(n, d)).astype(np.float32)
+    ids = np.arange(n, dtype=np.int64)
+    cat = {"docs": Table("docs", Schema((
+        Field("id", DataType(TypeKind.INT64)),
+        Field("grp", DataType(TypeKind.INT64)),
+        Field("emb", DataType.vector(d)),
+    )), {"id": ids, "grp": ids % 10, "emb": x})}
+    register_vector_index(cat, "docs", "emb", lists=64, nprobe=2)
+    sess = Session(cat)
+    lit = "[" + ",".join(f"{v:.5f}" for v in x[3]) + "]"
+    prepared, qp = _entry(
+        sess, f"select id from docs where grp < 5 "
+              f"order by vec_l2(emb, '{lit}') limit 10")
+    assert prepared.params.vector_topns, "not routed through the IVF probe"
+    _compile(prepared.jitted, shapes(prepared._inputs()), shapes(qp))
+
+
+def test_px_q1_compiles_for_v5e_mesh(chip, tpch):
+    """PX Q1 over the described four-chip mesh: inputs and capacities
+    from a run on four virtual CPU devices, the program lowered by a
+    second PxExecutor whose mesh is the chip's."""
+    shapes, mesh = chip
+    cpu_px = PxExecutor(tpch, make_mesh(4), unique_keys=UNIQUE_KEYS)
+    sess = Session(tpch, unique_keys=UNIQUE_KEYS)
+    pz = parameterize(sess.planner.plan(parse(QUERIES[1])).plan)
+    prepared = cpu_px.prepare(pz.plan)
+    qp = bind(pz.values, pz.dtypes)
+    prepared.run(qparams=qp)
+    chip_px = PxExecutor(tpch, mesh, unique_keys=UNIQUE_KEYS)
+    jitted, _spec, _ovf = chip_px.compile(prepared.plan, prepared.params)
+    rep = NamedSharding(mesh, PartitionSpec())
+    qshapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=rep),
+        qp)
+    compiled = _compile(jitted, shapes(prepared._inputs()), qshapes)
+    text = compiled.as_text()
+    assert "all-reduce" in text or "all-gather" in text, \
+        "no collective in the four-chip program"
