@@ -692,7 +692,7 @@ def _server_timeline(db) -> Table:
          [b["collective_bytes"] for b in bs]),
         # streaming pipeline pressure per slice: chunks streamed,
         # wire-busy vs compute-busy seconds and their overlap fraction
-        # (is the H2D tunnel or the device the out-of-core ceiling?),
+        # (is the H2D link or the device the out-of-core ceiling?),
         # grace-hash partitions spilled
         ("stream_chunks", DataType.int64(),
          [b["stream_chunks"] for b in bs]),

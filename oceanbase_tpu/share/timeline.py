@@ -324,7 +324,7 @@ class ServingTimeline:
         Session._execute_entry, from the prepared plan's StreamStats
         delta): wire-busy vs compute-busy seconds and their interval-
         union overlap — the fourth interference axis, answering whether
-        the H2D tunnel or the device is the out-of-core ceiling."""
+        the H2D link or the device is the out-of-core ceiling."""
         if not self.enabled or not chunks:
             return
         b = self._bucket(self._clock())

@@ -90,8 +90,8 @@ def build_ivf(x: np.ndarray, lists: int = 0, iters: int = 10,
     cent = x[rng.choice(n, size=L, replace=False)].copy()
 
     # the data matrix rides as a jit ARGUMENT, never a closure capture: a
-    # captured array becomes a program constant and the remote-compile
-    # request would carry the whole 512MB (observed HTTP 413 at 1M x 128)
+    # captured array becomes a program constant, and the compiled module
+    # would carry the whole 512MB at 1M x 128
     xd = jnp.asarray(x)
 
     a = np.asarray(_kmeans_assign(xd, jnp.asarray(cent)))
