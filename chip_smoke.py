@@ -4,20 +4,24 @@
 MySQL wire -> AsyncMySqlFrontend -> DbSession.sql -> plan cache -> one
 jitted device program -> result frame, at deployment size:
 
-  analytic       TPC-H (all eight tables, --sf, default 10) loaded with
-                 direct_load into DDL-created tables; Q6/Q1/Q14/Q3 against
-                 the plain numpy references, the other compiling queries
-                 once each.
-  transactional  kv table (1M x sf rows): distinct-key point reads, solo
-                 and concurrent (batcher buckets), then INSERT / UPDATE /
+  analytic       TPC-H (all eight tables, --sf) loaded with direct_load
+                 into DDL-created tables; Q6/Q1/Q14/Q3 three times each
+                 against the plain numpy references.
+  transactional  kv table, 10M rows: distinct-key point reads, solo and
+                 concurrent (batcher buckets), then INSERT / UPDATE /
                  DELETE / a two-table BEGIN..COMMIT, each acknowledged and
                  read back from a second connection against a dict.
-  vector         (100k x sf) x 128 float32, IVF lists 1024 / nprobe 32 at
-                 full size; filtered and unfiltered kNN, recall@10 against
-                 brute-force numpy.
+  vector         1M x 128 float32, IVF lists 1024 / nprobe 32; filtered
+                 and unfiltered kNN, recall@10 against brute-force numpy.
   --chips 4      only this: TPC-H Q1/Q6/Q3 under `set ob_px_dop = 4` on
                  the four-device mesh against the same statements at
                  dop 0.
+
+--sf scales TPC-H. Its default is 5, not the 10 of the repo's chip records:
+SF 10 passes on the chip but takes 1303 s with a cold compile cache (my chip
+run, PR 22), past the 1200 s this script is allowed; the split is in
+CHANGES.md PR 22. The kv table and the vectors keep their full size for any
+--sf >= 1 and shrink with it below that, which is what a CPU rehearsal uses.
 
 The script never chooses a device. `ok` is true and the exit code 0 only
 when that device is a TPU and every phase passed; held to the CPU it is a
@@ -44,7 +48,9 @@ import traceback
 
 import numpy as np
 
-DEFAULT_SF = {1: 10.0, 4: 3.0}
+DEFAULT_SF = {1: 5.0, 4: 3.0}
+KV_ROWS = 10_000_000
+VECTORS = 1_000_000
 K = 10  # kNN limit
 VEC_DIM = 128
 # statement-path degradations that must not be what made a phase pass
@@ -57,8 +63,9 @@ ZERO_DELTA = (
 )
 # TPC-H queries beyond the four headline ones that the no-chip compile
 # sweep (tools/compile_sweep.py, table in CHANGES.md PR 22) shows
-# compiling for v5e in under ~20 s at SF 0.01
-OTHER_QUERIES = (2, 4, 5, 7, 8, 10, 11, 12, 13, 16, 17, 18, 19, 20, 21, 22)
+# compiling for v5e in under ~20 s at SF 0.01: none does — every other
+# query's plan costs 38-500 s in the installed compiler
+OTHER_QUERIES = ()
 
 
 def emit(obj: dict) -> None:
@@ -387,7 +394,7 @@ def kv_value(k):
 def phase_transactional(ctx: Ctx) -> dict:
     from oceanbase_tpu.server.direct_load import direct_load
 
-    n = max(20_000, int(1_000_000 * ctx.sf))
+    n = max(20_000, int(KV_ROWS * min(1.0, ctx.sf)))
     rng = np.random.default_rng(ctx.seed + 1)
     c1, c2 = ctx.connect(), ctx.connect()
     c1.query("create table kv (id int primary key, k int, v int, grp int)")
@@ -554,8 +561,8 @@ def phase_vector(ctx: Ctx) -> dict:
     from oceanbase_tpu.core.table import Table
     from oceanbase_tpu.storage.vector_index import register_vector_index
 
-    n = max(20_000, int(100_000 * ctx.sf))
-    lists = 1024 if n >= 1_000_000 else 64
+    n = max(20_000, int(VECTORS * min(1.0, ctx.sf)))
+    lists = 1024 if n == VECTORS else 64
     nprobe = lists // 32
     rng = np.random.default_rng(ctx.seed + 2)
     c = ctx.connect()
@@ -603,8 +610,11 @@ def phase_vector(ctx: Ctx) -> dict:
     gen_s = time.perf_counter() - t0
     queries = x[rng.choice(n, 12, replace=False)] + rng.normal(
         size=(12, VEC_DIM)).astype(np.float32) * 0.05
+    # costing the route builds the IVF artifact (k-means on the device)
+    t0 = time.perf_counter()
     plan = "\n".join(r[0] for r in c.query(
         "explain " + knn_text("docs", queries[0])))
+    build_s = time.perf_counter() - t0
     unf = recall_at_k(ctx, c, "docs", x, ids, queries[:6])
     mask = grp < 5
     fil = recall_at_k(ctx, c, "docs", x[mask], ids[mask], queries[6:],
@@ -612,7 +622,7 @@ def phase_vector(ctx: Ctx) -> dict:
     counters1 = ctx.db.metrics.counters_snapshot()
     probes = counters1.get("ann probes", 0) - counters0.get("ann probes", 0)
     emit({"stmt": "knn docs", "rows": n, "dim": VEC_DIM, "lists": lists,
-          "nprobe": nprobe, "datagen_s": gen_s,
+          "nprobe": nprobe, "datagen_s": gen_s, "index_build_s": build_s,
           "ivf_routed": "ANN IVF probe" in plan, "ann_probes": probes,
           "unfiltered": unf, "filtered": fil})
     for label, r in (("unfiltered", unf), ("filtered", fil)):
@@ -675,8 +685,6 @@ def phase_px(ctx: Ctx) -> dict:
     emit({"px": "mesh", "mesh_devices": sorted(mesh_devices),
           "row_sharded_bytes_per_device": per_dev,
           "ledger_per_device_bytes": px.residency.per_device_bytes(),
-          "px_executions": snap1.get("px executions", 0)
-          - snap0.get("px executions", 0),
           "collectives": {k: v - snap0.get(k, 0) for k, v in snap1.items()
                           if k.startswith("px collective")}})
     if len(mesh_devices) != 4 or len(jax.devices()) < 4:
@@ -699,8 +707,8 @@ def cache_entries(path: str | None) -> int | None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--sf", type=float, default=None,
-                    help="TPC-H scale factor (default 10; 3 with --chips 4); "
-                         "kv rows and vectors scale with it")
+                    help="TPC-H scale factor (default 5; 3 with --chips 4); "
+                         "below 1 the kv rows and vectors shrink with it")
     ap.add_argument("--seed", type=int, default=19920101)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = ap.parse_args()

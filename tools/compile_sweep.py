@@ -14,7 +14,8 @@ the programs the smoke dispatches, at their settled capacities), then
 lowers every cached program against `v5e:2x2` shapes and compiles it. A
 compiler crash is a signal, not an exception: the child announces each
 program before compiling it, and the parent charges a dead child to the
-program in flight and restarts the phase without it. Children run one
+program in flight and restarts the phase without it (and without the
+programs already done). Children run one
 after another (one process at a time may hold the TPU library).
 """
 
@@ -57,6 +58,7 @@ def run_phase(phase: str, sf: float, only: str, out) -> list[dict]:
             if "program" not in rec:
                 continue  # one of the smoke's own lines from the CPU drive
             in_flight = None
+            skip.append(rec["program"])  # a restart does not redo it
             rec.update(phase=phase, sf=sf)
             rows.append(rec)
             out.write(json.dumps(rec) + "\n")
@@ -110,6 +112,10 @@ def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
     from oceanbase_tpu.sql import parser as P
     from oceanbase_tpu.sql.plan_cache import bind, parameterize
 
+    # first of all: a second process holding the TPU library fails here,
+    # before the expensive CPU drive and not after it
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
     enable_compile_cache()  # the CPU drive below recompiles on every restart
     db = Database(n_nodes=3, n_ls=2)
     front = AsyncMySqlFrontend(db).start()
@@ -157,8 +163,6 @@ def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
         # described, not attached, and such entries cannot be read back
         jax.config.update("jax_enable_compilation_cache", False)
         cc.reset_cache()
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
         one_chip = SingleDeviceSharding(topo.devices[0])
         mesh = Mesh(np.array(topo.devices), ("shard",))
 
@@ -211,7 +215,7 @@ def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
 
                 text = next(t for t in QUERIES.values()
                             if P.normalize_for_cache(t)[0] == key[1])
-                pz = parameterize(db.engine.planner.plan(P.parse(text)))
+                pz = parameterize(db.engine.planner.plan(P.parse(text)).plan)
                 px = PxExecutor(db.catalog, mesh,
                                 unique_keys=db._unique_keys,
                                 stats=db.engine.stats)
