@@ -57,9 +57,7 @@ def chip(topo):
             sh = NamedSharding(mesh, sh.spec)
         else:
             sh = one_chip
-        return jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
-                                    if not hasattr(a, "dtype") else a.dtype,
-                                    sharding=sh)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
 
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)

@@ -31,7 +31,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASES = ("tpch", "kv", "vector", "px")
-HEADLINE = (6, 1, 14, 3)
 
 
 # ------------------------------------------------------------------ parent
@@ -130,7 +129,7 @@ def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
             from oceanbase_tpu.models.tpch import schema as S
 
             c = ctx.connect()
-            smoke.load_tpch(ctx, c, list(S.TABLES), lambda _t: None)
+            smoke.load_tpch(ctx, c, list(S.TABLES))
             for q, text in QUERIES.items():
                 if only and f"q{q}" not in only:
                     continue
@@ -138,7 +137,7 @@ def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
                 try:
                     # three runs of a headline query leave its narrow
                     # frame and its profiled per-operator stages cached
-                    for _ in range(3 if q in HEADLINE else 1):
+                    for _ in range(3 if q in smoke.HEADLINE else 1):
                         c.query(text)
                 except smoke.WireError as e:
                     print(json.dumps({"program": f"q{q}", "ok": False,
