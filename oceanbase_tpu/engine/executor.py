@@ -57,6 +57,7 @@ from ..ops.join import (
     sort_build_side,
 )
 from ..ops.sort import sort_indices
+from ..share import gap_ledger as _gap
 from ..ops.window import peer_ends, segment_starts
 from ..sql.logical import (
     Aggregate,
@@ -71,6 +72,7 @@ from ..sql.logical import (
     Sort,
     TopN,
     Window,
+    op_kind,
     output_schema,
     setop_schema,
     unique_key_sets,
@@ -1932,7 +1934,7 @@ class Executor:
         )
 
         def emit(op, inputs) -> tuple[ColumnBatch, dict[int, jnp.ndarray]]:
-            return self._emit_node(op, inputs, emit, params, id_of)
+            return self._emit_scoped(op, inputs, emit, params, id_of)
 
         qparam_spec = _collect_qparam_spec(plan)
 
@@ -1955,7 +1957,17 @@ class Executor:
             ]) if overflow_nodes else jnp.zeros((0,), jnp.int64)
             return out, ovf_vec
 
+        run.__name__ = program_name(plan)
         return jax.jit(run), input_spec, overflow_nodes
+
+    def _emit_scoped(self, op, inputs, emit, params, id_of):
+        """`_emit_node` under the plan node's named scope: every HLO op
+        the node emits carries `<kind>#<nid>` in its `op_name`, children
+        nested inside parents, so a device trace reads in plan nodes (the
+        innermost scope is the node's self time). Every `emit` closure
+        (single-chip, PX, profiled stages) recurses through here."""
+        with jax.named_scope(f"{op_kind(op)}#{id_of[id(op)]}"):
+            return self._emit_node(op, inputs, emit, params, id_of)
 
     def _emit_node(self, op, inputs, emit, params, id_of):
         """Emit one plan node into the traced program (dispatch shared by
@@ -3334,6 +3346,17 @@ class Executor:
         return self.prepare(plan).run(max_retries)
 
 
+def program_name(plan: LogicalOp) -> str:
+    """`ob_select_<plan fingerprint, 8 hex>`: the `__name__` of a plan's
+    jitted program, so the profiler shows it as `jit_ob_select_...`. The
+    executors compile query plans only (a DML statement's qualification
+    scan is a select too); a launch that is not `jit_ob_*` is not a
+    statement program."""
+    from ..sql.plan_cache import plan_fingerprint
+
+    return f"ob_select_{plan_fingerprint(plan)[:8]}"
+
+
 def _collect_qparam_spec(plan) -> list | None:
     """Parameter slots of a parameterized plan, in slot order: list of
     (DataType, offset, width) per slot, or None when any parameter cannot
@@ -3697,19 +3720,22 @@ class PreparedPlan:
 
         def run_narrow(inputs, qparams):
             out, ovf_vec = inner(inputs, qparams)
-            nlive = jnp.sum(out.sel, dtype=jnp.int64)
-            idx = jnp.nonzero(out.sel, size=ncap, fill_value=0)[0]
-            cols = {n: jnp.take(c, idx, axis=0)
-                    for n, c in out.cols.items()}
-            valid = {n: jnp.take(v, idx, axis=0)
-                     for n, v in out.valid.items()}
-            nkeep = jnp.minimum(nlive, jnp.int64(ncap))
-            lanes = jnp.arange(ncap, dtype=jnp.int64) < nkeep
+            with jax.named_scope("frame"):
+                nlive = jnp.sum(out.sel, dtype=jnp.int64)
+                idx = jnp.nonzero(out.sel, size=ncap, fill_value=0)[0]
+                cols = {n: jnp.take(c, idx, axis=0)
+                        for n, c in out.cols.items()}
+                valid = {n: jnp.take(v, idx, axis=0)
+                         for n, v in out.valid.items()}
+                nkeep = jnp.minimum(nlive, jnp.int64(ncap))
+                lanes = jnp.arange(ncap, dtype=jnp.int64) < nkeep
+                novf = jnp.maximum(nlive - ncap, 0)
             nb = ColumnBatch(cols=cols, valid=valid, sel=lanes,
                              nrows=nkeep, schema=out.schema,
                              dicts=out.dicts)
-            return nb, ovf_vec, jnp.maximum(nlive - ncap, 0)
+            return nb, ovf_vec, novf
 
+        run_narrow.__name__ = program_name(self.plan) + "_narrow"
         return jax.jit(run_narrow)
 
     def run_device_narrow(self, qparams: tuple, ncap: int):
@@ -4008,6 +4034,9 @@ class DeviceResult:
             arrs = {n: self._out.cols[n] for n in need}
             vals = {n: self._out.valid[n] for n in need
                     if n in self._out.valid}
+            tl = _gap.tracing()
+            if tl is not None:
+                tl.leaf("d2h")
             t0 = _time.perf_counter()
             sel_fetched = self._hsel is None
             if sel_fetched:
@@ -4022,6 +4051,8 @@ class DeviceResult:
                 nbytes += int(self._hsel.nbytes)
             self._observe(_time.perf_counter() - t0, nbytes,
                           kind="d2h")
+            if tl is not None:
+                tl.leaf_end()
             self._hcols.update(harrs)
             self._hvalid.update(hvals)
         sub = Schema(tuple(fields))
@@ -4051,11 +4082,16 @@ class DeviceResult:
         kb = min(next_pow2(max(k, 1)), cap)
         arrs, vals = _head_gather(self._out.cols, self._out.valid,
                                   self._out.sel, kb)
+        tl = _gap.tracing()
+        if tl is not None:
+            tl.leaf("d2h")
         t0 = _time.perf_counter()
         harrs, hvals = jax.device_get((arrs, vals))
         nbytes = sum(int(getattr(a, "nbytes", 0))
                      for d in (harrs, hvals) for a in d.values())
         self._observe(_time.perf_counter() - t0, nbytes, kind="d2h")
+        if tl is not None:
+            tl.leaf_end()
         host = host_rows(self._out.schema, self._out.dicts, harrs, hvals,
                          np.ones(kb, dtype=np.bool_))
         return {n: v[:k] for n, v in host.items()}

@@ -325,6 +325,10 @@ class MemoryGovernor:
                     self._note_wait(self._clock() - t0)
                     return None
                 self._waiters += 1
+                if not waited:
+                    tl = _gap.tracing()
+                    if tl is not None:
+                        tl.leaf(None)  # parked, until _note_wait
                 waited = True
                 try:
                     self._cond.wait(timeout=min(rem, 0.05))
@@ -373,6 +377,7 @@ class MemoryGovernor:
         led = _gap.current()
         if led is not None and s > 0.0:
             led.add("governor reserve", s)
+            led.leaf_end()
 
     # ------------------------------------------------------- observation
     def wait_p99_s(self) -> float:

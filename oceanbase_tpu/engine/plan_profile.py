@@ -40,7 +40,9 @@ from .executor import (
     _number_nodes,
     _unpack_qparams,
     compact_batch,
+    program_name,
 )
+from ..sql.logical import op_kind
 
 # log2 histogram buckets: bucket i holds values in [2^(i-1), 2^i)
 _NB = 48
@@ -75,16 +77,6 @@ def _vec_project(op) -> bool:
         if isinstance(e, E.Func) and e.name == "vec_l2":
             return True
     return False
-
-
-def op_kind(op) -> str:
-    """Display kind of one plan node (JoinOp carries its join kind —
-    an anti join and an inner join calibrate very differently)."""
-    k = type(op).__name__
-    kind = getattr(op, "kind", None)
-    if k in ("JoinOp", "SetOp") and kind:
-        return f"{k[:-2] if k == 'JoinOp' else k}:{kind}"
-    return k
 
 
 def miss_factor(est, actual) -> float:
@@ -198,21 +190,31 @@ class SegmentedPlan:
         self.root = id_of[id(plan)]
         self.stages = {}
         self.builders = {}
+        # the segments are pieces of the statement's program and are
+        # named after it: a profile shows `jit_ob_select_<fp>_stage3`,
+        # and a launch that is not `jit_ob_*` stays an eager call
+        base = program_name(plan)
+
+        def jit_as(fn, suffix):
+            fn.__name__ = f"{base}_{suffix}"
+            return jax.jit(fn)
+
         for nid in order:
             op = self.nodes[nid]
             child_ids = tuple(id_of[id(c)] for c in eff_children(op))
             self.stages[nid] = (
                 child_ids,
-                jax.jit(self._make_stage(ex, op, child_ids, params, id_of)),
+                jit_as(self._make_stage(ex, op, child_ids, params, id_of),
+                       f"stage{nid}"),
             )
             bf = self._make_build(op, clustered=nid in params.clustered_aggs)
             if bf is not None:
-                self.builders[nid] = jax.jit(bf)
+                self.builders[nid] = jit_as(bf, f"build{nid}")
 
         def root_compact(out):
             return compact_batch(out, params.join_cap[ROOT_COMPACT])
 
-        self._compact = jax.jit(root_compact)
+        self._compact = jit_as(root_compact, "root")
 
     def stale(self, prepared) -> bool:
         """An overflow bump recompiled the plan: the stage closures
@@ -234,7 +236,7 @@ class SegmentedPlan:
                 def emit(child, _inputs):
                     return child_outs[child_ids.index(id_of[id(child)])], {}
 
-                out, ovf = ex._emit_node(op, inputs, emit, params, id_of)
+                out, ovf = ex._emit_scoped(op, inputs, emit, params, id_of)
             finally:
                 expr_compile.set_params(prev)
             return out, ovf, jnp.sum(out.sel, dtype=jnp.int64)

@@ -18,6 +18,7 @@ import numpy as np
 
 from ..core.column import batch_to_host
 from ..core.table import Table
+from ..share import gap_ledger as _gap
 from ..sql import parser as P
 from ..sql.plan_cache import (
     CacheEntry,
@@ -569,11 +570,19 @@ class Session:
         if jspecs:
             norm_key = f"{norm_key}|jh:{jspecs!r}"
         ex = executor if executor is not None else self.executor
+        # the statement's ledger while a profiler session annotates it
+        # (share/gap_ledger.py): each span timed below is also a leaf
+        # ob:<phase> in the profiler's trace
+        tl = _gap.tracing()
+        if tl is not None:
+            tl.leaf("plan compile")
         t0 = time.perf_counter()
         planned = self.planner.plan(ast)
         pz = parameterize(planned.plan)
         key, tables, fp = self._key_parts(norm_key, pz, executor)
         plan_s = time.perf_counter() - t0
+        if tl is not None:
+            tl.leaf_end()
         if use_cache is None:
             use_cache = self.cache_enabled_fn() if self.cache_enabled_fn else True
         entry = self.plan_cache.get(key) if use_cache else None
@@ -592,8 +601,12 @@ class Session:
             art_key = self._artifact_key(norm_key, pz, fp, tables, executor)
         hydrated = False
         if entry is None and art_key is not None and art_store.readable:
+            if tl is not None:
+                tl.leaf("plan compile")
             t0 = time.perf_counter()
             got = art_store.hydrate(art_store.key_id(art_key), ex)
+            if tl is not None:
+                tl.leaf_end()
             if got is not None:
                 _meta, prepared = got
                 compile_s = time.perf_counter() - t0
@@ -605,9 +618,13 @@ class Session:
                 self.plan_cache.put(key, entry)
                 hydrated = True
         if entry is None:
+            if tl is not None:
+                tl.leaf("plan compile")
             t0 = time.perf_counter()
             prepared = ex.prepare(pz.plan)
             compile_s = time.perf_counter() - t0
+            if tl is not None:
+                tl.leaf_end()
             entry = CacheEntry(prepared, planned.output_names, pz.dtypes)
             entry.json_specs, entry.json_hidden = jspecs, jhidden
             if self.plan_monitor is not None and self.plan_monitor.enabled:
@@ -691,6 +708,9 @@ class Session:
         # (plan-cache shared): fold per-run deltas, like overflow retries
         sstats = getattr(prepared, "stream_stats", None)
         stream0 = sstats.snapshot() if sstats is not None else None
+        tl = _gap.tracing()
+        if tl is not None:
+            tl.leaf("param pack")
         t0 = time.perf_counter()
         if hasattr(prepared, "run_host"):
             # packed parameter upload: ONE host->device transfer for the
@@ -702,6 +722,8 @@ class Session:
         bind_s = time.perf_counter() - t0
         d2h_bytes = 0
         fetch_s = 0.0
+        if tl is not None:
+            tl.leaf("device dispatch")
         exec_t0 = time.perf_counter()
         lazy = hasattr(prepared, "run_device") and not jn
         self.last_op_profile = None
@@ -758,6 +780,8 @@ class Session:
             if out is None:
                 out, ovf_vec = prepared.run_device(qparams=qparams)
             dispatch_s = time.perf_counter() - exec_t0
+            if tl is not None:
+                tl.leaf_end()
             if narrow is not None:
                 cursor = NarrowDeviceResult(
                     prepared, qparams, out, ovf_vec, narrow[0], narrow[1],
@@ -774,6 +798,8 @@ class Session:
             hcols, hvalid, hsel, oschema, odicts = prepared.run_host(
                 qparams=qparams)
             dispatch_s = time.perf_counter() - exec_t0
+            if tl is not None:
+                tl.leaf_end()
             if profiling:
                 d2h_bytes = sum(
                     int(getattr(a, "nbytes", 0))
@@ -786,6 +812,8 @@ class Session:
             # chunked / PX prepared plans: device-batch contract
             out_batch = prepared.run(qparams=qparams)
             dispatch_s = time.perf_counter() - exec_t0
+            if tl is not None:
+                tl.leaf_end()
             host = batch_to_host(out_batch)
             if profiling:
                 d2h_bytes = sum(
@@ -883,9 +911,13 @@ class Session:
             # chip time inside exec_s).
             cursor.profile = profile
             cursor.phases = phases
+            if tl is not None:
+                tl.leaf("device wait")
             tf = time.perf_counter()
             nrows = rs.nrows
             fetch_s = time.perf_counter() - tf
+            if tl is not None:
+                tl.leaf_end()
             phases["fetch_s"] = fetch_s
             if profile is not None:
                 profile.fetch_s = fetch_s

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -258,7 +259,16 @@ def _literal_as(value, target: DataType, batch: ColumnBatch, col_name: str | Non
 
 
 def evaluate(e: Expr, batch: ColumnBatch):
-    """Evaluate an expression over a batch -> (values, valid|None)."""
+    """Evaluate an expression over a batch -> (values, valid|None).
+
+    Traced under the named scope `expr`, inside the scope of the plan
+    node that asked: a device trace tells an operator's expression work
+    (a LIKE lookup table, a decimal rescale) from its own (a gather)."""
+    with jax.named_scope("expr"):
+        return _evaluate(e, batch)
+
+
+def _evaluate(e: Expr, batch: ColumnBatch):
     schema = batch.schema
 
     if isinstance(e, ColRef):
@@ -289,7 +299,7 @@ def evaluate(e: Expr, batch: ColumnBatch):
         return _eval_compare(e, batch)
 
     if isinstance(e, BoolOp):
-        vals_valid = [evaluate(a, batch) for a in e.args]
+        vals_valid = [_evaluate(a, batch) for a in e.args]
         if e.op == "and":
             out = vals_valid[0][0]
             for v, _ in vals_valid[1:]:
@@ -323,7 +333,7 @@ def evaluate(e: Expr, batch: ColumnBatch):
             return out, all_valid | known_true
 
     if isinstance(e, Not):
-        v, valid = evaluate(e.arg, batch)
+        v, valid = _evaluate(e.arg, batch)
         return ~v, valid
 
     if isinstance(e, IsNull):
@@ -337,7 +347,7 @@ def evaluate(e: Expr, batch: ColumnBatch):
             codes, valid, vals = view
             valid = _fold_view_nulls(codes, valid, vals)
         else:
-            _, valid = evaluate(e.arg, batch)
+            _, valid = _evaluate(e.arg, batch)
         if valid is None:
             out = jnp.zeros(batch.capacity, dtype=jnp.bool_)
         else:
@@ -360,7 +370,7 @@ def evaluate(e: Expr, batch: ColumnBatch):
 
         lo = Compare(">=", e.arg, e.low)
         hi = Compare("<=", e.arg, e.high)
-        v, valid = evaluate(and_(lo, hi), batch)
+        v, valid = _evaluate(and_(lo, hi), batch)
         return (~v if e.negated else v), valid
 
     if isinstance(e, Func):
@@ -377,8 +387,8 @@ def _numeric_align(e_left: Expr, e_right: Expr, batch: ColumnBatch):
     """
     schema = batch.schema
     lt, rt = infer_type(e_left, batch.schema), infer_type(e_right, batch.schema)
-    lv, lvalid = evaluate(e_left, batch)
-    rv, rvalid = evaluate(e_right, batch)
+    lv, lvalid = _evaluate(e_left, batch)
+    rv, rvalid = _evaluate(e_right, batch)
 
     if lt.is_float or rt.is_float:
         tgt = jnp.result_type(lv.dtype if lt.is_float else jnp.float32,
@@ -422,8 +432,8 @@ def _eval_arith(e: BinaryOp, batch: ColumnBatch):
         return ops[e.op](lv, rv), _merge_valid(lvalid, rvalid)
 
     if e.op == "*" and (lt.is_decimal or rt.is_decimal):
-        lv, lvalid = evaluate(e.left, batch)
-        rv, rvalid = evaluate(e.right, batch)
+        lv, lvalid = _evaluate(e.left, batch)
+        rv, rvalid = _evaluate(e.right, batch)
         prod = lv.astype(jnp.int64) * rv.astype(jnp.int64)
         ls = lt.scale if lt.is_decimal else 0
         rs = rt.scale if rt.is_decimal else 0
@@ -449,8 +459,8 @@ def _eval_arith(e: BinaryOp, batch: ColumnBatch):
 
 def _numeric_align_float(e_left: Expr, e_right: Expr, batch: ColumnBatch):
     lt, rt = infer_type(e_left, batch.schema), infer_type(e_right, batch.schema)
-    lv, lvalid = evaluate(e_left, batch)
-    rv, rvalid = evaluate(e_right, batch)
+    lv, lvalid = _evaluate(e_left, batch)
+    rv, rvalid = _evaluate(e_right, batch)
     tgt = jnp.float64 if (lt.kind is TypeKind.FLOAT64 or rt.kind is TypeKind.FLOAT64
                           or not (lt.is_float or rt.is_float)) else jnp.float32
     if lt.is_decimal:
@@ -481,11 +491,11 @@ def _eval_compare(e: Compare, batch: ColumnBatch):
 
     # date vs 'YYYY-MM-DD' string literal: parse on host, compare as int days
     if lt.kind is TypeKind.DATE and isinstance(e.right, Literal) and isinstance(e.right.value, str):
-        lv, lvalid = evaluate(e.left, batch)
+        lv, lvalid = _evaluate(e.left, batch)
         rv = _literal_as(e.right.value, lt, batch, None)
         return _CMP[e.op](lv, rv), lvalid
     if rt.kind is TypeKind.DATE and isinstance(e.left, Literal) and isinstance(e.left.value, str):
-        rv, rvalid = evaluate(e.right, batch)
+        rv, rvalid = _evaluate(e.right, batch)
         lv = _literal_as(e.left.value, rt, batch, None)
         return _CMP[e.op](lv, rv), rvalid
 
@@ -526,8 +536,8 @@ def _eval_compare(e: Compare, batch: ColumnBatch):
                     "columns use different dictionaries; requires dictionary "
                     "translation (not yet implemented)"
                 )
-            lv, lvalid = evaluate(e.left, batch)
-            rv, rvalid = evaluate(e.right, batch)
+            lv, lvalid = _evaluate(e.left, batch)
+            rv, rvalid = _evaluate(e.right, batch)
             return _CMP[e.op](lv, rv), _merge_valid(lvalid, rvalid)
         raise NotImplementedError("varchar comparison form")
 
@@ -539,7 +549,7 @@ def _dict_compare(col_expr: ColRef, op: str, value: str, batch: ColumnBatch):
     d = batch.dicts.get(col_expr.name)
     if d is None:
         raise KeyError(f"no dictionary for varchar column {col_expr.name}")
-    codes, valid = evaluate(col_expr, batch)
+    codes, valid = _evaluate(col_expr, batch)
     if d.sorted and op in ("<", "<=", ">", ">="):
         import bisect
 
@@ -606,7 +616,7 @@ def _eval_cast(e: Cast, batch: ColumnBatch):
         else:
             out = fv.astype(dst.storage_np)
         return out, valid
-    v, valid = evaluate(e.arg, batch)
+    v, valid = _evaluate(e.arg, batch)
     if src_t.is_decimal and dst.is_decimal:
         return _rescale_decimal(v, src_t.scale, dst.scale).astype(dst.storage_np), valid
     if src_t.is_decimal and dst.is_float:
@@ -624,14 +634,14 @@ def _eval_case(e: Case, batch: ColumnBatch):
     out_t = infer_type(e, batch.schema)
     np_dt = out_t.storage_np
     if e.default is not None:
-        out, out_valid = evaluate(Cast(e.default, out_t), batch)
+        out, out_valid = _evaluate(Cast(e.default, out_t), batch)
     else:
         out = jnp.zeros(batch.capacity, dtype=np_dt)
         out_valid = jnp.zeros(batch.capacity, dtype=jnp.bool_)
     for cond, val in reversed(e.whens):
-        c, cvalid = evaluate(cond, batch)
+        c, cvalid = _evaluate(cond, batch)
         take = c if cvalid is None else (c & cvalid)
-        v, vvalid = evaluate(Cast(val, out_t), batch)
+        v, vvalid = _evaluate(Cast(val, out_t), batch)
         out = jnp.where(take, v, out)
         if out_valid is not None or vvalid is not None:
             ov = out_valid if out_valid is not None else jnp.ones(batch.capacity, jnp.bool_)
@@ -655,7 +665,7 @@ def _eval_in_list(e: InList, batch: ColumnBatch):
         )
         out = jnp.asarray(lut)[jnp.clip(codes, 0, max(len(vals) - 1, 0))]
         return (~out if e.negated else out), valid
-    v, valid = evaluate(e.arg, batch)
+    v, valid = _evaluate(e.arg, batch)
     out = jnp.zeros(batch.capacity, dtype=jnp.bool_)
     for item in e.values:
         out = out | (v == _literal_as(item, t, batch, None))
@@ -695,7 +705,7 @@ def _string_view(e: Expr, batch: ColumnBatch):
         d = batch.dicts.get(e.name)
         if d is None:
             return None
-        codes, valid = evaluate(e, batch)
+        codes, valid = _evaluate(e, batch)
         return codes, valid, list(d.values())
     if isinstance(e, Func) and e.name == "substr":
         base = _string_view(e.args[0], batch)
@@ -789,7 +799,7 @@ def derive_dict_column(e: Expr, batch: ColumnBatch):
 
 def _eval_func(e: Func, batch: ColumnBatch):
     if e.name in ("extract_year", "extract_month", "extract_day"):
-        v, valid = evaluate(e.args[0], batch)
+        v, valid = _evaluate(e.args[0], batch)
         y, m, d = _civil_from_days(v)
         return {"extract_year": y, "extract_month": m, "extract_day": d}[e.name], valid
 
@@ -803,7 +813,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
             dtype=np.bool_,
             count=len(d),
         )
-        codes, valid = evaluate(col_expr, batch)
+        codes, valid = _evaluate(col_expr, batch)
         return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(d) - 1, 0))], valid
 
     if e.name == "fts_match":
@@ -823,7 +833,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
             dtype=np.bool_,
             count=len(d),
         )
-        codes, valid = evaluate(col_expr, batch)
+        codes, valid = _evaluate(col_expr, batch)
         return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(d) - 1, 0))], valid
 
     if e.name == "json_valid":
@@ -871,7 +881,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
         p = str(pat.value)
         test = (lambda v: v.startswith(p)) if e.name == "prefix" else (lambda v: p in v)
         lut = np.fromiter((test(v) for v in d.values()), dtype=np.bool_, count=len(d))
-        codes, valid = evaluate(col_expr, batch)
+        codes, valid = _evaluate(col_expr, batch)
         return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(d) - 1, 0))], valid
 
     if e.name in ("vec_l2", "vec_ip", "vec_cosine"):
@@ -881,7 +891,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
         # similarity, both oriented so ORDER BY <dist> ASC LIMIT k means
         # "nearest" for every metric. Used by the brute-force exact path
         # (plain TopN) and IVF candidate re-ranking.
-        xv, valid = evaluate(e.args[0], batch)
+        xv, valid = _evaluate(e.args[0], batch)
         q = evaluate_vector_literal(e.args[1])
         xq = xv @ q
         if e.name == "vec_ip":
@@ -893,16 +903,16 @@ def _eval_func(e: Func, batch: ColumnBatch):
         xn = jnp.sum(xv * xv, axis=1)
         return xn - 2.0 * xq + jnp.sum(q * q), valid
     if e.name == "abs":
-        v, valid = evaluate(e.args[0], batch)
+        v, valid = _evaluate(e.args[0], batch)
         return jnp.abs(v), valid
     if e.name == "neg":
-        v, valid = evaluate(e.args[0], batch)
+        v, valid = _evaluate(e.args[0], batch)
         return -v, valid
     if e.name in ("least", "greatest"):
         op = jnp.minimum if e.name == "least" else jnp.maximum
-        v, valid = evaluate(e.args[0], batch)
+        v, valid = _evaluate(e.args[0], batch)
         for a in e.args[1:]:
-            v2, valid2 = evaluate(a, batch)
+            v2, valid2 = _evaluate(a, batch)
             v = op(v, v2)
             valid = _merge_valid(valid, valid2)
         return v, valid
@@ -911,6 +921,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
 
 def compile_predicate(e: Expr, batch: ColumnBatch) -> jnp.ndarray:
     """Predicate -> bool mask over the batch; NULL results reject the row."""
-    v, valid = evaluate(e, batch)
-    mask = v if valid is None else (v & valid)
-    return mask & batch.sel
+    with jax.named_scope("expr"):
+        v, valid = _evaluate(e, batch)
+        mask = v if valid is None else (v & valid)
+        return mask & batch.sel

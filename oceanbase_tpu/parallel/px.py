@@ -1133,9 +1133,13 @@ class PxExecutor(Executor):
         )
 
         def emit(op, inputs):
-            return self._emit_node(op, inputs, emit, params, id_of)
+            return self._emit_scoped(op, inputs, emit, params, id_of)
 
-        from ..engine.executor import _collect_qparam_spec, _unpack_qparams
+        from ..engine.executor import (
+            _collect_qparam_spec,
+            _unpack_qparams,
+            program_name,
+        )
 
         qparam_spec = _collect_qparam_spec(plan)
         # the mesh-plan recorder for THIS compile. jit traces lazily, so
@@ -1213,6 +1217,7 @@ class PxExecutor(Executor):
                 check_replication=False,
             )(raw_inputs, qparams)
 
+        run.__name__ = program_name(plan) + "_px"
         return jax.jit(run), input_spec, overflow_nodes
 
 

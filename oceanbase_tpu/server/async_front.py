@@ -202,6 +202,7 @@ class AsyncMySqlFrontend:
             return [_err_packet(
                 1053, "server shutting down: retry on a peer")]
         with self._flight_lock:
+            depth = self._inflight  # statements ahead of this one
             self._inflight += 1
         import time as _t
 
@@ -212,8 +213,11 @@ class AsyncMySqlFrontend:
             # posting the statement and a pool thread picking it up —
             # host tax the statement ledger (which opens inside fn)
             # cannot see. Folded post-hoc against the statement's digest
-            # as frontend ingress ("wire read").
+            # as frontend ingress ("wire read"), and recorded under its
+            # own name beside the tenant's other queue wait.
             queued_s = _t.perf_counter() - t0
+            self.db.metrics.bulk(adds=(("front pool depth", depth),),
+                                 waits=(("front pool queue", queued_s),))
             out = fn(*args)
             sess_obj = args[0] if args else None
             ht = getattr(self.db, "host_tax", None)

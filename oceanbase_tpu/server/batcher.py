@@ -489,7 +489,12 @@ class StatementBatcher:
         A tenant within its weight share never waits; a flooding tenant
         over its share parks here — on the "tenant admission" wait
         event — while other tenants are active."""
+        tl = _gl.tracing()
+        if tl is not None:
+            tl.leaf(None)  # parked on the tenant's permit
         waited = self.gate.acquire_slot(self.tenant)
+        if tl is not None:
+            tl.leaf_end()
         if waited > 0.0:
             m = self.metrics
             if m is not None and m.enabled:
@@ -541,16 +546,19 @@ class StatementBatcher:
         # bound so a wedged gate degrades followers first (they shrink
         # the batch) and the leader eventually dispatches regardless.
         bound = wait_us / 1e6 + 2.0 * self.follower_timeout_s
+        led = _gl.current()
+        if led is not None:
+            led.leaf(None)  # parked in the group-commit window
         t0 = time.perf_counter()
         b.full.wait(bound)
         waited = time.perf_counter() - t0
         if m is not None and m.enabled:
             m.wait("stmt batch window", waited)
-        led = _gl.current()
         if led is not None:
             # host-tax hint on the LEADER's ledger: its group-commit
             # window wait (the dispatch is added separately, once)
             led.add("batch window", waited)
+            led.leaf_end()
         rider = None
         with self._lock:
             b.closed = True
@@ -614,7 +622,12 @@ class StatementBatcher:
         wedged — take a fresh token (adopted groups hold none) and
         degrade this lane to solo; followers degrade themselves off
         b.error exactly as after a failed dispatch."""
+        tl = _gl.tracing()
+        if tl is not None:
+            tl.leaf(None)  # parked behind the adopting leader
         ok = b.done.wait(2.0 * self.follower_timeout_s)
+        if tl is not None:
+            tl.leaf_end()
         if ok and b.error is None:
             if m is not None and m.enabled:
                 m.add("stmt batch coalesced rider")
@@ -667,17 +680,20 @@ class StatementBatcher:
         batch under the lock — it is neither device-executed nor
         counted — and re-execute solo on a fresh token."""
         bound = wait_us / 1e6 + self.follower_timeout_s
+        led = _gl.current()
+        if led is not None:
+            led.leaf(None)  # parked behind the leader
         tw = time.perf_counter()
         try:
             return self._follow_inner(b, lane, bound, m)
         finally:
-            led = _gl.current()
             if led is not None:
                 # host-tax hint: a FOLLOWER attributes its whole wait
                 # (window + the leader's dispatch it rode out) as batch
                 # window — the cohort's device busy is the leader's to
                 # count, exactly once
                 led.add("batch window", time.perf_counter() - tw)
+                led.leaf_end()
 
     def _follow_inner(self, b: _Batch, lane: int, bound: float, m) -> bool:
         ok = b.done.wait(bound)
@@ -745,6 +761,9 @@ class StatementBatcher:
         on that path (no done events, no results) — the caller falls
         back to two separate dispatches."""
         m = self.metrics
+        tl = _gl.tracing()
+        if tl is not None:
+            tl.leaf("device dispatch")
         t0 = time.perf_counter()
         try:
             qa = np.stack([b.rows[i] for i in alive])
@@ -755,6 +774,7 @@ class StatementBatcher:
             dispatch_s = time.perf_counter() - t0
             led = _gl.current()
             if led is not None:
+                led.leaf_end()
                 # ONE device execution on the ADOPTING leader's ledger;
                 # the rider's lanes hint only their window wait — same
                 # exactly-once discipline as the single-plan dispatch
@@ -790,6 +810,9 @@ class StatementBatcher:
         lane slots. Any failure parks the error and sends every lane
         back to the solo path."""
         m = self.metrics
+        tl = _gl.tracing()
+        if tl is not None:
+            tl.leaf("device dispatch")
         t0 = time.perf_counter()
         try:
             qblock = np.stack([b.rows[i] for i in alive])
@@ -799,6 +822,7 @@ class StatementBatcher:
             b.dispatch_s = time.perf_counter() - t0
             led = _gl.current()
             if led is not None:
+                led.leaf_end()
                 # _dispatch runs on the leader's thread: the cohort's ONE
                 # batched device execution lands on the LEADER's ledger
                 # (followers hint only their window wait) — the double-

@@ -2553,6 +2553,7 @@ class DbSession:
         # per-statement host-tax gap ledger (share/gap_ledger.py); also
         # published thread-locally so batcher/governor waits self-report
         self._gap = None
+        self._stmt_id = 0  # db-wide statement sequence number
         self._last_digest = ""
         # device-OOM degradation ladder state (reset per statement in
         # _sql_inner): None | "chunk" | "host", plus the fired rungs
@@ -2624,9 +2625,14 @@ class DbSession:
         around execution, one sql_audit record at completion."""
         db = self.db
         t0 = _time.perf_counter()
+        cpu0 = _time.thread_time()
         err, rs = "", None
         self._last_stmt_type = ""  # "": did not parse
         self._stmt_cache_hit = False  # set by any inner _select
+        # the statement's id: the interrupt registration's, the `stmt`
+        # tag of the `sql` span, and the `stmt` stat of every ob:<phase>
+        # annotation in a profiler trace
+        stmt_id = self._stmt_id = next(db._stmt_seq)
         # host-tax gap ledger: one per statement, spanning the SAME t0 as
         # the audit elapsed_s. Published thread-locally so the batcher and
         # governor (which run their waits on this thread) self-report
@@ -2638,7 +2644,7 @@ class DbSession:
             led = self._gap
             if led is None:
                 led = _GL.GapLedger()
-            led.begin(t0)
+            led.begin(t0, stmt_id)
             _GL.set_current(led)
         self._gap = led
         # statement deadline: min(ob_query_timeout from now, the open tx's
@@ -2663,12 +2669,13 @@ class DbSession:
             if bounded:
                 wait_s = max(deadline.remaining(), 0.0)
             if led is not None:
-                led.cut("setup")  # deadline/quota bookkeeping since t0
+                # deadline/quota bookkeeping since t0
+                led.cut("setup", "admission queue")
             tq = _time.perf_counter()
             ok = sem.acquire(timeout=wait_s)
             waited = _time.perf_counter() - tq
             if led is not None:
-                led.cut("admission queue")
+                led.cut("admission queue", "setup")
             db.metrics.wait("tenant worker queue", waited)
             tl = db.timeline
             if tl.enabled:
@@ -2685,7 +2692,7 @@ class DbSession:
                     f"({db.unit.max_workers} workers busy)"
                 )
         # per-statement interrupt registration (KILL QUERY target)
-        iid = ("stmt", db.tenant_name, self.session_id, next(db._stmt_seq))
+        iid = ("stmt", db.tenant_name, self.session_id, stmt_id)
         checker = db.interrupts[0].register(iid)
         db._active_stmts[self.session_id] = iid
         prev = _I.set_current(checker)
@@ -2697,9 +2704,9 @@ class DbSession:
             # interrupt + deadline registration (and admission metrics/
             # timeline above): small but real, and the residual gate is
             # strict — name it instead of leaking it
-            led.cut("setup")
+            led.cut("setup", "setup")
         try:
-            return self._sql_inner(text, t0)
+            return self._sql_inner(text, t0, cpu0)
         finally:
             if led is not None:
                 _GL.set_current(None)
@@ -2710,7 +2717,7 @@ class DbSession:
             if sem is not None:
                 sem.release()
 
-    def _sql_inner(self, text: str, t0) -> ResultSet:
+    def _sql_inner(self, text: str, t0, cpu0) -> ResultSet:
         db = self.db
         err, rs = "", None
         # last_profile is per-run_ast; statements that never reach run_ast
@@ -2734,12 +2741,21 @@ class DbSession:
         # _ladder records the rungs fired, in order, for tests/diagnosis
         self._degrade_mode = None
         self._ladder = []
-        with db.tracer.span("sql", session=self.session_id) as sp:
+        with db.tracer.span("sql", session=self.session_id,
+                            stmt=self._stmt_id) as sp:
             with db.ash.activity(self.session_id, "EXECUTING", text,
                                  sp.trace_id):
-                if self._gap is not None:
-                    # tracer span + ASH activity registration glue
-                    self._gap.cut("setup")
+                led = self._gap
+                if led is not None:
+                    # tracer span + ASH activity registration glue; a
+                    # SELECT's next cut is the fast tier's, any other
+                    # statement's the parser's
+                    then = None
+                    if led.stmt:
+                        then = ("fast lookup"
+                                if text[:16].lstrip()[:6].lower() == "select"
+                                else "parse bind")
+                    led.cut("setup", then)
                 pp = db.plan_profiler
                 if pp is not None and pp.enabled:
                     # hand the statement digest to the engine's operator
@@ -2807,8 +2823,11 @@ class DbSession:
                         # Deferred folds must NOT hold the live ledger —
                         # begin() re-arms it in place for this session's
                         # next statement — so they read a frozen snapshot
+                        if led.stmt:
+                            led.tag(digest=str(digest))
                         led.cut("completion fold")
                         led.close()
+                        led.cpu_s = _time.thread_time() - cpu0
                         snap = _GL.LedgerSnapshot(led)
                     retry_cnt = (self._retry_ctrl.retry_cnt
                                  if self._retry_ctrl else 0)
@@ -3030,13 +3049,16 @@ class DbSession:
                     else:
                         db.location.clear()
                 if wait > 0:
+                    led = _GL.current()
+                    if led is not None:
+                        led.leaf(None)  # parked in the backoff
                     tb = _time.perf_counter()
                     with m.waiting("statement retry backoff"):
                         db.cluster.settle(wait)
-                    led = _GL.current()
                     if led is not None:
                         led.add("retry backoff",
                                 _time.perf_counter() - tb)
+                        led.leaf_end()
                 if d is not None and d.expired:
                     raise ctrl.timeout_error(e) from e
             finally:
@@ -3326,7 +3348,7 @@ class DbSession:
         # full-path engine window: whatever the engine measured
         # (plan/compile/bind/dispatch/fetch) carves the window wall; the
         # rest is the named measured remainder "engine host"
-        led.window_start()
+        led.window_start("engine host")
         try:
             return self._dispatch_stmt(stmt, norm_key,
                                        fast_reg=self._fast_reg)
@@ -3362,7 +3384,7 @@ class DbSession:
             # the fast tier's wall is host tax even when it MISSES — the
             # tokenize/peek attempt preceded the full parse path
             if led is not None:
-                led.cut("fast lookup")
+                led.cut("fast lookup", "parse bind")
             return None
 
         try:
@@ -3399,7 +3421,8 @@ class DbSession:
             # tokenize + peek + priv + catalog refresh + lookup: the fast
             # tier's whole host cost, as one contiguous cut from the
             # dispatch-entry cursor
-            led.cut("fast lookup")
+            led.cut("fast lookup", "result cache" if self._vars.get(
+                "ob_enable_result_cache", 1) else None)
         # device-resident result cache: probed AFTER the privilege check
         # (a REVOKE between repeats must bite a cached hit) and the
         # catalog refresh (the watermark key must see fresh committed
@@ -3441,7 +3464,7 @@ class DbSession:
                 # only — the cohort's device busy is counted ONCE) from
                 # this thread via gap_ledger.current()
                 if led is not None:
-                    led.window_start()
+                    led.window_start("engine host")
                 rs = db.batcher.execute(
                     hit, bmax, self._vars.get("ob_batch_max_wait_us", 0))
                 if rs is not None:
@@ -3478,7 +3501,7 @@ class DbSession:
             finally:
                 db.batcher.admit_done()
         if led is not None:
-            led.window_start()
+            led.window_start("engine host")
         try:
             rs = db.engine.fast_execute(hit, fastparse_s=fastparse_s,
                                         rc_key=rc_key)

@@ -129,6 +129,11 @@ def _host_tax(db) -> Table:
         ("executions", DataType.int64(), [r["count"] for r in rows]),
         ("e2e_us", DataType.int64(),
          [int(r["e2e_s"] * 1e6) for r in rows]),
+        # thread CPU time of the statements' own threads: unlike the
+        # wall phases it does not stretch with the number of runnable
+        # threads under the interpreter lock
+        ("cpu_us", DataType.int64(),
+         [int(r["cpu_s"] * 1e6) for r in rows]),
         ("device_us", DataType.int64(),
          [int(r["device_s"] * 1e6) for r in rows]),
         ("chip_idle_pct", DataType.float64(),

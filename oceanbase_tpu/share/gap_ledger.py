@@ -38,6 +38,16 @@ Inner layers reach the statement's ledger through a thread-local
 (``current()``) set by ``server/database.py`` for the duration of the
 statement — the batcher and governor run their waits on the statement's
 own thread, so no API plumbing is needed to get hints home.
+
+While a ``jax.profiler`` session is active the same phases are also
+written into the profiler's own trace, as leaf
+``TraceAnnotation("ob:<phase>", stmt=<id>)`` events on the statement's
+thread: an idle gap of the device timeline then carries the phase the
+host was in.  The profiler's state is read once per statement
+(``begin``); with no session ``stmt`` stays 0 and every hook below is
+one attribute test.  One annotation is open at a time (``_mark``), so
+leaves never nest and never overlap on a thread; phases in which the
+thread is parked by design (``BLOCKED_PHASES``) are never written.
 """
 from __future__ import annotations
 
@@ -72,6 +82,31 @@ PHASE_ORDER = (
 )
 
 
+# Phases in which the statement's thread is parked by design. They are
+# ledger phases like any other, but never trace annotations: with eight
+# workers one is always waiting, and a waiting thread is not what the
+# host was doing in a device-idle gap.
+BLOCKED_PHASES = frozenset((
+    "admission queue", "tenant permit", "batch window",
+    "governor reserve", "retry backoff",
+))
+
+TRACE_PREFIX = "ob:"
+
+_TraceAnnotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use: this
+    module stays stdlib-only for the offline tools that import it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
 def phase_sort_key(name: str) -> Tuple[int, str]:
     try:
         return (PHASE_ORDER.index(name), name)
@@ -83,7 +118,8 @@ class GapLedger:
     """Conservation accounting for one statement's e2e wall."""
 
     __slots__ = ("clock", "t0", "phases", "device_s", "_pending",
-                 "_win_t0", "_cursor", "e2e_s", "unattributed_s", "closed")
+                 "_win_t0", "_cursor", "e2e_s", "unattributed_s", "closed",
+                 "cpu_s", "stmt", "_ann", "_ann_phase", "_win_phase")
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self.clock = clock
@@ -96,13 +132,30 @@ class GapLedger:
         self.e2e_s = 0.0
         self.unattributed_s = 0.0
         self.closed = False
+        # thread CPU seconds of the statement (time.thread_time delta,
+        # set by the caller that owns the thread): wall phases stretch
+        # with the number of runnable threads under one interpreter
+        # lock, thread CPU time does not
+        self.cpu_s = 0.0
+        # trace annotations: the statement's id while a profiler
+        # session is active, else 0; the one open annotation, the phase
+        # it names, and the phase a window falls back to between leaves
+        self.stmt = 0
+        self._ann = None
+        self._ann_phase: Optional[str] = None
+        self._win_phase: Optional[str] = None
 
     # -- lifecycle ----------------------------------------------------
-    def begin(self, t0: Optional[float] = None) -> "GapLedger":
+    def begin(self, t0: Optional[float] = None,
+              stmt: int = 0) -> "GapLedger":
         """(Re)arm for one statement.  Fully resets state: the serving
         session reuses ONE ledger object per session instead of
         allocating ledger + dicts per statement (the fast path is
-        ~200us end to end; allocator/GC churn there is measurable)."""
+        ~200us end to end; allocator/GC churn there is measurable).
+
+        ``stmt`` (nonzero) offers the statement to the profiler: if a
+        session is active its phases are annotated under that id,
+        starting with ``setup``."""
         self.t0 = self.clock() if t0 is None else t0
         self._cursor = self.t0
         if self.phases:
@@ -111,12 +164,23 @@ class GapLedger:
         self._pending = None
         self.e2e_s = 0.0
         self.unattributed_s = 0.0
+        self.cpu_s = 0.0
         self.closed = False
+        if self._ann is not None:  # a statement that never closed
+            self._mark(None)
+        self._win_phase = None
+        if stmt and _trace_annotation().is_enabled():
+            self.stmt = stmt
+            self._mark("setup")
+        else:
+            self.stmt = 0
         return self
 
     def close(self, t_end: Optional[float] = None) -> "GapLedger":
         if self._pending is not None:  # unbalanced window: flush clamped
             self.window_end()
+        if self.stmt:
+            self._mark(None)
         self.e2e_s = max(0.0, (self.clock() if t_end is None else t_end)
                          - self.t0)
         attributed = sum(self.phases.values())
@@ -144,9 +208,12 @@ class GapLedger:
             # serial cursor so a following cut() doesn't re-cover it
             self._cursor = self.clock()
 
-    def cut(self, phase: str) -> None:
+    def cut(self, phase: str, then: Optional[str] = None) -> None:
         """Attribute ALL wall since the last cut/add/window (or begin)
-        to ``phase`` and advance the cursor.
+        to ``phase`` and advance the cursor.  ``then`` names the phase
+        the thread works in from here on (the next cut's, as far as the
+        site knows): a cut learns its phase when the interval ENDS, a
+        trace annotation must be named when it STARTS.
 
         The serial serving path uses contiguous cuts instead of paired
         perf_counter reads: every nanosecond of inter-span glue (context
@@ -164,6 +231,39 @@ class GapLedger:
         self._cursor = now
         if dt > 0.0:
             self.phases[phase] = self.phases.get(phase, 0.0) + dt
+        if self.stmt and then != self._ann_phase:
+            self._mark(then)
+
+    # -- trace annotations ---------------------------------------------
+    def _mark(self, phase: Optional[str]) -> None:
+        """Close the open annotation; open ``ob:<phase>`` unless the
+        phase is None or one the thread is parked in."""
+        ann = self._ann
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if phase is None or phase in BLOCKED_PHASES:
+            self._ann = self._ann_phase = None
+            return
+        ann = _trace_annotation()(TRACE_PREFIX + phase, stmt=self.stmt)
+        ann.__enter__()
+        self._ann, self._ann_phase = ann, phase
+
+    def leaf(self, phase: Optional[str]) -> None:
+        """The thread enters ``phase`` (a span some site measures with
+        its own clock pair); None parks it (a blocked wait)."""
+        if self.stmt:
+            self._mark(phase)
+
+    def leaf_end(self) -> None:
+        """Back to the enclosing window's remainder phase, if any."""
+        if self.stmt:
+            self._mark(self._win_phase)
+
+    def tag(self, **stats) -> None:
+        """Stats onto the open annotation (the digest, known only at
+        completion, rides the statement's last leaf)."""
+        if self._ann is not None:
+            self._ann.set_metadata(**stats)
 
     def device(self, seconds: float) -> None:
         """Record device-busy wall overlapping this statement."""
@@ -171,9 +271,17 @@ class GapLedger:
             self.device_s += seconds
 
     # -- measured windows ---------------------------------------------
-    def window_start(self) -> None:
+    def window_start(self, remainder: Optional[str] = None) -> None:
+        """Open a measured window.  ``remainder`` names what the thread
+        does between the leaves inside it: the phase ``window_end`` gives
+        the un-hinted wall to (for the batcher's window that is so only
+        when it degrades to a solo run; a batched lane's glue is the
+        ledger's unattributed residual, and ``engine host`` here)."""
         self._pending = []
         self._win_t0 = self.clock()
+        if self.stmt:
+            self._win_phase = remainder
+            self._mark(remainder)
 
     def window_end(self, default_phase: Optional[str] = None) -> float:
         """Close the window; distribute buffered hints over its wall.
@@ -189,6 +297,11 @@ class GapLedger:
         pending, self._pending = self._pending, None
         now = self.clock()
         self._cursor = now  # the serial timeline resumes at window end
+        if self.stmt:
+            # what follows a window on the serial path is the return to
+            # the completion cut
+            self._win_phase = None
+            self._mark("completion fold")
         wall = max(0.0, now - self._win_t0)
         hinted = sum(s for _p, s in (pending or ()))
         scale = 1.0
@@ -356,6 +469,14 @@ def current() -> Optional[GapLedger]:
     return getattr(_tls, "led", None)
 
 
+def tracing() -> Optional[GapLedger]:
+    """The thread's ledger if its statement is being annotated into a
+    profiler trace, else None: what a site that brackets a phase with
+    ``leaf``/``leaf_end`` tests first."""
+    led = getattr(_tls, "led", None)
+    return led if led is not None and led.stmt else None
+
+
 class LedgerSnapshot:
     """Frozen copy of a closed ledger's fold-relevant surface.
 
@@ -363,15 +484,16 @@ class LedgerSnapshot:
     re-arms it in place), so completion work deferred behind the wire
     write (server/completion.py) must never hold the live object — it
     would read the NEXT statement's numbers. HostTaxRegistry.fold reads
-    exactly these four attributes, so a snapshot substitutes."""
+    exactly these five attributes, so a snapshot substitutes."""
 
-    __slots__ = ("e2e_s", "device_s", "unattributed_s", "phases")
+    __slots__ = ("e2e_s", "device_s", "unattributed_s", "phases", "cpu_s")
 
     def __init__(self, led: GapLedger):
         self.e2e_s = led.e2e_s
         self.device_s = led.device_s
         self.unattributed_s = led.unattributed_s
         self.phases = dict(led.phases)
+        self.cpu_s = led.cpu_s
 
     @property
     def chip_idle_pct(self) -> float:
@@ -430,7 +552,7 @@ class HostTaxRegistry:
                                  self._agg[d]["e2e_s"])
                     del self._agg[victim]
                 a = {"count": 0, "e2e_s": 0.0, "device_s": 0.0,
-                     "unattributed_s": 0.0, "phases": {}}
+                     "unattributed_s": 0.0, "cpu_s": 0.0, "phases": {}}
                 self._agg[digest] = a
             b = self._bucket(self.clock())
             a["count"] += 1
@@ -441,6 +563,7 @@ class HostTaxRegistry:
             b["device_s"] += led.device_s
             a["unattributed_s"] += led.unattributed_s
             b["unattributed_s"] += led.unattributed_s
+            a["cpu_s"] += led.cpu_s
             ph, bp = a["phases"], b["phases"]
             for k, v in led.phases.items():
                 ph[k] = ph.get(k, 0.0) + v
@@ -470,6 +593,7 @@ class HostTaxRegistry:
                     "e2e_s": a["e2e_s"],
                     "device_s": a["device_s"],
                     "unattributed_s": a["unattributed_s"],
+                    "cpu_s": a["cpu_s"],
                     "phases": dict(a["phases"]),
                 }
             wins = [dict(w, phases=dict(w["phases"]))
@@ -503,6 +627,7 @@ class HostTaxRegistry:
                 "digest": d,
                 "count": a["count"],
                 "e2e_s": e2e,
+                "cpu_s": a["cpu_s"],
                 "device_s": a["device_s"],
                 "chip_idle_pct": idle,
                 "unattributed_s": a["unattributed_s"],

@@ -15,8 +15,7 @@ QUERY_RESPONSE_TIME. The rebuild keeps the same three shapes:
 
 One registry per Database (per tenant). Everything is guarded by a single
 lock — the hot-path cost is one dict lookup + float add, and the
-`enabled` flag turns every record call into a cheap early return so the
-overhead bench (tools/obs_overhead_bench.py) can compare on/off.
+`enabled` flag turns every record call into a cheap early return.
 
 Device-side note: nothing here may be called from traced/jitted code
 (Python side effects don't survive tracing). All recording happens at the

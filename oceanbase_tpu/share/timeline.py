@@ -24,8 +24,7 @@ Every record call is a handful of GIL-atomic scalar adds into the
 current bucket — no lock on the hot path (the ring lock guards only
 bucket resets and readers; a preempted increment can drop a count,
 which telemetry tolerates). `enabled = False` turns each record into
-an attribute read; the obs_overhead_bench timeline A/B leg measures
-exactly this switch under 32 serving threads.
+an attribute read.
 
 Readout: __all_virtual_server_timeline / __all_virtual_tenant_qos
 virtual tables, Database.metrics_text() gauges, and WorkloadRepository
@@ -170,8 +169,8 @@ class ServingTimeline:
     # ------------------------------------------------------------ feeds
     #
     # The record_* hot path takes NO lock: under 32 serving threads the
-    # single ring lock convoys and costs ~6% of throughput (measured by
-    # obs_overhead_bench's timeline A/B, budget 2%). The adds are plain
+    # single ring lock convoys and costs ~6% of throughput (a CPU A/B
+    # of PR 7). The adds are plain
     # CPython scalar/list increments — a preempted read-modify-write can
     # drop a count, which telemetry tolerates; the lock guards only the
     # once-per-period bucket reset and the reader methods below.

@@ -137,6 +137,18 @@ class Window(LogicalOp):
     ]
 
 
+def op_kind(op) -> str:
+    """Display kind of one plan node (JoinOp carries its join kind —
+    an anti join and an inner join calibrate very differently). The
+    operator profiler's records and the device trace's plan-node scopes
+    (engine/executor.py ``node_scope``) share it."""
+    k = type(op).__name__
+    kind = getattr(op, "kind", None)
+    if k in ("JoinOp", "SetOp") and kind:
+        return f"{k[:-2] if k == 'JoinOp' else k}:{kind}"
+    return k
+
+
 def unique_key_sets(unique_keys: dict, table: str) -> tuple:
     """Every declared unique key of `table`, each a tuple of column
     names. A catalog entry is either one key (``("a", "b")``, what the

@@ -14,6 +14,8 @@ described chip cannot be read back without one). The CPU runs first, so
 each program is compiled at its settled capacities.
 """
 
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -133,6 +135,24 @@ def test_narrow_frame_compiles_for_v5e(chip, point_read):
     assert prepared._narrow, "the CPU run did not take the fused frame"
     for fn in prepared._narrow.values():
         _compile(fn, shapes(prepared._inputs()), shapes(qp))
+
+
+def test_q14_scopes_survive_the_v5e_compiler(chip, tpch):
+    """The names are HLO metadata: the v5e text of Q14's served program
+    (plan + result frame) keeps the plan-node, `expr` and `frame` scopes
+    as `op_name`s, and is called `jit_ob_select_<fingerprint>_narrow`.
+    What the device's trace shows of them only a chip run can say."""
+    shapes, _ = chip
+    sess = Session(tpch, unique_keys=UNIQUE_KEYS)
+    prepared, qp = _entry(sess, QUERIES[14])
+    assert prepared._narrow, "the CPU run did not take the fused frame"
+    fn = next(iter(prepared._narrow.values()))
+    text = _compile(fn, shapes(prepared._inputs()), shapes(qp)).as_text()
+    assert re.match(r"HloModule jit_ob_select_[0-9a-f]{8}_narrow\b", text)
+    ops = re.findall(r'op_name="([^"]*)"', text)
+    for want in (r"/Join:inner#\d+/", r"/Aggregate#\d+/",
+                 r"/Aggregate#\d+/expr/", r"/frame/"):
+        assert any(re.search(want, n) for n in ops), want
 
 
 def test_filtered_knn_compiles_for_v5e(chip):

@@ -312,8 +312,8 @@ def run_serve_leg(db, nsessions: int, seconds: float, wait_us: int,
     for t in threads:
         t.join()
     wall = time.perf_counter() - t_start
-    # process CPU over the measured window only (all threads): the
-    # low-noise numerator obs_overhead_bench's paired A/B gates on
+    # process CPU over the measured window only (all threads): a
+    # lower-noise numerator than the 1-2 s throughput readings
     cpu_s = time.process_time() - cpu_start
     c1 = db.metrics.counters_snapshot()
 
