@@ -12,10 +12,15 @@ sql/engine/expr/ob_expr_eval_functions.cpp:554). Differences by design:
   max scale, * adds scales (promoting storage to int64), / leaves the decimal
   domain and produces float (matching how the reference routes decimal
   division through lib/number only on the CPU).
-- String predicates (=, <, LIKE, IN) on dictionary-encoded columns are
-  evaluated once against the host-side dictionary, producing either a code
-  threshold (sorted dicts) or a boolean lookup table that becomes a gather on
-  device — the global-dictionary version of the reference's dict-decoder
+- String predicates (=, <, LIKE, IN, substr, fts_match, ...) on
+  dictionary-encoded columns are evaluated once against the host-side
+  dictionary, producing either a code threshold (sorted dicts) or a table
+  with one entry per dictionary value. dict_lookup() is the one place that
+  turns such a table into a per-row device array, and it tests the code
+  where it can instead of gathering: a boolean table whose true entries form
+  a few runs (every prefix LIKE on a sorted dictionary, short IN-lists)
+  becomes range compares, and only scattered or numeric tables stay a
+  gather — the global-dictionary version of the reference's dict-decoder
   pushdown filters (storage/blocksstable/encoding/ob_dict_decoder_simd.cpp).
 - NULL semantics: separate validity masks, Kleene AND/OR, comparisons yield
   NULL if either side is NULL; filters treat NULL as reject. (Reference:
@@ -28,6 +33,7 @@ parsing) folds into compile-time constants; everything per-row becomes XLA.
 from __future__ import annotations
 
 import re
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -230,6 +236,74 @@ def _merge_valid(*vs):
     return out
 
 
+# dict_lookup's limit: at most this many runs of true entries become range
+# compares. From a sweep on a TPU v5e (tools/dict_lookup_sweep.py;
+# CHANGES.md, PR 27), 6 M codes fused into a sum: a gather costs 48-49 ms at
+# any table of 150 entries or more, 32 runs 0.8-1.0 ms, beside 0.65 ms for
+# the sum alone. 32 is the most the sweep measured.
+LOOKUP_MAX_RUNS = 32
+
+_lookup_tls = threading.local()
+
+
+def set_lookup_metrics(metrics):
+    """Install the registry (share/metrics.py) that counts this thread's
+    dict_lookup choices as `dict lookup <lowering>`; returns the previous
+    one. The server sets it for the length of a statement: lowerings are
+    chosen at trace time, so the counters move per compiled program."""
+    prev = getattr(_lookup_tls, "metrics", None)
+    _lookup_tls.metrics = metrics
+    return prev
+
+
+def _true_runs(table: np.ndarray) -> list[tuple[int, int]]:
+    """[lo, hi) of each run of true entries."""
+    edges = np.flatnonzero(np.diff(np.r_[False, table, False].view(np.int8)))
+    return list(zip(edges[::2].tolist(), edges[1::2].tolist()))
+
+
+def dict_lookup(table: np.ndarray, codes) -> jnp.ndarray:
+    """Per-row values of a host table with one entry per dictionary value:
+    bit for bit `table[clip(codes, 0, len(table) - 1)]` (an empty table
+    answers false / zero), lowered by what the table itself shows.
+
+      constant  boolean, all true or all false: a broadcast constant
+      runs      boolean, the true entries in at most LOOKUP_MAX_RUNS runs:
+                the OR of `lo <= c < hi`, a one-entry run as `c == lo` —
+                every prefix LIKE over a sorted dictionary, at any
+                dictionary size, and short IN-lists
+      gather    everything else (scattered boolean tables, numeric
+                tables): an XLA gather
+
+    The first two are elementwise and fuse into their consumer; on a TPU
+    the gather is a kernel of its own that costs 8 ns per row whatever the
+    table's size (150 entries or 200 K)."""
+    table = np.asarray(table)
+    n = len(table)
+    m = getattr(_lookup_tls, "metrics", None)
+
+    def chose(lowering):
+        if m is not None:
+            m.add(f"dict lookup {lowering}")
+
+    if n == 0 or (table.dtype == np.bool_ and table.all() == table.any()):
+        chose("constant")
+        fill = table[0] if n else np.zeros((), table.dtype)
+        return jnp.full(jnp.shape(codes), fill, dtype=table.dtype)
+    c = jnp.clip(codes, 0, n - 1)
+    if table.dtype == np.bool_:
+        runs = _true_runs(table)
+        if len(runs) <= LOOKUP_MAX_RUNS:
+            chose("runs")
+            out = None
+            for lo, hi in runs:
+                t = c == lo if hi == lo + 1 else (c >= lo) & (c < hi)
+                out = t if out is None else out | t
+            return out
+    chose("gather")
+    return jnp.asarray(table)[c]
+
+
 def _rescale_decimal(vals, from_scale: int, to_scale: int):
     if to_scale == from_scale:
         return vals
@@ -263,7 +337,8 @@ def evaluate(e: Expr, batch: ColumnBatch):
 
     Traced under the named scope `expr`, inside the scope of the plan
     node that asked: a device trace tells an operator's expression work
-    (a LIKE lookup table, a decimal rescale) from its own (a gather)."""
+    (a string predicate's dict_lookup, a decimal rescale) from its own
+    (a gather)."""
     with jax.named_scope("expr"):
         return _evaluate(e, batch)
 
@@ -507,7 +582,7 @@ def _eval_compare(e: Compare, batch: ColumnBatch):
             flip = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}
             op = flip.get(e.op, e.op)
             return _dict_compare(e.right, op, e.left.value, batch)
-        # string transforms (substr) vs literal: boolean LUT over the view
+        # string transforms (substr) vs literal: boolean table over the view
         if isinstance(e.right, Literal):
             view = _string_view(e.left, batch)
             if view is not None:
@@ -520,8 +595,7 @@ def _eval_compare(e: Compare, batch: ColumnBatch):
                     ),
                     dtype=np.bool_, count=len(vals),
                 )
-                n = max(len(vals) - 1, 0)
-                return jnp.asarray(lut)[jnp.clip(codes, 0, n)], valid
+                return dict_lookup(lut, codes), valid
         if lt.kind is TypeKind.VARCHAR and rt.kind is TypeKind.VARCHAR:
             # col-vs-col code comparison is only sound when both columns
             # share one dictionary object (e.g. post-join copies); distinct
@@ -567,11 +641,11 @@ def _dict_compare(col_expr: ColRef, op: str, value: str, batch: ColumnBatch):
     if op in ("!=", "<>"):
         code = d.encode_one(value, add=False)
         return codes != jnp.asarray(code, dtype=jnp.int32), valid
-    # general fallback: boolean LUT over dictionary values
+    # general fallback: boolean table over dictionary values
     lut = np.fromiter(
         (_CMP[op](v, value) for v in d.values()), dtype=np.bool_, count=len(d)
     )
-    return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(d) - 1, 0))], valid
+    return dict_lookup(lut, codes), valid
 
 
 def _eval_cast(e: Cast, batch: ColumnBatch):
@@ -579,8 +653,8 @@ def _eval_cast(e: Cast, batch: ColumnBatch):
     dst = e.dtype
     if src_t.kind is TypeKind.VARCHAR and dst.kind is not TypeKind.VARCHAR:
         # string -> number through the dictionary: parse each DISTINCT
-        # value once into a numeric LUT (unparseable -> SQL NULL); this is
-        # what makes predicates on extracted JSON scalars pushable —
+        # value once into a numeric table (unparseable -> SQL NULL); this
+        # is what makes predicates on extracted JSON scalars pushable —
         # CAST(j->>'$.price' AS decimal) compiles to one gather + compare
         view = _string_view(e.arg, batch)
         if view is None:
@@ -605,10 +679,8 @@ def _eval_cast(e: Cast, batch: ColumnBatch):
             (0.0 if x is None else x for x in nums), dtype=np.float64,
             count=len(nums),
         )
-        n = max(len(vals) - 1, 0)
-        cl = jnp.clip(codes, 0, n)
-        fv = jnp.asarray(fl)[cl]
-        valid = _merge_valid(valid, jnp.asarray(nn)[cl])
+        fv = dict_lookup(fl, codes)
+        valid = _merge_valid(valid, dict_lookup(nn, codes))
         if dst.is_decimal:
             out = jnp.round(fv * dst.decimal_factor).astype(dst.storage_np)
         elif dst.is_integer:
@@ -663,7 +735,7 @@ def _eval_in_list(e: InList, batch: ColumnBatch):
             (v is not None and v in members for v in vals),
             dtype=np.bool_, count=len(vals),
         )
-        out = jnp.asarray(lut)[jnp.clip(codes, 0, max(len(vals) - 1, 0))]
+        out = dict_lookup(lut, codes)
         return (~out if e.negated else out), valid
     v, valid = _evaluate(e.arg, batch)
     out = jnp.zeros(batch.capacity, dtype=jnp.bool_)
@@ -696,8 +768,9 @@ def _string_view(e: Expr, batch: ColumnBatch):
 
     Works for a dictionary-encoded column or a host-computable string
     transform of one (substr with literal bounds). The per-code value list
-    lets predicates become boolean LUTs indexed by code — the TPU-friendly
-    compile of string functions (strings never reach the device; this is the
+    lets predicates become boolean tables with one entry per code, which
+    dict_lookup turns into tests of the code — the TPU-friendly compile of
+    string functions (strings never reach the device; this is the
     global-dictionary analog of the reference's dict-encoded pushdowns,
     storage/blocksstable/encoding/ob_dict_decoder_simd.cpp).
     """
@@ -738,7 +811,7 @@ def _string_view(e: Expr, batch: ColumnBatch):
         # once per DISTINCT document, rows map by code; a None in vals is
         # SQL NULL and is folded into `valid` by _fold_view_nulls at the
         # consumer boundary (ob_expr_json_extract.cpp evaluates per row —
-        # the columnar LUT is the redesign)
+        # the per-document table is the redesign)
         from .jsonpath import (
             extract_repr,
             json_type_of,
@@ -767,13 +840,12 @@ def _string_view(e: Expr, batch: ColumnBatch):
 
 def _fold_view_nulls(codes, valid, vals):
     """NULL results in a string view (None entries) become row-level
-    invalidity; remaining values are safe to feed LUT builders."""
+    invalidity; remaining values are safe to feed table builders."""
     if any(v is None for v in vals):
         nn = np.fromiter(
             (v is not None for v in vals), dtype=np.bool_, count=len(vals)
         )
-        notnull = jnp.asarray(nn)[jnp.clip(codes, 0, max(len(vals) - 1, 0))]
-        valid = _merge_valid(valid, notnull)
+        valid = _merge_valid(valid, dict_lookup(nn, codes))
     return valid
 
 
@@ -792,9 +864,7 @@ def derive_dict_column(e: Expr, batch: ColumnBatch):
     valid = _fold_view_nulls(codes, valid, vals)
     safe = ["" if v is None else v for v in vals]  # NULL rows are invalid
     d2, mapping = Dictionary.from_strings_bulk(np.asarray(safe, dtype=str))
-    lut = jnp.asarray(mapping.astype(np.int32))
-    n = max(len(vals) - 1, 0)
-    return lut[jnp.clip(codes, 0, n)], valid, d2
+    return dict_lookup(mapping.astype(np.int32), codes), valid, d2
 
 
 def _eval_func(e: Func, batch: ColumnBatch):
@@ -804,6 +874,10 @@ def _eval_func(e: Func, batch: ColumnBatch):
         return {"extract_year": y, "extract_month": m, "extract_day": d}[e.name], valid
 
     if e.name == "like":
+        # the pattern runs once per DISTINCT value on the host; over a
+        # sorted dictionary the matches of a prefix pattern are one run of
+        # codes, so dict_lookup emits `lo <= code < hi` (TPC-H Q14's
+        # `p_type like 'PROMO%'`: two compares per row, no table)
         col_expr, pat = e.args
         assert isinstance(col_expr, ColRef) and isinstance(pat, Literal)
         d = batch.dicts[col_expr.name]
@@ -814,13 +888,14 @@ def _eval_func(e: Func, batch: ColumnBatch):
             count=len(d),
         )
         codes, valid = _evaluate(col_expr, batch)
-        return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(d) - 1, 0))], valid
+        return dict_lookup(lut, codes), valid
 
     if e.name == "fts_match":
         # word-level full-text match against a dict-encoded column: the
         # dictionary IS the index (reference: src/storage/fts tokenizes
         # raw rows into an inverted index; here every distinct value
-        # tokenizes ONCE into a boolean LUT and rows match by code)
+        # tokenizes ONCE into a boolean table and rows match by code:
+        # dict_lookup's range compares or gather, as the table's runs say)
         col_expr, q = e.args
         assert isinstance(col_expr, ColRef) and isinstance(q, Literal)
         d = batch.dicts[col_expr.name]
@@ -834,7 +909,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
             count=len(d),
         )
         codes, valid = _evaluate(col_expr, batch)
-        return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(d) - 1, 0))], valid
+        return dict_lookup(lut, codes), valid
 
     if e.name == "json_valid":
         view = _string_view(e.args[0], batch)
@@ -847,7 +922,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
             (v is not None and is_valid(v) for v in vals),
             dtype=np.bool_, count=len(vals),
         )
-        return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(vals) - 1, 0))], valid
+        return dict_lookup(lut, codes), valid
 
     if e.name == "json_array_length":
         from .jsonpath import array_length, parse_path
@@ -866,7 +941,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
             (0 if x is None else x for x in lens), dtype=np.int64,
             count=len(lens),
         )
-        return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(vals) - 1, 0))], valid
+        return dict_lookup(lut, codes), valid
 
     if e.name in STRING_VIEW_FUNCS and e.name != "substr":
         # value context without a dictionary sink (e.g. a join key):
@@ -882,7 +957,7 @@ def _eval_func(e: Func, batch: ColumnBatch):
         test = (lambda v: v.startswith(p)) if e.name == "prefix" else (lambda v: p in v)
         lut = np.fromiter((test(v) for v in d.values()), dtype=np.bool_, count=len(d))
         codes, valid = _evaluate(col_expr, batch)
-        return jnp.asarray(lut)[jnp.clip(codes, 0, max(len(d) - 1, 0))], valid
+        return dict_lookup(lut, codes), valid
 
     if e.name in ("vec_l2", "vec_ip", "vec_cosine"):
         # vector distances in matmul form (the n*d work lands on the MXU

@@ -36,6 +36,7 @@ from ..core.dtypes import DataType, Field, Schema, TypeKind
 from ..core.table import Table
 from ..log.palf import leader_of as _leader_of
 from ..engine.session import ResultSet, Session
+from ..expr import compile as _EC
 from ..rootserver import RootService
 from ..share import Config, LocationService
 from ..share import gap_ledger as _GL
@@ -2700,6 +2701,9 @@ class DbSession:
         # and the generator contextmanager is measurable per-statement
         prev_dl = _R.current_deadline()
         _R.set_current_deadline(deadline)
+        # programs traced on this thread count their dict_lookup lowerings
+        # (`dict lookup runs`, ...) into this tenant's registry
+        prev_lm = _EC.set_lookup_metrics(db.metrics)
         if led is not None:
             # interrupt + deadline registration (and admission metrics/
             # timeline above): small but real, and the residual gate is
@@ -2711,6 +2715,7 @@ class DbSession:
             if led is not None:
                 _GL.set_current(None)
             _R.set_current_deadline(prev_dl)
+            _EC.set_lookup_metrics(prev_lm)
             _I.set_current(prev)
             db._active_stmts.pop(self.session_id, None)
             db.interrupts[0].unregister(iid)

@@ -30,6 +30,7 @@ from oceanbase_tpu.models.tpch import datagen
 from oceanbase_tpu.models.tpch.sql_suite import QUERIES, UNIQUE_KEYS
 from oceanbase_tpu.parallel.mesh import SHARD_AXIS, make_mesh
 from oceanbase_tpu.parallel.px import PxExecutor
+from oceanbase_tpu.share.metrics import MetricsRegistry
 from oceanbase_tpu.sql.parser import parse
 from oceanbase_tpu.sql.plan_cache import bind, parameterize
 from oceanbase_tpu.storage.vector_index import register_vector_index
@@ -153,6 +154,28 @@ def test_q14_scopes_survive_the_v5e_compiler(chip, tpch):
     for want in (r"/Join:inner#\d+/", r"/Aggregate#\d+/",
                  r"/Aggregate#\d+/expr/", r"/frame/"):
         assert any(re.search(want, n) for n in ops), want
+
+
+def test_dict_lookup_limit_compiles_for_v5e_without_a_gather(chip):
+    """dict_lookup at its limit, LOOKUP_MAX_RUNS runs, over lineitem's
+    rows at SF 1 and fused as Q14 uses it: the v5e compiler takes it and
+    emits no gather."""
+    from oceanbase_tpu.expr import compile as C
+
+    shapes, _ = chip
+    codes = jax.ShapeDtypeStruct((6_000_640,), np.int32)
+    price = jax.ShapeDtypeStruct((6_000_640,), np.int64)
+    table = np.arange(4 * C.LOOKUP_MAX_RUNS) % 4 == 1
+    reg = MetricsRegistry()
+    prev = C.set_lookup_metrics(reg)
+    try:
+        fn = jax.jit(lambda c, v: jax.numpy.sum(
+            jax.numpy.where(C.dict_lookup(table, c), v, 0)))
+        text = _compile(fn, *shapes((codes, price))).as_text()
+    finally:
+        C.set_lookup_metrics(prev)
+    assert reg.counter("dict lookup runs") == 1
+    assert " gather(" not in text
 
 
 def test_filtered_knn_compiles_for_v5e(chip):

@@ -12,7 +12,8 @@ stderr beside its own lines:
                     seconds by `kind:ob:<phase>`, the six trace-derived
                     per-layer numbers of ISSUE 26, the eager modules
   named_counters    thread-CPU ms per statement and the front end's pool
-                    hand-off wait per statement over the measured window
+                    hand-off wait per statement over the measured window;
+                    the `dict lookup <lowering>` counters since the start
   ledger_vs_leaves  per phase, the seconds of the `ob:` leaves inside the
                     traced sub-windows beside the host-tax registry's delta
                     from `start_trace` to `stop_trace`
@@ -144,7 +145,12 @@ def main(argv) -> int:
                 "pool_depth_mean":
                     (b["depth_sum"] - a["depth_sum"]) / waits
                     if waits else None,
-                "statements": n}})
+                "statements": n,
+                # since process start: the lowerings are chosen when a
+                # program is traced, which the warm-up does
+                "dict_lookup": {
+                    k: db.metrics.counter(f"dict lookup {k}")
+                    for k in ("constant", "runs", "gather")}}})
         return counters(self)
 
     server.Served.counters = counters_and_mine
