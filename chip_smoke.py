@@ -13,9 +13,9 @@ jitted device program -> result frame, at deployment size:
                  read back from a second connection against a dict.
   vector         1M x 128 float32, IVF lists 1024 / nprobe 32; filtered
                  and unfiltered kNN, recall@10 against brute-force numpy.
-  --chips 4      only this: TPC-H Q1/Q6/Q3 under `set ob_px_dop = 4` on
-                 the four-device mesh against the same statements at
-                 dop 0.
+  --chips 4      only this: TPC-H Q1/Q6/Q3 on the four-device mesh (`alter
+                 system set ob_px_dop = 4`, as a deployment sets it)
+                 against the same statements under `set ob_px_dop = 0`.
 
 --sf scales TPC-H. Its default is 5, not the 10 of the repo's chip records:
 SF 10 passes on the chip but takes 1303 s with a cold compile cache (my chip
@@ -55,7 +55,6 @@ K = 10  # kNN limit
 VEC_DIM = 128
 # statement-path degradations that must not be what made a phase pass
 ZERO_DELTA = (
-    "px fallbacks",
     "stmt degraded chunked",
     "stmt degraded host",
     "device OOM retries",
@@ -667,17 +666,21 @@ def phase_px(ctx: Ctx) -> dict:
 
     from oceanbase_tpu.models.tpch.sql_suite import QUERIES
 
+    one_chip = ctx.connect()
+    load_tpch(ctx, one_chip, ["customer", "orders", "lineitem"])
+    # the deployment as the cell tpch-sf1-px4.join sets it: the tenant
+    # parameter, then a connection opened after it; the comparison leg
+    # overrides it for its own session
+    ctx.connect().query("alter system set ob_px_dop = 4")
     c = ctx.connect()
-    load_tpch(ctx, c, ["customer", "orders", "lineitem"])
+    one_chip.query("set ob_px_dop = 0")
     bad = []
     snap0 = ctx.db.metrics.counters_snapshot()
     for q in (6, 1, 3):
         text = QUERIES[q]
-        c.query("set ob_px_dop = 4")
         px_rows, cold = ctx.timed(c, text)
         _, warm = ctx.timed(c, text)
-        c.query("set ob_px_dop = 0")
-        one_rows, one = ctx.timed(c, text)
+        one_rows, one = ctx.timed(one_chip, text)
         same = same_rows(px_rows, one_rows)
         if not same:
             bad.append(f"Q{q}: dop 4 {px_rows[:2]} != dop 0 {one_rows[:2]}")
@@ -692,6 +695,10 @@ def phase_px(ctx: Ctx) -> dict:
           "ledger_per_device_bytes": px.residency.per_device_bytes(),
           "collectives": {k: v - snap0.get(k, 0) for k, v in snap1.items()
                           if k.startswith("px collective")}})
+    ran = snap1.get("px executions", 0) - snap0.get("px executions", 0)
+    if ran != 6:
+        bad.append(f"'px executions' moved by {ran}, not by the 6 "
+                   "statements sent at dop 4")
     if len(mesh_devices) != 4 or len(jax.devices()) < 4:
         bad.append(f"mesh holds {len(mesh_devices)} distinct devices")
     if set(per_dev) != mesh_devices or min(per_dev.values(), default=0) <= 0:

@@ -1014,6 +1014,14 @@ class Session:
             retries = getattr(prepared, "retries", 0) - retries0
             if retries > 0:
                 m.add("overflow recompiles", retries)
+            if getattr(prepared, "px_nsh", 0):
+                # the served PX route prepares and dispatches here, not
+                # through PxExecutor.execute: count it where it runs
+                m.add("px executions")
+                if retries > 0:
+                    # an exchange lane or a join capacity of a mesh
+                    # program overflowed: each is one more PX compile
+                    m.add("px overflow recompiles", retries)
             params = getattr(prepared, "params", None)
             vts = getattr(params, "vector_topns", None)
             if vts:

@@ -25,11 +25,21 @@ The TPU redesign collapses the DFO graph into ONE shard_map program:
 
 Every intermediate carries a distribution state, the DFO data-layout
 analog: SHARDED (rows split over the mesh axis) or REPLICATED (every
-device holds all rows). Placement rules:
+device holds all rows), each with a variant that also knows the rows'
+ORDER, which the direct-address join needs (`_affine_build_info`):
+ROW_SLICED is SHARDED with shard i holding rows [i*n, (i+1)*n) of a base
+Scan in storage order (only the sel mask differs), TABLE_ORDER is
+REPLICATED with every row of a base Scan at its storage index (a
+ROW_SLICED batch gathered whole). Placement rules:
 
-  scan -> SHARDED.  filter/project preserve.
-  join: build(right) REPLICATED -> local; small build -> broadcast build;
-        else hash-repartition both sides on the join keys.
+  scan -> ROW_SLICED.  filter/project preserve; so does the probe side
+        of a join that emits probe columns untouched (semi/anti, the
+        merge/affine inner join) when nothing exchanged it. Every hash
+        or range exchange -> SHARDED: the order is gone for good.
+  join: build(right) REPLICATED -> local; small build -> broadcast build
+        (ROW_SLICED build -> TABLE_ORDER, the one case that may take the
+        direct-address join); else hash-repartition both sides on the
+        join keys.
   group-by: small-domain direct aggregation -> local partials + merge
         (REPLICATED out); generic hash group-by -> hash-repartition on the
         group keys (SHARDED out); scalar aggregate -> partials + merge.
@@ -83,6 +93,12 @@ from .spmd import ShardedResidency, SpmdLowering, shard_put
 
 SHARDED = "sharded"
 REPLICATED = "replicated"
+ROW_SLICED = "row_sliced"  # SHARDED, and a base Scan's contiguous row slice
+TABLE_ORDER = "table_order"  # REPLICATED, and a base Scan's rows in place
+
+
+def _is_sharded(dist: str) -> bool:
+    return dist in (SHARDED, ROW_SLICED)
 
 # synthesized PhysicalParams ids for exchange lanes (disjoint from plan
 # node ids, which are small pre-order indexes)
@@ -189,10 +205,29 @@ class PxExecutor(Executor):
         return src
 
     def _affine_build_info(self, op):
-        # inside shard_map every batch is a per-shard SLICE (and hash
-        # exchanges reorder rows), so the storage-layout affinity the
-        # direct-address join relies on does not hold: always sort-merge
+        # inside shard_map a batch is a per-shard slice, and a hash or
+        # range exchange reorders rows besides, so the storage-layout
+        # affinity the direct-address join relies on does not hold, with
+        # one exception: a ROW_SLICED build side gathered whole lies in
+        # the table's storage order again (shard i's block at offset
+        # i * n, the padding behind the last row). `_emit_join_px` marks
+        # that build TABLE_ORDER; every other join sort-merges.
+        if self._dist.get(id(op.right)) == TABLE_ORDER:
+            return super()._affine_build_info(op)
         return None
+
+    # the distribution state of every node of the plan being traced. One
+    # PxExecutor serves every session of a Database and a program is
+    # traced on the thread that first calls it, so the state is the
+    # thread's own: two sessions compiling at once do not read each
+    # other's layout.
+    @property
+    def _dist(self) -> dict[int, str]:
+        return self._trace_local.__dict__.setdefault("dist", {})
+
+    @_dist.setter
+    def _dist(self, value: dict[int, str]) -> None:
+        self._trace_local.dist = value
 
     def __init__(self, catalog, mesh: Mesh, unique_keys=None,
                  default_rows_estimate=1 << 16,
@@ -249,7 +284,7 @@ class PxExecutor(Executor):
         # BEFORE the optimizer histograms — measured key frequencies beat
         # quantile-edge inference (JSPIM's sampled skew detection)
         self.access = access
-        self._dist: dict[int, str] = {}
+        self._trace_local = threading.local()
         # observability hooks (server/diag.Tracer + share/metrics registry).
         # Exchange helpers run INSIDE traced shard_map code, so accounting
         # happens host-side: once per compile at emission time (static
@@ -314,6 +349,7 @@ class PxExecutor(Executor):
             t0 = _time.perf_counter()
             prepared = self.prepare(plan)
             compile_s = _time.perf_counter() - t0
+            retries0 = getattr(prepared, "retries", 0)
             t0 = _time.perf_counter()
             out = prepared.run(max_retries)
             exec_s = _time.perf_counter() - t0
@@ -332,6 +368,9 @@ class PxExecutor(Executor):
                 root.tags["exec_us"] = int(exec_s * 1e6)
             if m is not None:
                 m.add("px executions")
+                retries = getattr(prepared, "retries", 0) - retries0
+                if retries > 0:
+                    m.add("px overflow recompiles", retries)
                 m.observe("px compile", compile_s)
                 m.observe("px execute", exec_s)
                 m.wait("px dispatch", exec_s)
@@ -438,9 +477,13 @@ class PxExecutor(Executor):
 
         def lane_cap(rows: float) -> int:
             # per (src,dst) lane of an all_to_all: expected rows/nsh^2
-            # with 2x skew headroom
-            c = int(rows * 2 / (self.nsh * self.nsh)) + 512
-            return -(-c // 128) * 128
+            # with 2x skew headroom, on the power-of-two grid the root
+            # compaction and the result frames use. A capacity is a shape
+            # of the program: on a 128-row grid an estimate that moves by
+            # a third of a per cent (other data of the same distribution,
+            # another first literal) was another program to compile, and
+            # a persistent compile cache never hit
+            return next_pow2(int(rows * 2 / (self.nsh * self.nsh)) + 512)
 
         for nid, op in nodes.items():
             if isinstance(op, JoinOp) and op.left_keys:
@@ -502,48 +545,65 @@ class PxExecutor(Executor):
         return None
 
     # -------------------------------------------------------- exchanges
-    def _gather_batch(self, b: ColumnBatch) -> ColumnBatch:
+    def _gather_batch(self, b: ColumnBatch, lane,
+                      kind: str = "broadcast") -> ColumnBatch:
         """GATHER/BROADCAST: replicate all rows on every shard, via
-        all_gather (bisection) or the ppermute ring per broadcast_impl."""
+        all_gather (bisection) or the ppermute ring per broadcast_impl.
+        The HLO it emits carries `Exchange:<kind>#<lane>`, as a plan
+        node's carries `<kind>#<nid>` (`_emit_scoped`): `broadcast` where
+        a join's build side goes to every probe shard, `gather` where a
+        sharded relation is collected for a replicated operator (top-n,
+        sort; the statement's root, whose lane is the root node's id)."""
         ring = self.broadcast_impl == "ring"
         self._note_exchange("broadcast", len(b.cols) + len(b.valid),
                             int(b.sel.shape[0]),
                             collective="ppermute" if ring else "all_gather")
         payload = {f"c:{n}": a for n, a in b.cols.items()}
         payload.update({f"v:{n}": a for n, a in b.valid.items()})
-        if ring:
-            out, mask = ring_broadcast_rows(payload, b.sel, self.nsh)
-        else:
-            out, mask = broadcast_rows(payload, b.sel)
+        with jax.named_scope(f"Exchange:{kind}#{lane}"):
+            if ring:
+                out, mask = ring_broadcast_rows(payload, b.sel, self.nsh)
+            else:
+                out, mask = broadcast_rows(payload, b.sel)
+            nrows = jnp.sum(mask, dtype=jnp.int64)
         return ColumnBatch(
             cols={n: out[f"c:{n}"] for n in b.cols},
             valid={n: out[f"v:{n}"] for n in b.valid},
             sel=mask,
-            nrows=jnp.sum(mask, dtype=jnp.int64),
+            nrows=nrows,
             schema=b.schema,
             dicts=b.dicts,
         )
 
-    def _exchange_dest(self, b: ColumnBatch, dest, cap: int):
-        """Redistribute rows of a batch to per-row dest shards (all_to_all)."""
+    def _exchange_dest(self, b: ColumnBatch, dest_of, cap: int, lane: int,
+                       kind: str = "hash"):
+        """Redistribute rows of a batch to the shards `dest_of()` names,
+        row by row (all_to_all), `cap` rows a lane. The destination
+        computation, the lane packing, the collective and what follows it
+        carry `Exchange:<kind>#<lane>` in their HLO."""
         self._note_exchange("repartition", len(b.cols) + len(b.valid), cap)
         payload = {f"c:{n}": a for n, a in b.cols.items()}
         payload.update({f"v:{n}": a for n, a in b.valid.items()})
-        out, mask, ovf = repartition(payload, b.sel, dest, self.nsh, cap)
+        with jax.named_scope(f"Exchange:{kind}#{lane}"):
+            out, mask, ovf = repartition(
+                payload, b.sel, dest_of(), self.nsh, cap)
+            nrows = jnp.sum(mask, dtype=jnp.int64)
         nb = ColumnBatch(
             cols={n: out[f"c:{n}"] for n in b.cols},
             valid={n: out[f"v:{n}"] for n in b.valid},
             sel=mask,
-            nrows=jnp.sum(mask, dtype=jnp.int64),
+            nrows=nrows,
             schema=b.schema,
             dicts=b.dicts,
         )
         return nb, ovf
 
-    def _exchange_hash(self, b: ColumnBatch, key_exprs, cap: int):
+    def _exchange_hash(self, b: ColumnBatch, key_exprs, cap: int, lane: int):
         """HASH distribution: co-partition rows by key hash (all_to_all)."""
-        keys = [evaluate(e, b)[0] for e in key_exprs]
-        return self._exchange_dest(b, dest_by_hash(keys, self.nsh), cap)
+        return self._exchange_dest(
+            b, lambda: dest_by_hash(
+                [evaluate(e, b)[0] for e in key_exprs], self.nsh),
+            cap, lane)
 
     def _concat_batches(self, a: ColumnBatch, b: ColumnBatch) -> ColumnBatch:
         """Row-concatenate two same-schema batches (static capacities add)."""
@@ -558,7 +618,8 @@ class PxExecutor(Executor):
 
     def _hybrid_exchange(self, probe: ColumnBatch, probe_keys,
                          build: ColumnBatch, build_keys,
-                         cap_probe: int, cap_build: int):
+                         cap_probe: int, cap_build: int,
+                         lane_probe: int, lane_build: int):
         """HYBRID_HASH_BROADCAST/RANDOM: skew-adaptive repartition.
 
         The reference samples probe keys through the datahub and routes
@@ -592,7 +653,8 @@ class PxExecutor(Executor):
         p_pop = popular[ph] & probe.sel
 
         probe_norm, ox_p = self._exchange_hash(
-            probe.with_sel(probe.sel & ~p_pop), probe_keys, cap_probe)
+            probe.with_sel(probe.sel & ~p_pop), probe_keys, cap_probe,
+            lane_probe)
         probe_loc = probe.with_sel(p_pop)
         # align capacities: exchanged batch is nsh*cap rows; local popular
         # rows keep their original capacity — concat handles both
@@ -600,8 +662,9 @@ class PxExecutor(Executor):
 
         b_pop = popular[bh] & build.sel
         build_norm, ox_b = self._exchange_hash(
-            build.with_sel(build.sel & ~b_pop), build_keys, cap_build)
-        build_bc = self._gather_batch(build.with_sel(b_pop))
+            build.with_sel(build.sel & ~b_pop), build_keys, cap_build,
+            lane_build)
+        build_bc = self._gather_batch(build.with_sel(b_pop), lane_build)
         new_build = self._concat_batches(build_norm, build_bc)
         return new_probe, new_build, ox_p, ox_b
 
@@ -627,7 +690,7 @@ class PxExecutor(Executor):
 
         if isinstance(op, Scan):
             out, ovf = super()._emit_node(op, inputs, emit, params, id_of)
-            self._dist[id(op)] = SHARDED
+            self._dist[id(op)] = ROW_SLICED
             return out, ovf
 
         if isinstance(op, JoinOp):
@@ -644,10 +707,11 @@ class PxExecutor(Executor):
             # the small survivors, final top-n (the merge-sort-receive
             # coordinator analog, ob_px_ms_receive_vec_op.h)
             child, covf = emit(op.child, inputs)
-            if self._dist[id(op.child)] == SHARDED:
+            if _is_sharded(self._dist[id(op.child)]):
                 local = self._topn_batch(
                     child, op.keys, op.n, op.offset, apply_offset=False)
-                gathered = self._gather_batch(local)
+                gathered = self._gather_batch(
+                    local, _exch_id(nid, _SORT_CHILD), kind="gather")
                 out = self._topn_batch(gathered, op.keys, op.n, op.offset)
             else:
                 out = self._topn_batch(child, op.keys, op.n, op.offset)
@@ -661,7 +725,7 @@ class PxExecutor(Executor):
             # per-shard prelimit + compacted gather: moves O(n + offset)
             # rows per shard, never the relation
             child, covf = emit(op.child, inputs)
-            if self._dist[id(op.child)] == SHARDED:
+            if _is_sharded(self._dist[id(op.child)]):
                 from ..engine.executor import compact_batch
 
                 k = op.n + op.offset
@@ -669,7 +733,8 @@ class PxExecutor(Executor):
                 local = child.with_sel(child.sel & (pos < k))
                 cap2 = min(child.capacity, max(8, -(-k // 8) * 8))
                 local, _oc = compact_batch(local, cap2)  # k <= cap2: no ovf
-                child = self._gather_batch(local)
+                child = self._gather_batch(
+                    local, _exch_id(nid, _SORT_CHILD), kind="gather")
                 covf = dict(covf)
             out, ovf = super()._emit_node(
                 op, inputs, _override(emit, op.child, (child, covf)),
@@ -686,14 +751,14 @@ class PxExecutor(Executor):
             cd = self._dist[id(op.child)]
             exch = _exch_id(nid, _AGG_CHILD)
             if (
-                cd == SHARDED
+                _is_sharded(cd)
                 and exch in params.exchange_cap
                 and self._est_rows(op.child) > self.broadcast_threshold
             ):
-                keys = self._row_hash_keys(child)
                 child2, xovf = self._exchange_dest(
-                    child, dest_by_hash(keys, self.nsh),
-                    params.exchange_cap[exch])
+                    child, lambda: dest_by_hash(
+                        self._row_hash_keys(child), self.nsh),
+                    params.exchange_cap[exch], exch)
                 out, ovf = super()._emit_node(
                     op, inputs, _override(emit, op.child, (child2, covf)),
                     params, id_of)
@@ -701,8 +766,8 @@ class PxExecutor(Executor):
                 ovf[exch] = xovf
                 self._dist[id(op)] = SHARDED
                 return out, ovf
-            if cd == SHARDED:
-                child = self._gather_batch(child)
+            if _is_sharded(cd):
+                child = self._gather_batch(child, exch, kind="gather")
             out, ovf = super()._emit_node(
                 op, inputs, _override(emit, op.child, (child, covf)),
                 params, id_of)
@@ -727,16 +792,20 @@ class PxExecutor(Executor):
             k.astype(jnp.int32) if k.dtype == jnp.bool_ else k for k in keys
         ]
 
-    def _copartition_side(self, b: ColumnBatch, dist: str, cap: int):
+    def _copartition_side(self, b: ColumnBatch, dist: str, cap: int,
+                          lane: int):
         """Bring one promoted set-op side onto the whole-row hash
         partitioning. SHARDED: all_to_all exchange. REPLICATED: free —
         every shard already holds all rows, so each just keeps the ones
         hashing to itself (a mask, no collective)."""
-        dest = dest_by_hash(self._row_hash_keys(b), self.nsh)
+        def dest_of():
+            return dest_by_hash(self._row_hash_keys(b), self.nsh)
+
         if dist == REPLICATED:
+            dest = dest_of()
             me = lax.axis_index(SHARD_AXIS).astype(dest.dtype)
             return b.with_sel(b.sel & (dest == me)), None
-        return self._exchange_dest(b, dest, cap)
+        return self._exchange_dest(b, dest_of, cap, lane)
 
     def _emit_setop_px(self, op: SetOp, nid, inputs, emit, params, id_of):
         left, lovf = emit(op.left, inputs)
@@ -772,12 +841,14 @@ class PxExecutor(Executor):
             > self.broadcast_threshold
         )
         if big and cap_l is not None and cap_r is not None \
-                and (ld == SHARDED or rd == SHARDED):
+                and (_is_sharded(ld) or _is_sharded(rd)):
             # co-partition both sides by whole-row hash: every equal row
             # lands on one shard, so the local dedup/bag kernels are
             # globally exact and the output stays SHARDED
-            lb2, xl = self._copartition_side(lb, ld, cap_l)
-            rb2, xr = self._copartition_side(rb, rd, cap_r)
+            lb2, xl = self._copartition_side(
+                lb, ld, cap_l, _exch_id(nid, _JOIN_LEFT))
+            rb2, xr = self._copartition_side(
+                rb, rd, cap_r, _exch_id(nid, _JOIN_RIGHT))
             out, ovf = self._setop_combine(op, lb2, rb2, out_schema, dicts, ovf)
             ovf = dict(ovf)
             if xl is not None:
@@ -787,10 +858,12 @@ class PxExecutor(Executor):
             self._dist[id(op)] = SHARDED
             return out, ovf
 
-        if ld == SHARDED:
-            lb = self._gather_batch(lb)
-        if rd == SHARDED:
-            rb = self._gather_batch(rb)
+        if _is_sharded(ld):
+            lb = self._gather_batch(
+                lb, _exch_id(nid, _JOIN_LEFT), kind="gather")
+        if _is_sharded(rd):
+            rb = self._gather_batch(
+                rb, _exch_id(nid, _JOIN_RIGHT), kind="gather")
         out, ovf = self._setop_combine(op, lb, rb, out_schema, dicts, ovf)
         self._dist[id(op)] = REPLICATED
         return out, ovf
@@ -809,13 +882,13 @@ class PxExecutor(Executor):
         cd = self._dist[id(op.child)]
         exch = _exch_id(nid, _SORT_CHILD)
         use_range = (
-            cd == SHARDED
+            _is_sharded(cd)
             and exch in params.exchange_cap
             and self._est_rows(op.child) > self.broadcast_threshold
         )
         if not use_range:
-            if cd == SHARDED:
-                child = self._gather_batch(child)
+            if _is_sharded(cd):
+                child = self._gather_batch(child, exch, kind="gather")
             out, ovf = super()._emit_node(
                 op, inputs, _override(emit, op.child, (child, covf)),
                 params, id_of)
@@ -823,16 +896,18 @@ class PxExecutor(Executor):
             return out, ovf
 
         key_expr, desc0 = op.keys[0]
-        kv = evaluate(key_expr, child)[0]
         self._note_merge("range_sample", 1, 4096)
-        bounds = sample_range_bounds(kv, child.sel, self.nsh)
-        dest = dest_by_range(kv.astype(jnp.int64), bounds)
-        if desc0:
-            # shard 0 must hold the HIGHEST range so the gathered
-            # concatenation reads in descending order
-            dest = (self.nsh - 1) - dest
+
+        def dest_of():
+            kv = evaluate(key_expr, child)[0]
+            bounds = sample_range_bounds(kv, child.sel, self.nsh)
+            dest = dest_by_range(kv.astype(jnp.int64), bounds)
+            # shard 0 must hold the HIGHEST range of a descending sort so
+            # the gathered concatenation reads in descending order
+            return (self.nsh - 1) - dest if desc0 else dest
+
         child2, xovf = self._exchange_dest(
-            child, dest, params.exchange_cap[exch])
+            child, dest_of, params.exchange_cap[exch], exch, kind="range")
         out, ovf = super()._emit_node(
             op, inputs, _override(emit, op.child, (child2, covf)),
             params, id_of)
@@ -852,13 +927,13 @@ class PxExecutor(Executor):
         exch = _exch_id(nid, _AGG_CHILD)
         pk = self._window_common_pk(op)
         if (
-            cd == SHARDED
+            _is_sharded(cd)
             and pk is not None
             and exch in params.exchange_cap
             and self._est_rows(op.child) > self.broadcast_threshold
         ):
             child2, xovf = self._exchange_hash(
-                child, list(pk), params.exchange_cap[exch])
+                child, list(pk), params.exchange_cap[exch], exch)
             out, ovf = super()._emit_node(
                 op, inputs, _override(emit, op.child, (child2, covf)),
                 params, id_of)
@@ -866,8 +941,8 @@ class PxExecutor(Executor):
             ovf[exch] = xovf
             self._dist[id(op)] = SHARDED
             return out, ovf
-        if cd == SHARDED:
-            child = self._gather_batch(child)
+        if _is_sharded(cd):
+            child = self._gather_batch(child, exch, kind="gather")
         out, ovf = super()._emit_node(
             op, inputs, _override(emit, op.child, (child, covf)),
             params, id_of)
@@ -931,9 +1006,11 @@ class PxExecutor(Executor):
         right, rovf = emit(op.right, inputs)
         ld, rd = self._dist[id(op.left)], self._dist[id(op.right)]
         ovf = {**lovf, **rovf}
+        lane_l = _exch_id(nid, _JOIN_LEFT)
+        lane_r = _exch_id(nid, _JOIN_RIGHT)
 
         # choose distribution method (the optimizer's exchange allocation)
-        if op.kind == "full" and (ld == SHARDED or rd == SHARDED):
+        if op.kind == "full" and (_is_sharded(ld) or _is_sharded(rd)):
             # a broadcast build would duplicate unmatched-right rows on
             # every shard: FULL joins must co-partition both sides
             method = "hash" if op.left_keys else "gather_both"
@@ -961,8 +1038,8 @@ class PxExecutor(Executor):
                 left = self._bloom_prefilter(
                     left, op.left_keys, right, op.right_keys,
                     self._est_rows(op.right))
-            cap_l = params.exchange_cap[_exch_id(nid, _JOIN_LEFT)]
-            cap_r = params.exchange_cap[_exch_id(nid, _JOIN_RIGHT)]
+            cap_l = params.exchange_cap[lane_l]
+            cap_r = params.exchange_cap[lane_r]
             use_hybrid = op.kind == "inner" and (
                 self.hybrid_hash is True
                 or (
@@ -975,25 +1052,40 @@ class PxExecutor(Executor):
             )
             if use_hybrid:
                 left, right, xl, xr = self._hybrid_exchange(
-                    left, op.left_keys, right, op.right_keys, cap_l, cap_r)
+                    left, op.left_keys, right, op.right_keys, cap_l, cap_r,
+                    lane_l, lane_r)
             else:
-                left, xl = self._exchange_hash(left, op.left_keys, cap_l)
-                right, xr = self._exchange_hash(right, op.right_keys, cap_r)
+                left, xl = self._exchange_hash(
+                    left, op.left_keys, cap_l, lane_l)
+                right, xr = self._exchange_hash(
+                    right, op.right_keys, cap_r, lane_r)
             ovf = dict(ovf)
-            ovf[_exch_id(nid, _JOIN_LEFT)] = xl
-            ovf[_exch_id(nid, _JOIN_RIGHT)] = xr
+            ovf[lane_l] = xl
+            ovf[lane_r] = xr
+            self._dist[id(op.right)] = SHARDED  # exchanged: no order left
             out_dist = SHARDED
         elif method == "broadcast":
-            right = self._gather_batch(right)
+            right = self._gather_batch(right, lane_r)
+            # the batch that stands for op.right from here on: a scan's
+            # row slices gathered whole are the table in storage order
+            self._dist[id(op.right)] = (
+                TABLE_ORDER if rd == ROW_SLICED else REPLICATED)
             out_dist = ld
         elif method == "gather_both":
-            if ld == SHARDED:
-                left = self._gather_batch(left)
-            if rd == SHARDED:
-                right = self._gather_batch(right)
+            if _is_sharded(ld):
+                left = self._gather_batch(left, lane_l, kind="gather")
+            if _is_sharded(rd):
+                right = self._gather_batch(right, lane_r, kind="gather")
             out_dist = REPLICATED
         else:
             out_dist = ld
+        if out_dist == ROW_SLICED and not (
+            op.kind in ("semi", "anti")
+            or (op.kind == "inner" and self._merge_joinable(op))
+        ):
+            # only the joins `_resolve_layout_col` walks through emit the
+            # probe's columns untouched; an expanding join reorders them
+            out_dist = SHARDED
 
         emit2 = _override(
             _override(emit, op.left, (left, {})), op.right, (right, {}))
@@ -1006,6 +1098,7 @@ class PxExecutor(Executor):
     def _emit_agg_px(self, op, nid, inputs, emit, params, id_of):
         child, covf = emit(op.child, inputs)
         cd = self._dist[id(op.child)]
+        lane = _exch_id(nid, _AGG_CHILD)
 
         if cd == REPLICATED:
             out, ovf = super()._emit_aggregate(
@@ -1033,15 +1126,15 @@ class PxExecutor(Executor):
         distinct_args = {a[2] for a in op.aggs if a[3] or a[1] == "approx_ndv"}
         if distinct_args and not op.group_keys:
             if len(distinct_args) == 1:
-                cap = params.exchange_cap[_exch_id(nid, _AGG_CHILD)]
                 child, xovf = self._exchange_hash(
-                    child, [next(iter(distinct_args))], cap)
+                    child, [next(iter(distinct_args))],
+                    params.exchange_cap[lane], lane)
                 covf = dict(covf)
-                covf[_exch_id(nid, _AGG_CHILD)] = xovf
+                covf[lane] = xovf
             else:
                 # two different distinct domains cannot both colocate by
                 # one exchange: replicate (rare shape; correct, not fast)
-                child = self._gather_batch(child)
+                child = self._gather_batch(child, lane, kind="gather")
                 out, ovf = super()._emit_aggregate(
                     op, nid, inputs,
                     _override(emit, op.child, (child, covf)), params)
@@ -1063,37 +1156,38 @@ class PxExecutor(Executor):
                 "merge", len(out.cols) + len(out.valid) + 1,
                 int(out.sel.shape[0]))
             merged = dict(out.cols)
-            for name, fn, _arg, _d in op.aggs:
-                col = out.cols[name]
-                if fn in ("sum", "count", "approx_ndv"):
-                    merged[name] = lax.psum(col, SHARD_AXIS)
-                elif fn == "min":
-                    merged[name] = lax.pmin(col, SHARD_AXIS)
-                elif fn == "max":
-                    merged[name] = lax.pmax(col, SHARD_AXIS)
-                else:
-                    raise NotImplementedError(f"PX merge for {fn}")
-            sel = lax.psum(out.sel.astype(jnp.int32), SHARD_AXIS) > 0
-            valid = {
-                n: lax.psum(v.astype(jnp.int32), SHARD_AXIS) > 0
-                for n, v in out.valid.items()
-            }
+            with jax.named_scope(f"Exchange:merge#{nid}"):
+                for name, fn, _arg, _d in op.aggs:
+                    col = out.cols[name]
+                    if fn in ("sum", "count", "approx_ndv"):
+                        merged[name] = lax.psum(col, SHARD_AXIS)
+                    elif fn == "min":
+                        merged[name] = lax.pmin(col, SHARD_AXIS)
+                    elif fn == "max":
+                        merged[name] = lax.pmax(col, SHARD_AXIS)
+                    else:
+                        raise NotImplementedError(f"PX merge for {fn}")
+                sel = lax.psum(out.sel.astype(jnp.int32), SHARD_AXIS) > 0
+                valid = {
+                    n: lax.psum(v.astype(jnp.int32), SHARD_AXIS) > 0
+                    for n, v in out.valid.items()
+                }
+                nrows = jnp.sum(sel, dtype=jnp.int64)
             out = replace(
-                out, cols=merged, valid=valid, sel=sel,
-                nrows=jnp.sum(sel, dtype=jnp.int64),
+                out, cols=merged, valid=valid, sel=sel, nrows=nrows,
             )
             self._dist[id(op)] = REPLICATED
             return out, ovf
 
         # generic hash group-by: co-partition rows on the group keys, then
         # each shard owns its key space entirely
-        cap = params.exchange_cap[_exch_id(nid, _AGG_CHILD)]
         child2, xovf = self._exchange_hash(
-            child, [e for _, e in op.group_keys], cap)
+            child, [e for _, e in op.group_keys],
+            params.exchange_cap[lane], lane)
         out, ovf = super()._emit_aggregate(
             op, nid, inputs, _override(emit, op.child, (child2, covf)), params)
         ovf = dict(ovf)
-        ovf[_exch_id(nid, _AGG_CHILD)] = xovf
+        ovf[lane] = xovf
         self._dist[id(op)] = SHARDED
         return out, ovf
 
@@ -1186,8 +1280,11 @@ class PxExecutor(Executor):
             out, oc = compact_batch(out, params.join_cap[ROOT_COMPACT])
             ovf = dict(ovf)
             ovf[ROOT_COMPACT] = oc
-            if self._dist[id(plan)] == SHARDED:
-                out = self._gather_batch(out)
+            if _is_sharded(self._dist[id(plan)]):
+                # the statement's own gather: its lane is the root node's
+                # id, as a merge's is its aggregate's (every other lane is
+                # an `_exch_id`, a million and up)
+                out = self._gather_batch(out, id_of[id(plan)], kind="gather")
             # overflow counters must leave the shard_map replicated; psum
             # may multiply already-replicated counters by nsh, which is
             # harmless (the driver only tests >0)
@@ -1228,6 +1325,11 @@ class _PxChunkSourceExecutor(ChunkWindowMixin, PxExecutor):
     chunk executor; the slice/estimate logic lives in ChunkWindowMixin)."""
 
     chunking_enabled = False
+
+    def _affine_build_info(self, op):
+        # the streamed table's batch is one chunk of it, not the table
+        return None
+
     # legacy host-slice chunk loop: PX uploads must shard over the mesh
     # (jax.device_put of a staged pytree would land whole on one device),
     # so the streaming prefetch/decode pipeline stays single-chip

@@ -2573,12 +2573,13 @@ class DbSession:
         # and elections cap at 30s each)
         self._vars: dict[str, int] = {
             "ob_enable_show_trace": 0,
-            "ob_px_dop": 0,
             "ob_query_timeout": 100_000_000,
             "ob_trx_timeout": 500_000_000,
-            # cross-session micro-batching (server/batcher.py), seeded
-            # from the tenant config so ALTER SYSTEM moves the default
-            # for new sessions while SET overrides per session
+            # PX routing and cross-session micro-batching
+            # (server/batcher.py), seeded from the tenant config so ALTER
+            # SYSTEM moves the default for new sessions while SET
+            # overrides per session
+            "ob_px_dop": int(db.config["ob_px_dop"]),
             "ob_batch_max_size": int(db.config["ob_batch_max_size"]),
             "ob_batch_max_wait_us": int(db.config["ob_batch_max_wait_us"]),
             # read-consistency routing (0 strong / 1 bounded_staleness /
@@ -4545,24 +4546,15 @@ class DbSession:
                and names == raw_names else None)
         try:
             with self.db.catalog.tx_scope(views):
-                try:
-                    rs = self.db.engine.run_ast(
-                        ast, norm_key,
-                        use_cache=False if any_vt else None,
-                        executor=px,
-                        fast_reg=reg,
-                    )
-                except Exception:
-                    if px is None:
-                        raise
-                    # PX degradation: distributed compile/execute failures
-                    # fall back to the single-chip path (genuine SQL
-                    # errors re-raise identically from it)
-                    self.db.metrics.add("px fallbacks")
-                    rs = self.db.engine.run_ast(
-                        ast, norm_key,
-                        use_cache=False if any_vt else None,
-                    )
+                # a PX compile or execution failure is the statement's
+                # error (the retry controller sorts retryable from not):
+                # no re-run on one chip behind the operator's back
+                rs = self.db.engine.run_ast(
+                    ast, norm_key,
+                    use_cache=False if any_vt else None,
+                    executor=px,
+                    fast_reg=reg,
+                )
             # surfaces in the audit record; for DML the qualification
             # scan's plan reuse IS the statement's plan-cache behavior
             self._stmt_cache_hit = rs.plan_cache_hit

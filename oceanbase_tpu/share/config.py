@@ -108,8 +108,11 @@ def default_params() -> list[Param]:
         Param("parallel_servers_target", "int", 64,
               "cluster-wide PX worker admission quota", scope="cluster",
               min=0),
-        Param("ob_sql_parallel_degree", "int", 8,
-              "default DOP for PX plans", min=1, max=4096),
+        Param("ob_px_dop", "int", 0,
+              "degree of parallelism new sessions start with: above 0 "
+              "their SELECTs run as one SPMD program over the device "
+              "mesh (PX); SET ob_px_dop overrides per session",
+              min=0, max=4096),
         Param("ob_batch_max_size", "int", 16,
               "cross-session micro-batching: max fast-path statements "
               "folded into one batched device dispatch (1 disables "

@@ -96,9 +96,9 @@ def test_big_distinct_repartitions_not_gathers(env):
     gathered = []
 
     class Spy(PxExecutor):
-        def _gather_batch(self, b):
+        def _gather_batch(self, b, *a, **kw):
             gathered.append(b.capacity)
-            return super()._gather_batch(b)
+            return super()._gather_batch(b, *a, **kw)
 
     px = Spy(tables, make_mesh(8), unique_keys=UNIQUE_KEYS,
              broadcast_threshold=1024)
@@ -125,9 +125,9 @@ def test_big_setops_copartition_not_gather(env):
     gathered = []
 
     class Spy(PxExecutor):
-        def _gather_batch(self, b):
+        def _gather_batch(self, b, *a, **kw):
             gathered.append(b.capacity)
-            return super()._gather_batch(b)
+            return super()._gather_batch(b, *a, **kw)
 
     for sql in (
         "select l_suppkey from lineitem union select s_suppkey from supplier",
@@ -192,6 +192,90 @@ def test_auto_hybrid_hash_on_skew(env):
     want = batch_rows_normalized(single, planned.output_names)
     assert got == want
     assert hybrid_calls, "skewed join did not choose hybrid-hash"
+
+
+def _join_layouts(tables, sql_text, **kw):
+    """Run `sql_text` under a 4-shard PxExecutor; return its rows and, per
+    join in emission order, (build state, output state, (a0, stride) the
+    direct-address join took or None)."""
+    seen = []
+
+    class Spy(PxExecutor):
+        def _emit_join_px(self, op, nid, *a):
+            out = super()._emit_join_px(op, nid, *a)
+            seen.append((self._dist[id(op.right)], self._dist[id(op)],
+                         self._affine_build_info(op)))
+            return out
+
+    px = Spy(tables, make_mesh(4), unique_keys=UNIQUE_KEYS, **kw)
+    planned = Planner(tables).plan(parse(sql_text))
+    rows = batch_rows_normalized(px.execute(planned.plan),
+                                 planned.output_names)
+    return rows, seen
+
+
+def test_direct_address_join_needs_a_build_in_table_order(env):
+    """The direct-address join indexes the build side by storage position.
+    Under PX that holds only for a scan's row slices gathered whole
+    (TABLE_ORDER). A build that a hash exchange reordered further down
+    (`customer` semi-joined to `orders` by hash lanes, then broadcast to
+    the probing `orders`) must sort-merge: addressed by position it loses
+    every match, silently. Against numpy, not the one-chip executor."""
+    import numpy as np
+
+    from oceanbase_tpu.parallel.px import (
+        REPLICATED, ROW_SLICED, SHARDED, TABLE_ORDER)
+
+    tables = env["tables"]
+    cu, od = tables["customer"].data, tables["orders"].data
+    day = int(np.datetime64("1995-03-15").astype("datetime64[D]").astype(int))
+    building = tables["customer"].dicts["c_mktsegment"].encode_one(
+        "BUILDING", add=False)
+    early = np.unique(od["o_custkey"][od["o_orderdate"] < day])
+    keep = cu["c_custkey"][(cu["c_mktsegment"] == building)
+                           & np.isin(cu["c_custkey"], early)]
+    hit = np.isin(od["o_custkey"], keep)
+    want = [(int(hit.sum()), int(od["o_totalprice"][hit].sum()) / 100)]
+
+    rows, joins = _join_layouts(tables, """
+        select count(*) as n, sum(o_totalprice) as q from orders
+        where o_custkey in (
+            select c_custkey from customer
+            where c_mktsegment = 'BUILDING'
+              and c_custkey in (select o_custkey from orders
+                                where o_orderdate < date '1995-03-15'))
+    """, broadcast_threshold=1000)
+    assert rows == want and want[0][0] > 0
+    # the nested join ran over hash lanes; the outer one broadcast that
+    # reordered build and did not address it by position
+    assert joins == [(SHARDED, SHARDED, None),
+                     (REPLICATED, ROW_SLICED, None)], joins
+
+    # a scan (under its filter) broadcast whole is the table in storage
+    # order: this join is the one that may address directly
+    hit = np.isin(od["o_custkey"],
+                  cu["c_custkey"][cu["c_mktsegment"] == building])
+    rows, joins = _join_layouts(tables, """
+        select count(*) as n from orders where o_custkey in (
+            select c_custkey from customer where c_mktsegment = 'BUILDING')
+    """)
+    assert rows == [(int(hit.sum()),)]
+    assert joins == [(TABLE_ORDER, ROW_SLICED, (1, 1))], joins
+
+
+def test_px_trace_state_is_per_thread(env):
+    """One PxExecutor serves every session of a Database: the layout of
+    the plan one thread is tracing is not another thread's to read."""
+    import threading
+
+    px = env["px"]
+    px._dist = {1: "mine"}
+    other = {}
+    t = threading.Thread(target=lambda: other.update(px._dist))
+    t.start()
+    t.join()
+    assert other == {} and px._dist == {1: "mine"}
+    px._dist = {}
 
 
 def test_admission_quota():

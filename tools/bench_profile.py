@@ -10,10 +10,19 @@ stderr beside its own lines:
   named_breakdown   the profile reduced by `benchmark/harness/spans.py`: device
                     seconds by `kind:Node#nid[/expr]/primitive`, idle-gap
                     seconds by `kind:ob:<phase>`, the six trace-derived
-                    per-layer numbers of ISSUE 26, the eager modules
+                    per-layer numbers of ISSUE 26 and
+                    `exchange_device_ms_per_stmt` (device time whose
+                    innermost scope is `Exchange:*`, ISSUE 28), the eager
+                    modules
   named_counters    thread-CPU ms per statement and the front end's pool
                     hand-off wait per statement over the measured window;
-                    the `dict lookup <lowering>` counters since the start
+                    the `dict lookup <lowering>` counters since the start;
+                    on a PX deployment the `px ...` counters' deltas over
+                    the window, the mesh's devices and each one's peak and
+                    row-sharded bytes
+  slow_statements   the window's three longest statements by the audit ring,
+                    with their phases and the garbage collections of 20 ms
+                    and more that overlap them
   ledger_vs_leaves  per phase, the seconds of the `ob:` leaves inside the
                     traced sub-windows beside the host-tax registry's delta
                     from `start_trace` to `stop_trace`
@@ -54,18 +63,29 @@ def main(argv) -> int:
     keep = mine[mine.index("--keep") + 1] if "--keep" in mine else None
 
     from benchmark.harness import server, spans
+    from chip_smoke import row_sharded_bytes_per_device
     from benchmark.harness import trace as T
 
     read_events = T.read_events
 
     def read_and_name(logdir):
         path = spans.profile_path(logdir)
-        red = spans.reduce_spans(spans.read_spans(path))
+        ev = spans.read_spans(path)
+        # seconds are the mean over the chips that ran ops, as trace.py's
+        red = spans.reduce_spans(ev, len({op[0] for op in ev["ops"]}) or 1)
         # statements completed in the sub-windows: by their last leaf,
         # or (a program that writes none) one program launch each
         statements = {k: v["completed"] or v["programs"]
                       for k, v in red["per_kind"].items()}
-        leaves = spans.quantities(red)["phase_s"]
+        q = spans.quantities(red)
+        leaves = q["phase_s"]
+        total = sum(statements.values())
+        exchange_s = {k: v for k, v in q["scope_s"].items()
+                      if k.startswith("Exchange:")}
+        metrics = spans.metrics(red, total)
+        metrics["exchange_device_ms_per_stmt"] = (
+            sum(exchange_s.values()) / total * 1000.0
+            if exchange_s and total else None)
         if len(traced) == 2:
             log({"ledger_vs_leaves": {
                 ph: [leaves.get(ph), traced[1].get(ph, 0.0)
@@ -81,7 +101,7 @@ def main(argv) -> int:
              "phase_events": red["phase_events"],
              "phase_overlaps": red["phase_overlaps"],
              "statements": statements,
-             "metrics": spans.metrics(red, sum(statements.values())),
+             "metrics": metrics,
              "xplane_bytes": os.path.getsize(path)})
         if keep:
             os.makedirs(keep, exist_ok=True)
@@ -119,12 +139,73 @@ def main(argv) -> int:
     jax.profiler.start_trace = start_and_note
     jax.profiler.stop_trace = stop_and_note
 
+    def px_state(db):
+        """The PX route's counters, and where its rows live (None on a
+        deployment whose statements run on one chip)."""
+        px = db._px_executor_obj
+        if px is None:
+            return None
+        return {"counters": {k: v for k, v in
+                             db.metrics.counters_snapshot().items()
+                             if k.startswith("px ")},
+                "mesh_devices": [str(d) for d in px.mesh.devices.flat],
+                "row_sharded_bytes": row_sharded_bytes_per_device(),
+                "peak_bytes_in_use": {
+                    str(d): (d.memory_stats() or {}).get("peak_bytes_in_use")
+                    for d in px.mesh.devices.flat}}
+
+    # garbage collections of 20 ms and more, on the wall clock the audit
+    # ring stamps statements with: (generation, start, seconds)
+    import gc
+    import time
+
+    gc_pauses, gc_open = [], {}
+
+    def note_gc(phase, info):
+        if phase == "start":
+            gc_open[info["generation"]] = time.time()
+        else:
+            t0 = gc_open.pop(info["generation"], None)
+            if t0 is not None and time.time() - t0 >= 0.02:
+                gc_pauses.append((info["generation"], t0, time.time() - t0))
+
+    gc.callbacks.append(note_gc)
+
+    def slow_statements(db, t0, t1):
+        """The window's three longest statements by the server's own clock
+        (the audit ring), each with the phases the record carries and the
+        collections that overlap it: what a statement that runs 200 ms over
+        its kind was doing (PERF.md section 7)."""
+        recs = [r for r in list(db.audit._ring)
+                if t0 <= r.ts <= t1 and r.stmt_type == "Select"]
+        out = []
+        for r in sorted(recs, key=lambda r: r.elapsed_s)[-3:]:
+            begin = r.ts - r.elapsed_s
+            out.append({
+                "sql": r.sql[:40], "begin": begin - t0,
+                "elapsed_ms": r.elapsed_s * 1e3,
+                "compile_ms": r.compile_s * 1e3,
+                "dispatch_ms": r.dispatch_us / 1e3,
+                "fetch_ms": r.fetch_us / 1e3,
+                "chip_idle_ms": r.chip_idle_us / 1e3,
+                "unattributed_ms": r.unattributed_us / 1e3,
+                "retry_cnt": r.retry_cnt,
+                "gc": [{"generation": g, "at": at - t0, "ms": d * 1e3}
+                       for g, at, d in gc_pauses
+                       if at < r.ts and at + d > begin]})
+        return {"statements": len(recs), "slowest": out,
+                "gc_pauses_in_window": [
+                    {"generation": g, "at": at - t0, "ms": d * 1e3}
+                    for g, at, d in gc_pauses if t0 <= at <= t1]}
+
     def counters_and_mine(self):
         db = self.db
         served[:] = [self]
         tax = db.host_tax.snapshot()["digests"]
         w = db.metrics.wait_event("front pool queue")
         seen.append({
+            "at": time.time(),
+            "px": px_state(db),
             "statements": sum(a["count"] for a in tax.values()),
             "cpu_s": sum(a.get("cpu_s", 0.0) for a in tax.values()),
             "has_cpu": any("cpu_s" in a for a in tax.values()),
@@ -151,6 +232,12 @@ def main(argv) -> int:
                 "dict_lookup": {
                     k: db.metrics.counter(f"dict lookup {k}")
                     for k in ("constant", "runs", "gather")}}})
+            log({"slow_statements": slow_statements(db, a["at"], b["at"])})
+            if b["px"] is not None:
+                before = (a["px"] or {}).get("counters", {})
+                log({"named_px": dict(b["px"], counters={
+                    k: v - before.get(k, 0)
+                    for k, v in b["px"]["counters"].items()})})
         return counters(self)
 
     server.Served.counters = counters_and_mine
