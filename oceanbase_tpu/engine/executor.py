@@ -3905,12 +3905,26 @@ class DeviceResult:
     fast path: `SELECT ... LIMIT 10` over a 60M-row result must transfer
     KB, not GB).
 
-    The first host access fetches ONLY the overflow counters and the live
-    row count (two scalars — this is the async-dispatch sync point; a
-    capacity overflow redrives the recompile loop here, exactly as
-    run_host's eager loop would have). Column data transfers on demand:
-    per touched column, or LIMIT-bounded via a device-side compaction
-    gather when the caller wants the first k rows of a large result."""
+    `start_copies` (called once at dispatch, and again on every redriven
+    output) starts the device-to-host copy of every leaf the completion
+    sync will read, while the program still runs, so `_sync` waits for
+    the program once and finds the copies landed or landing. Which leaves
+    those are depends on the frame's static bytes alone:
+
+      * at most FRAME_PREFETCH_BYTES: the overflow counters and the whole
+        frame (columns, validity vectors, sel);
+      * larger: ONLY the overflow counters and the live row count (two
+        scalars). Column data transfers on demand: per touched column, or
+        LIMIT-bounded via a device-side compaction gather when the caller
+        wants the first k rows of a large result.
+
+    The sync is the async-dispatch sync point; a capacity overflow
+    redrives the recompile loop here, exactly as run_host's eager loop
+    would have."""
+
+    # a frame this small (bytes of its leaves' static shapes) crosses the
+    # link whole with the completion sync; a larger one stays lazy
+    FRAME_PREFETCH_BYTES = 65536
 
     def __init__(self, prepared, qparams, out, ovf_vec, max_retries: int = 3,
                  profile=None, phases=None):
@@ -3928,6 +3942,27 @@ class DeviceResult:
         self._hcols: dict = {}
         self._hvalid: dict = {}
         self._hsel = None
+        # set by start_copies: the frame's static bytes, and whether the
+        # whole of it was started (and so is what _sync reads)
+        self.frame_bytes = 0
+        self.prefetched = False
+
+    # True where the program itself has bounded the frame: it crosses
+    # whole whatever its bytes (NarrowDeviceResult)
+    _frame_bounded = False
+
+    def start_copies(self) -> None:
+        """Start the device-to-host copy of exactly the leaves `_sync` is
+        going to read (nothing blocks here: the runtime queues each copy
+        behind the program that defines its buffer)."""
+        out = self._out
+        frame = [*out.cols.values(), *out.valid.values(), out.sel]
+        self.frame_bytes = sum(int(a.nbytes) for a in frame)
+        self.prefetched = (self._frame_bounded
+                           or self.frame_bytes <= self.FRAME_PREFETCH_BYTES)
+        for a in ([self._ovf, *frame] if self.prefetched
+                  else [self._ovf, out.nrows]):
+            a.copy_to_host_async()
 
     def _observe(self, seconds: float, nbytes: int,
                  kind: str = "sync") -> None:
@@ -3952,32 +3987,22 @@ class DeviceResult:
         from ..share.interrupt import checkpoint
 
         p = self.prepared
-        # serving-latency fold: when the whole result footprint is small
-        # (known from the per-executable memo), piggyback the column data
-        # on the completion sync — ONE host roundtrip instead of a second
-        # device_get when the client fetches. Big results keep the lazy
-        # contract (transfer only what's touched).
-        rmemo = getattr(p, "_result_bytes_memo", None)
-        small = (rmemo is not None and rmemo[0] == getattr(p, "retries", 0)
-                 and rmemo[1] <= 65536 and not self._hcols
-                 and self._hsel is None)
         for attempt in range(self._max_retries + 1):
             t0 = _time.perf_counter()
+            # every read below was started by start_copies: the first
+            # waits for the program, the rest are landed or landing
+            small = self.prefetched
+            hovf = np.asarray(self._ovf)
             if small:
-                # per-leaf np.asarray: same blocking semantics, none of
-                # device_get's pytree + async-batching overhead (~16us a
-                # statement for a handful of KB-sized leaves). The device
-                # nrows scalar is sum(sel); with sel crossing anyway the
-                # sum runs host-side — one fewer transfer leaf.
-                hovf = np.asarray(self._ovf)
                 harrs = {n: np.asarray(a)
                          for n, a in self._out.cols.items()}
                 hvals = {n: np.asarray(a)
                          for n, a in self._out.valid.items()}
                 hsel = np.asarray(self._out.sel)
+                # the device nrows scalar is sum(sel); with sel crossing
+                # anyway the sum runs host-side, one fewer leaf
                 hn = int(hsel.sum())
             else:
-                hovf = np.asarray(self._ovf)
                 hn = int(np.asarray(self._out.nrows))
             self._observe(_time.perf_counter() - t0,
                           int(getattr(hovf, "nbytes", 0)) + 8)
@@ -4004,6 +4029,7 @@ class DeviceResult:
             p.recompile()
             checkpoint()
             self._out, self._ovf = p.jit_call(p._inputs(), self._qparams)
+            self.start_copies()
 
     @property
     def nrows(self) -> int:
@@ -4100,11 +4126,11 @@ class DeviceResult:
 class NarrowDeviceResult(DeviceResult):
     """DeviceResult over a FUSED narrowed dispatch: `out` is the final
     ncap-row result frame (plan program + compaction gather in one XLA
-    program), so the completion sync fetches the entire client-visible
-    payload in one host roundtrip — no separate d2h leg and no
-    O(capacity) host result fold. A frame overflow grows the pow2 width
-    and redrives; past the configured ceiling the plan surrenders fusion
-    and this cursor falls back to the plain lazy contract."""
+    program), so the whole client-visible payload is in flight from
+    dispatch on and the completion sync reads it — no separate d2h leg
+    and no O(capacity) host result fold. A frame overflow grows the pow2
+    width and redrives; past the configured ceiling the plan surrenders
+    fusion and this cursor falls back to the plain lazy contract."""
 
     narrowed = True
 
@@ -4119,6 +4145,16 @@ class NarrowDeviceResult(DeviceResult):
         self._narrow_max = int(narrow_max)
         self._fallback = False
 
+    @property
+    def _frame_bounded(self) -> bool:
+        # the fused program has already cut the frame to ncap rows
+        return not self._fallback
+
+    def start_copies(self) -> None:
+        super().start_copies()
+        if not self._fallback:
+            self._novf.copy_to_host_async()
+
     def _sync(self) -> None:
         if self._nrows is not None:
             return
@@ -4131,10 +4167,8 @@ class NarrowDeviceResult(DeviceResult):
         p = self.prepared
         for attempt in range(self._max_retries + 1):
             t0 = _time.perf_counter()
-            # the frame IS the result: per-leaf blocking np.asarray of
-            # overflow counters + every (ncap-row) leaf — the base small
-            # path's one-roundtrip shape, made unconditional by the fused
-            # program having already bounded the frame
+            # the frame IS the result: the overflow counters and every
+            # (ncap-row) leaf, each started by start_copies
             hovf = np.asarray(self._ovf)
             hnovf = int(np.asarray(self._novf))
             harrs = {n: np.asarray(a) for n, a in self._out.cols.items()}
@@ -4175,11 +4209,13 @@ class NarrowDeviceResult(DeviceResult):
                     checkpoint()
                     self._out, self._ovf = p.jit_call(
                         p._inputs(), self._qparams)
+                    self.start_copies()
                     return super()._sync()
                 self._ncap = grown
             checkpoint()
             self._out, self._ovf, self._novf = p.run_device_narrow(
                 self._qparams, self._ncap)
+            self.start_copies()
         raise AssertionError
 
 

@@ -57,10 +57,12 @@ class ResultSet:
 class LazyResultSet:
     """Device-resident ResultSet: same read surface as ResultSet, but
     column data stays on the TPU behind a DeviceResult cursor until a
-    host access touches it. `nrows` costs two scalars (the async-dispatch
-    sync point — overflow redrive happens there); `.columns` fetches
-    everything once; `column(name)` transfers only that column;
-    `rows(limit=k)` transfers only k compacted rows per column."""
+    host access touches it. `nrows` is the async-dispatch sync point
+    (overflow redrive happens there): it reads what the cursor started
+    copying at dispatch, the whole frame when it is small, two scalars
+    when it is not. Over a large frame `.columns` fetches everything
+    once; `column(name)` transfers only that column; `rows(limit=k)`
+    transfers only k compacted rows per column."""
 
     def __init__(self, names: tuple[str, ...], cursor, affected: int = 0,
                  plan_cache_hit: bool = False, fast_path_hit: bool = False):
@@ -686,10 +688,11 @@ class Session:
 
         Single-chip plans take the LAZY route: dispatch is async
         (PreparedPlan.run_device returns device references immediately),
-        sql_audit/metrics/trace host work overlaps device compute, and the
-        only in-statement sync is the overflow-counter + row-count fetch.
-        Column data stays device-resident behind the DeviceResult cursor
-        until the caller touches it."""
+        the cursor starts the device-to-host copies its sync will read
+        right behind the program, sql_audit/metrics/trace host work
+        overlaps both, and the only in-statement wait is that sync. A
+        frame over DeviceResult.FRAME_PREFETCH_BYTES stays device-resident
+        behind the cursor until the caller touches it."""
         from ..share.errsim import errsim_point
         from ..sql.json_host import apply_host_json
 
@@ -788,6 +791,10 @@ class Session:
                     self.narrow_max_rows)
             else:
                 cursor = DeviceResult(prepared, qparams, out, ovf_vec)
+            # every leaf the completion sync will read starts crossing
+            # the link now, behind the program: the bookkeeping below
+            # overlaps the program AND the transfers
+            cursor.start_copies()
             rs = LazyResultSet(entry.output_names, cursor,
                                plan_cache_hit=was_hit, fast_path_hit=fast)
         elif hasattr(prepared, "run_host"):
@@ -849,27 +856,10 @@ class Session:
                     prepared._dev_bytes_memo = (
                         ex.h2d_bytes, ex, device_bytes)
             if lazy:
-                # result footprint measured on-device (no transfer): the
-                # cursor adds actual d2h bytes as fetches happen. Output
-                # shapes are static per compiled executable, so warm
-                # statements reuse the walk (invalidated by a recompile)
-                rmemo = getattr(prepared, "_result_bytes_memo", None)
-                if narrow is not None:
-                    # narrowed frame bytes — NOT memoized: the memo feeds
-                    # the base cursor's small-result heuristic against
-                    # the UN-narrowed output shape
-                    result_bytes = sum(
-                        int(getattr(a, "nbytes", 0))
-                        for d in (out.cols, out.valid) for a in d.values()
-                    ) + int(getattr(out.sel, "nbytes", 0))
-                elif rmemo is not None and rmemo[0] == retries0:
-                    result_bytes = rmemo[1]
-                else:
-                    result_bytes = sum(
-                        int(getattr(a, "nbytes", 0))
-                        for d in (out.cols, out.valid) for a in d.values()
-                    ) + int(getattr(out.sel, "nbytes", 0))
-                    prepared._result_bytes_memo = (retries0, result_bytes)
+                # result footprint from the frame's static shapes (no
+                # transfer): the cursor adds actual d2h bytes as fetches
+                # happen
+                result_bytes = cursor.frame_bytes
             else:
                 result_bytes = d2h_bytes
             # peak working set: device-resident inputs + the result's
@@ -904,9 +894,9 @@ class Session:
         self.last_phases = phases
         if lazy:
             # wire the in-place observability sinks, THEN force the sync
-            # point: the overflow check + row count (two scalars). All the
-            # host work above overlapped device compute. The sync wall IS
-            # the statement's device wait — time it (host-tax ledger's
+            # point: the overflow check + what start_copies put in flight.
+            # All the host work above overlapped device compute. The sync
+            # wall IS the statement's device wait — time it (host-tax ledger's
             # "device wait" phase reads fetch_s; leaving it 0.0 hid the
             # chip time inside exec_s).
             cursor.profile = profile
@@ -1011,6 +1001,11 @@ class Session:
             m.add("result rows returned", nrows)
             if narrow is not None:
                 m.add("stmt fused dispatches")
+            if lazy:
+                # by what the sync read: a narrow frame that fell back
+                # over the ceiling finished lazy
+                m.add("result frames prefetched" if cursor.prefetched
+                      else "result frames lazy")
             retries = getattr(prepared, "retries", 0) - retries0
             if retries > 0:
                 m.add("overflow recompiles", retries)

@@ -17,12 +17,19 @@ stderr beside its own lines:
   named_counters    thread-CPU ms per statement and the front end's pool
                     hand-off wait per statement over the measured window;
                     the `dict lookup <lowering>` counters since the start;
+                    the `result frames prefetched` / `result frames lazy`
+                    counters' deltas over the window;
                     on a PX deployment the `px ...` counters' deltas over
                     the window, the mesh's devices and each one's peak and
                     row-sharded bytes
   slow_statements   the window's three longest statements by the audit ring,
                     with their phases and the garbage collections of 20 ms
                     and more that overlap them
+  device_wait_split per kind, the mean `ob:device wait` leaf of the traced
+                    sub-windows in its parts: ms before the program's first
+                    op, ms with an op running, ms between ops, ms after its
+                    last op (the tail: the result frame crossing the link),
+                    and the kind's whole idle ms per statement beside them
   ledger_vs_leaves  per phase, the seconds of the `ob:` leaves inside the
                     traced sub-windows beside the host-tax registry's delta
                     from `start_trace` to `stop_trace`
@@ -39,6 +46,7 @@ process with the run.
 
 from __future__ import annotations
 
+import bisect
 import gzip
 import json
 import os
@@ -52,6 +60,105 @@ sys.path.insert(0, ROOT)
 
 def log(obj) -> None:
     print(json.dumps(obj, default=float), file=sys.stderr, flush=True)
+
+
+WAIT_PHASE = "device wait"
+
+
+def wait_split(ev: dict, statements: dict, idle_s: dict) -> dict:
+    """Per window kind, the mean `ob:device wait` leaf in milliseconds:
+    before the first device op that overlaps it, with an op running,
+    between ops, after the last op (mean over the chips that ran ops), the
+    leaves no op overlapped, and the kind's whole idle time per statement
+    (`trace.py` gives a whole gap to the phase that overlaps it most, so
+    that number also holds the host path between two statements).
+
+    The host's leaves and the device's ops are on one clock only to within
+    some 0.5 ms, another offset in every process, and the before and after
+    parts carry it. `around_program_ms` does not: the start of the
+    statement's `device dispatch` leaf to the end of its wait leaf (host
+    clock) less `program_ms`, the statement programs' own span (device
+    clock): launch and tail together."""
+    from benchmark.harness import spans
+
+    _merge = spans.T._merge
+    by_dev, programs = {}, {}
+    for op in ev["ops"]:
+        by_dev.setdefault(op[0], []).append((op[1], op[1] + op[2]))
+    for dev, name, s, d in ev["modules"]:
+        if name.startswith(spans.PROGRAM_PREFIX):
+            programs.setdefault(dev, []).append((s, s + d))
+    for ivs in by_dev.values():
+        ivs.sort()
+    starts = {dev: [iv[0] for iv in ivs] for dev, ivs in by_dev.items()}
+    longest = max((e - s for ivs in by_dev.values() for s, e in ivs),
+                  default=0)
+    dispatched = {}  # thread -> starts of its `device dispatch` leaves
+    for t, ph, s, _d, _stmt in ev["phases"]:
+        if ph == "device dispatch":
+            dispatched.setdefault(t, []).append(s)
+    for starts_t in dispatched.values():
+        starts_t.sort()
+    parts_of = ("before_first_op_ms", "busy_ms", "between_ops_ms",
+                "after_last_op_ms")
+    out = {}
+    for kind, w0, w1 in ev["windows"]:
+        k = out.setdefault(kind, dict.fromkeys(
+            ("leaves", "no_op_leaves", "programs_seen"), 0) | dict.fromkeys(
+            parts_of + ("leaf_ms", "program_ms", "dispatch_to_wait_end_ms"),
+            0.0))
+        for t, ph, s, d, _stmt in ev["phases"]:
+            if ph != WAIT_PHASE or s < w0 or s + d > w1:
+                continue
+            e = s + d
+            k["leaves"] += 1
+            k["leaf_ms"] += d / 1e6
+            parts = []
+            for dev, ivs in by_dev.items():
+                i = bisect.bisect_left(starts[dev], s - longest)
+                hit = []
+                while i < len(ivs) and ivs[i][0] < e:
+                    if ivs[i][1] > s:
+                        hit.append((max(ivs[i][0], s), min(ivs[i][1], e)))
+                    i += 1
+                if hit:
+                    merged = _merge(hit)
+                    busy = sum(b - a for a, b in merged)
+                    before, after = merged[0][0] - s, e - merged[-1][1]
+                    parts.append((before, busy, d - before - busy - after,
+                                  after))
+            if not parts:
+                k["no_op_leaves"] += 1
+                continue
+            for name, col in zip(parts_of, zip(*parts)):
+                k[name] += sum(col) / len(col) / 1e6
+            # the statement programs that overlap the leaf, first start to
+            # last end on each chip, and the host's span around them
+            spans_ = [(min(a for a, _b in mine), max(b for _a, b in mine))
+                      for mine in ([p for p in ps if p[0] < e and p[1] > s]
+                                   for ps in programs.values()) if mine]
+            i = bisect.bisect_right(dispatched.get(t, []), s)
+            if spans_ and i:
+                k["programs_seen"] += 1
+                k["program_ms"] += sum(
+                    b - a for a, b in spans_) / len(spans_) / 1e6
+                k["dispatch_to_wait_end_ms"] += (
+                    e - dispatched[t][i - 1]) / 1e6
+    for kind, k in out.items():
+        with_ops = k["leaves"] - k["no_op_leaves"]
+        for name, n in ([("leaf_ms", k["leaves"])]
+                        + [(name, with_ops) for name in parts_of]
+                        + [(name, k["programs_seen"]) for name in
+                           ("program_ms", "dispatch_to_wait_end_ms")]):
+            if n:
+                k[name] /= n
+        k["around_program_ms"] = (
+            k["dispatch_to_wait_end_ms"] - k["program_ms"]
+            if k["programs_seen"] else None)
+        k["idle_ms_per_stmt"] = (
+            sum(idle_s.get(kind, {}).values()) / statements[kind] * 1e3
+            if statements.get(kind) else None)
+    return out
 
 
 def main(argv) -> int:
@@ -91,6 +198,9 @@ def main(argv) -> int:
                 ph: [leaves.get(ph), traced[1].get(ph, 0.0)
                      - traced[0].get(ph, 0.0)]
                 for ph in sorted(set(leaves) | set(traced[1]))}})
+        log({"device_wait_split": wait_split(
+            ev, statements,
+            {k: v["idle_phase_s"] for k, v in red["per_kind"].items()})})
         log({"named_breakdown": red["named_breakdown"],
              "scope_s": {k: v["scope_s"] for k, v in red["per_kind"].items()},
              "idle_phase_s": {k: v["idle_phase_s"]
@@ -211,7 +321,9 @@ def main(argv) -> int:
             "has_cpu": any("cpu_s" in a for a in tax.values()),
             "pool_wait_s": w.total_s if w else None,
             "pool_waits": w.count if w else 0,
-            "depth_sum": db.metrics.counter("front pool depth")})
+            "depth_sum": db.metrics.counter("front pool depth"),
+            "frames": {k: db.metrics.counter(f"result frames {k}")
+                       for k in ("prefetched", "lazy")}})
         if len(seen) == 2:  # the measured window: counters0, counters1
             a, b = seen
             n = b["statements"] - a["statements"]
@@ -227,6 +339,8 @@ def main(argv) -> int:
                     (b["depth_sum"] - a["depth_sum"]) / waits
                     if waits else None,
                 "statements": n,
+                "result_frames": {k: b["frames"][k] - a["frames"][k]
+                                  for k in b["frames"]},
                 # since process start: the lowerings are chosen when a
                 # program is traced, which the warm-up does
                 "dict_lookup": {
