@@ -244,8 +244,8 @@ def batch_to_host(batch: ColumnBatch, decode_strings: bool = True) -> dict[str, 
 
 def host_rows(schema, dicts, hcols, hvalid, hsel,
               decode_strings: bool = True) -> dict[str, np.ndarray | list]:
-    """batch_to_host over ALREADY-FETCHED numpy arrays (the single-
-    device_get dispatch path, engine/executor.py run_host)."""
+    """batch_to_host over ALREADY-FETCHED numpy arrays (the cursor's
+    host cache, engine/executor.py DeviceResult)."""
     out: dict[str, np.ndarray | list] = {}
     for f in schema.fields:
         a = np.asarray(hcols[f.name])[hsel]

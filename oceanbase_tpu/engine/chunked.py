@@ -52,7 +52,7 @@ from ..sql.logical import (
     Window,
     output_schema,
 )
-from .executor import Executor, _children
+from .executor import Dispatchable, Executor, _children
 from .pipeline import StreamStats, assemble_partials_table, run_stream
 
 import jax
@@ -501,7 +501,7 @@ class _ChunkSourceExecutor(ChunkWindowMixin, Executor):
         return self._chunk_slice_batch(name, cols)
 
 
-class ChunkedPreparedPlan:
+class ChunkedPreparedPlan(Dispatchable):
     """Drop-in replacement for PreparedPlan when inputs exceed the device
     budget: runs the chunk program per chunk, then the merge plan."""
 
@@ -514,7 +514,6 @@ class ChunkedPreparedPlan:
         self.split = split
         self.kind = kind
         self.chunk_rows = chunk_rows
-        self.retries = 0
         self.stream_stats = StreamStats()
 
         if kind == "scan":
@@ -562,14 +561,15 @@ class ChunkedPreparedPlan:
             unique_keys=executor.unique_keys, stats=None,
         )
         self.merge_exec.chunking_enabled = False
+        self.merge_exec.fuses_frame = False
         self._partial_cap = 1024
         self._merge_prepared = None
         self._merge_cap = 0
 
-    def run_nocheck(self, qparams: tuple = ()):
-        return self.run(qparams=qparams)
-
-    def run(self, max_retries: int = 3, qparams: tuple = ()):
+    def dispatch(self, qparams: tuple = (), max_retries: int = 3,
+                 fused: bool = True):
+        """The chunk loop, then the merge plan's dispatch: the cursor is
+        the merge's (engine/executor.PreparedPlan.dispatch)."""
         if getattr(self.chunk_exec, "supports_staged", False):
             # streaming pipeline (engine/pipeline.py): prefetch-staged
             # wire-encoded chunks, decode-on-device, overlap metering
@@ -584,7 +584,8 @@ class ChunkedPreparedPlan:
         if self._merge_prepared is None or self._merge_cap != self._partial_cap:
             self._merge_prepared = self.merge_exec.prepare(self.above_plan)
             self._merge_cap = self._partial_cap
-        return self._merge_prepared.run(max_retries, qparams=qparams)
+        return self._merge_prepared.dispatch(
+            qparams, max_retries=max_retries, fused=fused)
 
     def _run_legacy(self, max_retries: int = 3, qparams: tuple = ()):
         import os

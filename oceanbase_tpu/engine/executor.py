@@ -461,6 +461,12 @@ class Executor:
         # set on degraded host-fallback executors: disables the
         # EN_DEVICE_OOM injection point (host execution cannot device-OOM)
         self.host_fallback = False
+        # whether this executor's plans hand a client the fused narrow
+        # frame (PreparedPlan.dispatch). The executors that stand in for
+        # a session's own set it false and get the plain frame: PX, a
+        # streamed plan's merge, the degraded ladder's chunked and host
+        # rungs
+        self.fuses_frame = True
         # streaming pipeline knobs (engine/pipeline.py): prefetch depth 0
         # disables the prefetch thread (strictly alternating wire/compute
         # — the bench A/B baseline); stream_compress off ships raw
@@ -3468,11 +3474,9 @@ def pack_qparams(values, dtypes, spec) -> "np.ndarray | tuple":
     """Host side of the packed-parameter ABI: one int64 vector for the
     whole parameter set (or the legacy tuple when the spec opted out)."""
     if spec is None or len(spec) != len(values):
-        import jax.numpy as _jnp
+        from ..sql.plan_cache import bind
 
-        return tuple(
-            _jnp.asarray(bind_value(v, t)) for v, t in zip(values, dtypes)
-        )
+        return bind(values, dtypes)
     out = np.empty(packed_width(spec), dtype=np.int64)
     for (t, off, w), v in zip(spec, values):
         if w != 1:
@@ -3522,7 +3526,49 @@ def _narrow_seed(plan, default_rows: int) -> int:
     return max(1, int(default_rows))
 
 
-class PreparedPlan:
+class Dispatchable:
+    """What `Session._execute_entry` reads off anything it can dispatch
+    (PreparedPlan, and the out-of-core plans of engine/chunked.py and
+    engine/pipeline.py), with the value a plan has that never sets it."""
+
+    retries = 0            # lifetime overflow-recompile count (plan monitor)
+    params = None
+    input_spec = None      # device inputs; None: nothing resident to weigh
+    stream_stats = None    # engine/pipeline.StreamStats of a streamed plan
+    mesh_plan = None       # PxExecutor.sync_prepared attaches these three
+    px_nsh = 0
+    px_exchanges = None    # None: not a PX plan; (): a PX plan, no exchange
+    access_profile = ()
+    node_estimates = None
+    _qparam_spec = None    # None: parameters ride as the per-slot tuple
+    _dev_bytes_memo = None
+    _access_memo = None
+
+    def bind(self, values, dtypes):
+        """Values -> the dispatch form (one packed int64 vector when the
+        plan's parameter set allows it — one upload instead of N; the
+        per-slot tuple otherwise: a streamed plan's chunk and merge
+        programs each read a sparse subset of the statement's slots)."""
+        return pack_qparams(values, dtypes, self._qparam_spec)
+
+    @property
+    def batchable(self) -> bool:
+        """Eligible for the statement micro-batcher: the plan rides the
+        packed int64 qparam ABI with at least one slot (a 0-slot plan has
+        nothing to vary per lane — every concurrent hit is the SAME
+        dispatch and the solo path already amortizes it via the XLA
+        result cache; vector/legacy-tuple plans opted out of packing)."""
+        return bool(self._qparam_spec)
+
+    def run(self, max_retries: int = 3, qparams: tuple = ()):
+        """The synced device batch at the plan's own capacities — what
+        the internal consumers take (Executor.execute, PxExecutor.execute,
+        a streamed plan's merge)."""
+        return self.dispatch(
+            qparams, max_retries=max_retries, fused=False).batch()
+
+
+class PreparedPlan(Dispatchable):
     """A compiled plan: jitted XLA program + static capacities. Re-runnable;
     transparently recompiles at larger capacities on overflow."""
 
@@ -3533,7 +3579,6 @@ class PreparedPlan:
         self.jitted = jitted
         self.input_spec = input_spec
         self.overflow_nodes = overflow_nodes
-        self.retries = 0  # lifetime overflow-recompile count (plan monitor)
         self._qparam_spec = _collect_qparam_spec(plan)
         # cross-session micro-batching: pow2 bucket -> vmapped executable
         # (cleared by recompile(): a capacity bump makes them stale)
@@ -3555,11 +3600,6 @@ class PreparedPlan:
         # prepare(); restored from ArtifactMeta on warm hydrate) — the
         # estimate half of the operator profiler's calibration pairs
         self.node_estimates: dict[int, int] = {}
-
-    def bind(self, values, dtypes):
-        """Values -> the dispatch form (one packed int64 vector when the
-        plan's parameter set allows it — one upload instead of N)."""
-        return pack_qparams(values, dtypes, self._qparam_spec)
 
     def recompile(self) -> None:
         """Refresh the jitted executable after a capacity/spec change.
@@ -3616,30 +3656,33 @@ class PreparedPlan:
             self.recompile()
             return self.jitted(self._inputs(), qparams)
 
-    def run_nocheck(self, qparams: tuple = ()):
-        """Dispatch one execution WITHOUT the overflow host sync — for
-        benchmarking/pipelining after a checked run validated capacities."""
-        out, _ovf = self.jit_call(self._inputs(), qparams)
-        return out
+    def dispatch(self, qparams: tuple = (), max_retries: int = 3,
+                 fused: bool = True) -> "DeviceResult":
+        """THE way to run a prepared plan: enqueue ONE program WITHOUT any
+        host sync, start the device-to-host copies its completion sync
+        will read, and return the cursor. JAX async dispatch returns as
+        soon as the program is enqueued, so the caller's host work (audit,
+        metrics, trace assembly) overlaps device compute and the copies;
+        the overflow check is the cursor's first read (DeviceResult._sync).
 
-    def run(self, max_retries: int = 3, qparams: tuple = ()):
+        This is also the one place that chooses the frame that crosses
+        the link, from what the plan can see: the fused narrow frame of
+        `_narrow_frame()` rows when there is one, the plan's own output
+        otherwise. `fused=False` is for callers that want the device
+        batch itself (`run`), not a result to hand a client."""
         from ..share.interrupt import checkpoint
 
-        for attempt in range(max_retries + 1):
-            checkpoint()  # between overflow retries (and before the first run)
-            inputs = self._inputs()
-            out, ovf_vec = self.jit_call(inputs, qparams)
-            overflows = self._overflows(np.asarray(ovf_vec))  # ONE fetch
-            if not overflows:
-                return out
-            if attempt == max_retries:
-                raise RuntimeError(
-                    f"capacity overflow after {max_retries} retries: {overflows}"
-                )
-            self.retries += 1
-            self.params.bump(overflows)
-            self.recompile()
-        raise AssertionError
+        checkpoint()
+        ncap = self._narrow_frame() if fused else 0
+        if ncap:
+            out, ovf_vec, novf = self._run_narrow(qparams, ncap)
+        else:
+            out, ovf_vec = self.jit_call(self._inputs(), qparams)
+            novf = None
+        cursor = DeviceResult(self, qparams, out, ovf_vec, novf=novf,
+                              ncap=ncap, max_retries=max_retries)
+        cursor.start_copies()
+        return cursor
 
     def _overflows(self, hovf) -> dict:
         return {
@@ -3648,63 +3691,46 @@ class PreparedPlan:
             if int(v) > 0
         }
 
-    def run_host(self, max_retries: int = 3, qparams: tuple = ()):
-        """Dispatch + fetch EVERYTHING (result columns, validity, sel,
-        overflow counters) in ONE device_get. The separate run() +
-        batch_to_host path costs one device->host sync per array; for a
-        short query those syncs dominate end-to-end latency. Returns
-        (host_cols, host_valid, host_sel, schema, dicts)."""
-        import jax as _jax
-
-        from ..share.interrupt import checkpoint
-
-        for attempt in range(max_retries + 1):
-            checkpoint()
-            inputs = self._inputs()
-            out, ovf_vec = self.jit_call(inputs, qparams)
-            hovf, hcols, hvalid, hsel = _jax.device_get(
-                (ovf_vec, out.cols, out.valid, out.sel))
-            overflows = self._overflows(hovf)
-            if not overflows:
-                return hcols, hvalid, hsel, out.schema, out.dicts
-            if attempt == max_retries:
-                raise RuntimeError(
-                    f"capacity overflow after {max_retries} retries: "
-                    f"{overflows}")
+    def _grow(self, overflows: dict, attempt: int, max_retries: int,
+              frame_short: int = 0) -> None:
+        """THE overflow step of every redrive loop (the cursor's sync, a
+        batched bucket): give up after `max_retries`, else bump the
+        capacities that overflowed and recompile. `frame_short` is the
+        cursor's own shortfall (rows its narrow frame could not hold): an
+        overflow to report, with no plan capacity to bump."""
+        if attempt == max_retries:
+            raise RuntimeError(
+                f"capacity overflow after {max_retries} retries: "
+                f"{overflows or {'narrow': frame_short}}")
+        if overflows:
             self.retries += 1
             self.params.bump(overflows)
             self.recompile()
-        raise AssertionError
-
-    def run_device(self, qparams: tuple = ()):
-        """Dispatch WITHOUT any host sync: returns device references
-        (out ColumnBatch, overflow vector). JAX async dispatch returns as
-        soon as the program is enqueued, so the caller's host work
-        (audit, metrics, trace assembly) overlaps device compute; the
-        overflow check moves to the first fetch (DeviceResult._sync)."""
-        from ..share.interrupt import checkpoint
-
-        checkpoint()
-        return self.jit_call(self._inputs(), qparams)
 
     # ---- whole-statement fusion (result narrowing) --------------------
-    def narrow_frame(self, default_rows: int, max_rows: int) -> int:
-        """Pow2 width of the fused result frame, or 0 when this plan has
-        opted out (result provably wider than the ceiling, or a prior
-        narrow run overflowed past it). Seeded from the plan root
-        (LIMIT/aggregate bounds), clamped to the root-compaction capacity
-        — narrowing past what compact_batch already emits moves no fewer
-        bytes."""
-        if self._narrow_off:
+    def _narrow_frame(self) -> int:
+        """Pow2 width of the fused result frame, or 0 for the plain one:
+        this plan has opted out (result provably wider than the ceiling,
+        or a prior narrow run overflowed past it); its executor's plans
+        do not fuse (PX, a streamed merge, the degraded ladder); or the
+        executable is AOT-hydrated and stays un-narrowed until a natural
+        recompile makes it traceable again (building the narrow program
+        would force the honest recompile that the zero-compile warm-boot
+        promise forbids). Seeded from the plan root (LIMIT/aggregate
+        bounds), clamped to the root-compaction capacity — narrowing past
+        what compact_batch already emits moves no fewer bytes."""
+        if (self._narrow_off or not self._traceable
+                or not self.executor.fuses_frame):
             return 0
         ncap = self._narrow_cap
         if ncap == 0:
-            ncap = next_pow2(_narrow_seed(self.plan, default_rows))
+            ncap = next_pow2(
+                _narrow_seed(self.plan, DeviceResult.NARROW_SEED_ROWS))
             root = self.params.join_cap.get(ROOT_COMPACT)
             if root:
                 ncap = min(ncap, next_pow2(int(root)))
             self._narrow_cap = ncap
-        if ncap > max_rows:
+        if ncap > DeviceResult.NARROW_MAX_ROWS:
             self._narrow_off = True
             return 0
         return ncap
@@ -3738,17 +3764,14 @@ class PreparedPlan:
         run_narrow.__name__ = program_name(self.plan) + "_narrow"
         return jax.jit(run_narrow)
 
-    def run_device_narrow(self, qparams: tuple, ncap: int):
+    def _run_narrow(self, qparams: tuple, ncap: int):
         """Fused dispatch WITHOUT host sync: returns (narrowed ColumnBatch,
         plan overflow vector, narrow-overflow scalar) as device refs —
         ONE enqueued program covering predicate through final frame, so
-        the statement's only host roundtrip is NarrowDeviceResult's
-        completion sync."""
-        from ..share.interrupt import checkpoint
-
+        the statement's only host roundtrip is the cursor's completion
+        sync."""
         from .plan_artifact import ArtifactStale
 
-        checkpoint()
         for _attempt in range(3):
             fn = self._narrow.get(ncap)
             if fn is None:
@@ -3780,15 +3803,6 @@ class PreparedPlan:
         raise RuntimeError("narrowed executable stale after recompiles")
 
     # ---- cross-session micro-batching ---------------------------------
-    @property
-    def batchable(self) -> bool:
-        """Eligible for the statement micro-batcher: the plan rides the
-        packed int64 qparam ABI with at least one slot (a 0-slot plan has
-        nothing to vary per lane — every concurrent hit is the SAME
-        dispatch and the solo path already amortizes it via the XLA
-        result cache; vector/legacy-tuple plans opted out of packing)."""
-        return bool(self._qparam_spec)
-
     def run_batched_host(self, qblock: np.ndarray, max_retries: int = 3):
         """ONE device dispatch for B same-plan statements: `qblock` is
         the [B, nslots] stack of packed parameter vectors. The executable
@@ -3802,9 +3816,9 @@ class PreparedPlan:
         compilations is bounded by the bucket count regardless of traffic
         shape. Returns (hcols, hvalid, hsel, schema, dicts) with a
         leading [bucket] axis on every array — the caller scatters lane i
-        to waiting session i. Overflow on ANY lane redrives the shared
-        bump/recompile loop (max over lanes, exactly what run_host does
-        for one)."""
+        to waiting session i. Overflow on ANY lane takes the shared
+        `_grow` step (max over lanes, exactly what the cursor's sync does
+        for one) and redrives the bucket."""
         from ..share.interrupt import checkpoint
 
         b = int(qblock.shape[0])
@@ -3865,13 +3879,7 @@ class PreparedPlan:
             overflows = self._overflows(np.asarray(hovf).max(axis=0))
             if not overflows:
                 return hcols, hvalid, hsel, out.schema, out.dicts
-            if attempt == max_retries:
-                raise RuntimeError(
-                    f"capacity overflow after {max_retries} retries: "
-                    f"{overflows}")
-            self.retries += 1
-            self.params.bump(overflows)
-            self.recompile()
+            self._grow(overflows, attempt, max_retries)
         raise AssertionError
 
 
@@ -3903,41 +3911,58 @@ _head_gather = jax.jit(_head_gather_impl, static_argnums=(3,))
 class DeviceResult:
     """Lazy device-resident result cursor (the serving-path half of the
     fast path: `SELECT ... LIMIT 10` over a 60M-row result must transfer
-    KB, not GB).
+    KB, not GB), and the record of the execution it is the result of.
+
+    The frame behind it is in one of two states. `narrow(ncap)`: `out` is
+    the final ncap-row result frame (plan program + compaction gather in
+    ONE XLA program, PreparedPlan._run_narrow), so the whole client-
+    visible payload is in flight from dispatch on. `plain` (ncap 0): `out`
+    is the plan's own output at its static capacities.
 
     `start_copies` (called once at dispatch, and again on every redriven
     output) starts the device-to-host copy of every leaf the completion
     sync will read, while the program still runs, so `_sync` waits for
     the program once and finds the copies landed or landing. Which leaves
-    those are depends on the frame's static bytes alone:
+    those are depends on the frame alone:
 
-      * at most FRAME_PREFETCH_BYTES: the overflow counters and the whole
-        frame (columns, validity vectors, sel);
-      * larger: ONLY the overflow counters and the live row count (two
-        scalars). Column data transfers on demand: per touched column, or
-        LIMIT-bounded via a device-side compaction gather when the caller
-        wants the first k rows of a large result.
+      * narrow, or plain of at most FRAME_PREFETCH_BYTES (static bytes):
+        the overflow counters and the whole frame (columns, validity
+        vectors, sel) — no separate d2h leg and no O(capacity) host fold;
+      * a larger plain frame: ONLY the overflow counters and the live row
+        count (two scalars). Column data transfers on demand: per touched
+        column, or LIMIT-bounded via a device-side compaction gather when
+        the caller wants the first k rows of a large result.
 
-    The sync is the async-dispatch sync point; a capacity overflow
-    redrives the recompile loop here, exactly as run_host's eager loop
-    would have."""
+    The sync is the async-dispatch sync point and owns THE overflow loop:
+    a capacity overflow bumps, recompiles and redrives here; a narrow
+    frame too small for the live rows grows to the next power of two and
+    redrives, and past NARROW_MAX_ROWS the plan surrenders fusion (for
+    good: `_narrow_off`) and this cursor moves to the plain state."""
 
-    # a frame this small (bytes of its leaves' static shapes) crosses the
-    # link whole with the completion sync; a larger one stays lazy
+    # a plain frame this small (bytes of its leaves' static shapes)
+    # crosses the link whole with the completion sync; a larger one stays
+    # lazy
     FRAME_PREFETCH_BYTES = 65536
+    # the narrow frame: rows it is seeded with where the plan root gives
+    # no bound, and the width past which a result is not worth fusing
+    NARROW_SEED_ROWS = 256
+    NARROW_MAX_ROWS = 4096
 
-    def __init__(self, prepared, qparams, out, ovf_vec, max_retries: int = 3,
-                 profile=None, phases=None):
+    def __init__(self, prepared, qparams, out, ovf_vec, novf=None,
+                 ncap: int = 0, max_retries: int = 3):
         self.prepared = prepared
         self._qparams = qparams
         self._out = out
         self._ovf = ovf_vec
+        self._novf = novf      # narrow state: rows the frame fell short by
+        self._ncap = int(ncap)  # narrow state: the frame's width; 0 = plain
         self._max_retries = max_retries
-        # observability hooks, updated in place as transfers happen:
-        # server/diag.QueryProfile (fetch_s / d2h_bytes) and the session's
-        # last_phases dict for this statement
-        self.profile = profile
-        self.phases = phases
+        # the execution's record, updated in place as transfers happen:
+        # server/diag.QueryProfile (fetch_s / d2h_bytes), the statement's
+        # phase walls, and the per-operator profile of a profiled run
+        self.profile = None
+        self.phases = None
+        self.op_profile = None
         self._nrows: int | None = None
         self._hcols: dict = {}
         self._hvalid: dict = {}
@@ -3947,9 +3972,11 @@ class DeviceResult:
         self.frame_bytes = 0
         self.prefetched = False
 
-    # True where the program itself has bounded the frame: it crosses
-    # whole whatever its bytes (NarrowDeviceResult)
-    _frame_bounded = False
+    @property
+    def narrowed(self) -> bool:
+        """The frame is the fused program's: already cut to the rows a
+        client can receive, whatever its bytes."""
+        return self._ncap > 0
 
     def start_copies(self) -> None:
         """Start the device-to-host copy of exactly the leaves `_sync` is
@@ -3958,10 +3985,13 @@ class DeviceResult:
         out = self._out
         frame = [*out.cols.values(), *out.valid.values(), out.sel]
         self.frame_bytes = sum(int(a.nbytes) for a in frame)
-        self.prefetched = (self._frame_bounded
+        self.prefetched = (self.narrowed
                            or self.frame_bytes <= self.FRAME_PREFETCH_BYTES)
-        for a in ([self._ovf, *frame] if self.prefetched
-                  else [self._ovf, out.nrows]):
+        leaves = ([self._ovf, *frame] if self.prefetched
+                  else [self._ovf, out.nrows])
+        if self.narrowed:
+            leaves.append(self._novf)
+        for a in leaves:
             a.copy_to_host_async()
 
     def _observe(self, seconds: float, nbytes: int,
@@ -3978,8 +4008,8 @@ class DeviceResult:
                     self.phases.get("d2h_s", 0.0) + seconds)
 
     def _sync(self) -> None:
-        """Overflow check + row count: the deferred tail of the dispatch.
-        Runs the same bump/recompile/redrive loop as PreparedPlan.run."""
+        """Overflow check + row count: the deferred tail of the dispatch,
+        and the one bump / recompile / redrive loop."""
         if self._nrows is not None:
             return
         import time as _time
@@ -3991,9 +4021,10 @@ class DeviceResult:
             t0 = _time.perf_counter()
             # every read below was started by start_copies: the first
             # waits for the program, the rest are landed or landing
-            small = self.prefetched
+            whole = self.prefetched
             hovf = np.asarray(self._ovf)
-            if small:
+            short = int(np.asarray(self._novf)) if self.narrowed else 0
+            if whole:
                 harrs = {n: np.asarray(a)
                          for n, a in self._out.cols.items()}
                 hvals = {n: np.asarray(a)
@@ -4006,30 +4037,45 @@ class DeviceResult:
                 hn = int(np.asarray(self._out.nrows))
             self._observe(_time.perf_counter() - t0,
                           int(getattr(hovf, "nbytes", 0)) + 8)
-            overflows = p._overflows(np.asarray(hovf))
-            if not overflows:
-                self._nrows = int(hn)
-                if small:
+            overflows = p._overflows(hovf)
+            if not overflows and not short:
+                self._nrows = hn
+                if whole:
                     # commit ONLY on a clean run: an overflowed attempt's
                     # arrays are garbage and must not seed the host cache
                     self._hcols.update(harrs)
                     self._hvalid.update(hvals)
-                    self._hsel = np.asarray(hsel)
+                    self._hsel = hsel
                     self._observe(0.0, sum(
                         int(getattr(a, "nbytes", 0))
                         for d in (harrs, hvals) for a in d.values()
-                    ) + int(self._hsel.nbytes))
+                    ) + int(hsel.nbytes))
                 return
-            if attempt == self._max_retries:
-                raise RuntimeError(
-                    f"capacity overflow after {self._max_retries} retries: "
-                    f"{overflows}")
-            p.retries += 1
-            p.params.bump(overflows)
-            p.recompile()
+            p._grow(overflows, attempt, self._max_retries, short)
+            if short:
+                grown = next_pow2(self._ncap + short)
+                p._narrow_cap = max(p._narrow_cap, grown)
+                if grown > self.NARROW_MAX_ROWS:
+                    # frame too wide to fuse: remember on the plan (next
+                    # warm hit skips fusion outright) and finish THIS
+                    # statement in the plain state
+                    p._narrow_off = True
+                    grown = 0
+                self._ncap = grown
             checkpoint()
-            self._out, self._ovf = p.jit_call(p._inputs(), self._qparams)
+            if self.narrowed:
+                self._out, self._ovf, self._novf = p._run_narrow(
+                    self._qparams, self._ncap)
+            else:
+                self._out, self._ovf = p.jit_call(
+                    p._inputs(), self._qparams)
             self.start_copies()
+        raise AssertionError
+
+    def batch(self):
+        """The synced device batch itself (PreparedPlan.run)."""
+        self._sync()
+        return self._out
 
     @property
     def nrows(self) -> int:
@@ -4121,102 +4167,6 @@ class DeviceResult:
         host = host_rows(self._out.schema, self._out.dicts, harrs, hvals,
                          np.ones(kb, dtype=np.bool_))
         return {n: v[:k] for n, v in host.items()}
-
-
-class NarrowDeviceResult(DeviceResult):
-    """DeviceResult over a FUSED narrowed dispatch: `out` is the final
-    ncap-row result frame (plan program + compaction gather in one XLA
-    program), so the whole client-visible payload is in flight from
-    dispatch on and the completion sync reads it — no separate d2h leg
-    and no O(capacity) host result fold. A frame overflow grows the pow2
-    width and redrives; past the configured ceiling the plan surrenders
-    fusion and this cursor falls back to the plain lazy contract."""
-
-    narrowed = True
-
-    def __init__(self, prepared, qparams, out, ovf_vec, novf, ncap: int,
-                 narrow_max: int, max_retries: int = 3, profile=None,
-                 phases=None):
-        super().__init__(prepared, qparams, out, ovf_vec,
-                         max_retries=max_retries, profile=profile,
-                         phases=phases)
-        self._novf = novf
-        self._ncap = int(ncap)
-        self._narrow_max = int(narrow_max)
-        self._fallback = False
-
-    @property
-    def _frame_bounded(self) -> bool:
-        # the fused program has already cut the frame to ncap rows
-        return not self._fallback
-
-    def start_copies(self) -> None:
-        super().start_copies()
-        if not self._fallback:
-            self._novf.copy_to_host_async()
-
-    def _sync(self) -> None:
-        if self._nrows is not None:
-            return
-        if self._fallback:
-            return super()._sync()
-        import time as _time
-
-        from ..share.interrupt import checkpoint
-
-        p = self.prepared
-        for attempt in range(self._max_retries + 1):
-            t0 = _time.perf_counter()
-            # the frame IS the result: the overflow counters and every
-            # (ncap-row) leaf, each started by start_copies
-            hovf = np.asarray(self._ovf)
-            hnovf = int(np.asarray(self._novf))
-            harrs = {n: np.asarray(a) for n, a in self._out.cols.items()}
-            hvals = {n: np.asarray(a) for n, a in self._out.valid.items()}
-            hsel = np.asarray(self._out.sel)
-            self._observe(_time.perf_counter() - t0,
-                          int(getattr(hovf, "nbytes", 0)) + 8)
-            overflows = p._overflows(np.asarray(hovf))
-            if not overflows and hnovf == 0:
-                self._nrows = int(hsel.sum())
-                # commit ONLY on a clean run (overflowed frames are
-                # garbage), same contract as the base small path
-                self._hcols.update(harrs)
-                self._hvalid.update(hvals)
-                self._hsel = hsel
-                self._observe(0.0, sum(
-                    int(getattr(a, "nbytes", 0))
-                    for d in (harrs, hvals) for a in d.values()
-                ) + int(hsel.nbytes))
-                return
-            if attempt == self._max_retries:
-                raise RuntimeError(
-                    f"capacity overflow after {self._max_retries} "
-                    f"retries: {overflows or {'narrow': hnovf}}")
-            if overflows:
-                p.retries += 1
-                p.params.bump(overflows)
-                p.recompile()
-            if hnovf > 0:
-                grown = next_pow2(self._ncap + hnovf)
-                p._narrow_cap = max(p._narrow_cap, grown)
-                if grown > self._narrow_max:
-                    # frame too wide to fuse: remember on the plan (next
-                    # warm hit skips fusion outright) and finish THIS
-                    # statement on the plain path
-                    p._narrow_off = True
-                    self._fallback = True
-                    checkpoint()
-                    self._out, self._ovf = p.jit_call(
-                        p._inputs(), self._qparams)
-                    self.start_copies()
-                    return super()._sync()
-                self._ncap = grown
-            checkpoint()
-            self._out, self._ovf, self._novf = p.run_device_narrow(
-                self._qparams, self._ncap)
-            self.start_copies()
-        raise AssertionError
 
 
 def _range_bounds(c: E.Expr, qual: str) -> list:

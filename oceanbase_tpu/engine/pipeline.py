@@ -76,6 +76,7 @@ from ..sql.logical import (
 )
 from ..share import gap_ledger as _gap
 from ..storage.encoding import ENC_FOR, ENC_RLE, analyze_ints, choose_encoding
+from .executor import Dispatchable
 
 # ---------------------------------------------------------------------------
 # telemetry
@@ -901,7 +902,7 @@ def derive_partition_count(total_bytes: int, budget: int,
     return min(p, 256)
 
 
-class GraceHashPreparedPlan:
+class GraceHashPreparedPlan(Dispatchable):
     """Out-of-core execution when chunk streaming is NOT enough: the
     build side of a join (or the whole input of a keyed group-by) also
     exceeds the budget. Each grace input hash-partitions by its
@@ -933,7 +934,6 @@ class GraceHashPreparedPlan:
         self.kind = kind
         self.mode = mode
         self.n_parts = n_parts
-        self.retries = 0
         self.stream_stats = StreamStats()
         self._scans = scans
 
@@ -984,14 +984,12 @@ class GraceHashPreparedPlan:
             unique_keys=executor.unique_keys, stats=None,
         )
         self.merge_exec.chunking_enabled = False
+        self.merge_exec.fuses_frame = False
         self._partial_cap = 1024
         self._merge_prepared = None
         self._merge_cap = 0
 
     # ------------------------------------------------------------- run
-    def run_nocheck(self, qparams: tuple = ()):
-        return self.run(qparams=qparams)
-
     def _overlay_for(self, alias: str, scan: Scan, cols, segs, tmp,
                      cap: int) -> Table:
         """One partition of one grace input as a padded overlay Table."""
@@ -1030,7 +1028,10 @@ class GraceHashPreparedPlan:
             {c: d for c, d in t.dicts.items() if c in data}, valid=vdata,
         )
 
-    def run(self, max_retries: int = 3, qparams: tuple = ()):
+    def dispatch(self, qparams: tuple = (), max_retries: int = 3,
+                 fused: bool = True):
+        """Partition, run every partition (pair), then the merge plan's
+        dispatch: the cursor is the merge's."""
         from ..share.interrupt import checkpoint
         from ..storage.tmp_file import TmpFileManager
 
@@ -1091,7 +1092,8 @@ class GraceHashPreparedPlan:
                 self._merge_cap != self._partial_cap:
             self._merge_prepared = self.merge_exec.prepare(self.above_plan)
             self._merge_cap = self._partial_cap
-        return self._merge_prepared.run(max_retries, qparams=qparams)
+        return self._merge_prepared.dispatch(
+            qparams, max_retries=max_retries, fused=fused)
 
     def _run_partition(self, max_retries: int, qparams: tuple):
         prepared = self._part_prepared

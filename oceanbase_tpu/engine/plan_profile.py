@@ -35,6 +35,7 @@ import numpy as np
 
 from .executor import (
     ROOT_COMPACT,
+    PreparedPlan,
     _children,
     _device_nbytes,
     _number_nodes,
@@ -418,10 +419,7 @@ def profile_eligible(prepared) -> bool:
     """Only plain single-chip PreparedPlans segment: chunked/grace-hash
     plans stream (their stages ARE the chunk loop), PX plans shard over
     the mesh — both keep the plan-level monitor row they have today."""
-    return (hasattr(prepared, "run_device")
-            and getattr(prepared, "plan", None) is not None
-            and not getattr(prepared, "px_nsh", 0)
-            and getattr(prepared, "params", None) is not None)
+    return isinstance(prepared, PreparedPlan) and not prepared.px_nsh
 
 
 # ---- calibration record store ----------------------------------------------

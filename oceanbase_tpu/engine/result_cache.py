@@ -18,8 +18,8 @@ Identity = (logical entry key, bound literal values, snapshot watermark):
 - DML/flush additionally REMOVE entries eagerly (invalidate_tables /
   flush) — the key change alone would strand dead frames at capacity.
 
-Each entry keeps a reference to the NarrowDeviceResult cursor that
-produced it, pinning the ncap-row frame on device: the cache is charged
+Each entry keeps a reference to the narrow-state DeviceResult cursor
+that produced it, pinning the ncap-row frame on device: the cache is charged
 against the tenant's memory unit through the governor residency surface
 (server/database.py _resident_bytes) and drops its pins under the same
 OOM/eviction ladder as cold table residency (rung 1 flushes it first —

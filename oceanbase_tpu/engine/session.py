@@ -24,7 +24,6 @@ from ..sql.plan_cache import (
     CacheEntry,
     FastEntry,
     PlanCache,
-    bind,
     build_slot_map,
     parameterize,
     plan_fingerprint,
@@ -40,6 +39,15 @@ class ResultSet:
     affected: int = 0  # DML-affected row count (0 for queries)
     plan_cache_hit: bool = False  # this statement reused a compiled plan
     fast_path_hit: bool = False  # served by the text-keyed fast tier
+    # the record of the execution this is the result of; all None where
+    # the statement never reached the engine (pure DDL, SHOW). A result
+    # built around another (a DML statement's over its qualification
+    # scan's, EXPLAIN ANALYZE's over the analyzed statement's) takes the
+    # inner one's `record()`.
+    profile: object = None  # server/diag.QueryProfile (None: profiling off)
+    phases: dict | None = None  # the phase walls the host-tax carve reads
+    plan: object = None  # logical plan (flight-recorder bundles)
+    op_profile: dict | None = None  # per-operator, of a profiled run
 
     @property
     def nrows(self) -> int:
@@ -53,6 +61,10 @@ class ResultSet:
         out = list(zip(*cols)) if cols else []
         return out[:limit] if limit is not None else out
 
+    def record(self) -> dict:
+        return {"profile": self.profile, "phases": self.phases,
+                "plan": self.plan, "op_profile": self.op_profile}
+
 
 class LazyResultSet:
     """Device-resident ResultSet: same read surface as ResultSet, but
@@ -62,17 +74,34 @@ class LazyResultSet:
     copying at dispatch, the whole frame when it is small, two scalars
     when it is not. Over a large frame `.columns` fetches everything
     once; `column(name)` transfers only that column; `rows(limit=k)`
-    transfers only k compacted rows per column."""
+    transfers only k compacted rows per column. The execution's record
+    is the cursor's (later fetches keep adding to it in place)."""
+
+    record = ResultSet.record
 
     def __init__(self, names: tuple[str, ...], cursor, affected: int = 0,
-                 plan_cache_hit: bool = False, fast_path_hit: bool = False):
+                 plan_cache_hit: bool = False, fast_path_hit: bool = False,
+                 plan=None):
         self.names = names
         self.affected = affected
         self.plan_cache_hit = plan_cache_hit
         self.fast_path_hit = fast_path_hit
+        self.plan = plan
         self._cursor = cursor
         self._columns_cache: dict | None = None
         self._nrows: int | None = None
+
+    @property
+    def profile(self):
+        return self._cursor.profile
+
+    @property
+    def phases(self):
+        return self._cursor.phases
+
+    @property
+    def op_profile(self):
+        return self._cursor.op_profile
 
     @property
     def nrows(self) -> int:
@@ -166,28 +195,10 @@ class Session:
         # hook: share/timeline.ServingTimeline — per-dispatch device-busy
         # and compile-interference feed (the server wires it)
         self.timeline = None
-        # per-statement phase breakdown of the LAST run_ast call (EXPLAIN
-        # ANALYZE reads it right after executing the analyzed statement)
-        self.last_phases: dict = {}
-        # per-statement TPU resource attribution (server/diag.QueryProfile)
-        # of the LAST run_ast call; None when profiling is off or the
-        # statement bypassed run_ast (pure DDL)
-        self.last_profile = None
-        # logical plan of the LAST run_ast call (flight-recorder bundles
-        # capture its repr as the plan text)
-        self.last_plan = None
         # hook: engine/plan_profile.PlanProfiler — sampled per-operator
         # profiled execution (the server wires it and sets the pending
         # statement digest before dispatch)
         self.plan_profiler = None
-        # whole-statement fusion knobs (server wires them to
-        # ob_enable_result_narrow / ob_result_narrow_rows /
-        # ob_result_narrow_max_rows): fuse the final result-frame gather
-        # into the plan's device program so a warm statement is ONE
-        # dispatch + ONE host roundtrip
-        self.narrow_enabled_fn = None
-        self.narrow_default_rows = 256
-        self.narrow_max_rows = 4096
         # hook: engine/result_cache.ResultCache — device-resident narrowed
         # results keyed (logical key, bound literals, snapshot watermark);
         # a hit skips dispatch entirely
@@ -195,10 +206,6 @@ class Session:
         # hook: tables -> snapshot watermark tuple (the server supplies
         # per-table committed data versions; staleness = key mismatch)
         self.result_watermark_fn = None
-        # per-operator profile of the LAST profiled run_ast call (EXPLAIN
-        # ANALYZE reads it to annotate the plan tree); None when the
-        # statement was not profiled
-        self.last_op_profile = None
 
     def materialize(self, text: str, name: str) -> Table:
         """Run a SELECT and materialize its result as a storage-domain
@@ -331,7 +338,7 @@ class Session:
                            fastparse_s: float = 0.0):
         """Serve a fast hit from the device-resident result cache, or
         None on miss. A hit skips bind + dispatch + sync entirely and
-        still fills last_phases/last_profile so completion accounting
+        still carries phases and a profile so completion accounting
         (audit, summary, host-tax ledger) sees a normal statement."""
         rc = self.result_cache
         if rc is None or rc_key is None:
@@ -339,31 +346,25 @@ class Session:
         ce = rc.get(rc_key)
         if ce is None:
             return None
+        prepared = hit.entry.prepared
         rs = ResultSet(ce.names, ce.copy_columns(), plan_cache_hit=True,
-                       fast_path_hit=True)
-        phases = {
+                       fast_path_hit=True, plan=prepared.plan)
+        rs.phases = {
             "plan_s": 0.0, "compile_s": 0.0, "fastparse_s": fastparse_s,
             "bind_s": 0.0, "dispatch_s": 0.0, "fetch_s": 0.0,
             "exec_s": 0.0, "rows": rs.nrows, "cache_hit": True,
             "fast_hit": True, "result_cache": True,
         }
-        self.last_phases = phases
-        profile = None
         if self.profile_enabled_fn is None or self.profile_enabled_fn():
             from ..server.diag import QueryProfile
 
-            profile = QueryProfile(
+            rs.profile = QueryProfile(
                 compile_hit=True, fastparse_s=fastparse_s,
                 fast_path_hit=True)
-        self.last_profile = profile
-        self.last_plan = getattr(hit.entry.prepared, "plan", None)
-        self.last_op_profile = None
         m = self.metrics
         if m is not None and m.enabled:
             m.add("result rows returned", rs.nrows)
-            vts = getattr(
-                getattr(hit.entry.prepared, "params", None),
-                "vector_topns", None)
+            vts = getattr(prepared.params, "vector_topns", None)
             if vts:
                 # an ANN statement served straight from the device-
                 # resident cache: the whole probe+re-rank was skipped
@@ -372,16 +373,22 @@ class Session:
         # the plan's access profile so advisor heat (projection
         # keep/drop, index recommendations) doesn't see a dashboard
         # table go cold the moment its statements start hitting
-        acc = self.access
-        if acc is not None and acc.enabled:
-            prepared = hit.entry.prepared
-            memo = getattr(prepared, "_access_memo", None)
-            if memo is None or memo[0] != acc.epoch:
-                memo = (acc.epoch, acc.resolve(
-                    getattr(prepared, "access_profile", ())))
-                prepared._access_memo = memo
-            acc.fold_resolved(memo[1])
+        self._fold_access(prepared)
         return rs
+
+    def _fold_access(self, prepared) -> None:
+        """Access heat of one execution: the plan's profile resolves to
+        live stat objects once per (prepared, epoch); every execution
+        after that folds through direct references (no dict lookups)."""
+        acc = self.access
+        if acc is None or not acc.enabled:
+            return
+        memo = prepared._access_memo
+        if memo is None or memo[0] != acc.epoch:
+            memo = prepared._access_memo = (
+                acc.epoch, acc.resolve(prepared.access_profile))
+        if memo[1]:
+            acc.fold_resolved(memo[1])
 
     def _result_cache_put(self, rc_key, hit: "_FastHit", rs) -> None:
         """Admit a freshly executed fused result: only clean narrowed
@@ -390,10 +397,7 @@ class Session:
         the decoded host columns make hits free of fold work too)."""
         rc = self.result_cache
         cur = getattr(rs, "_cursor", None)
-        if rc is None or cur is None:
-            return
-        if not getattr(cur, "narrowed", False) \
-                or getattr(cur, "_fallback", False):
+        if rc is None or cur is None or not cur.narrowed:
             return
         nbytes = sum(
             int(getattr(a, "nbytes", 0))
@@ -429,7 +433,6 @@ class Session:
                 hit.entry, hit.values, ex=self.executor, was_hit=True,
                 fast=True, plan_s=0.0, compile_s=0.0,
                 fastparse_s=fastparse_s, profiling=profiling, h2d0=h2d0,
-                plan_obj=getattr(hit.entry.prepared, "plan", None),
             )
         except Exception:
             self.plan_cache.fast_invalidate(hit.text_key)
@@ -453,12 +456,10 @@ class Session:
         entry = self.plan_cache.get(key)
         if entry is None:
             return None, None
-        if hasattr(entry.prepared, "bind"):
-            # the SAME dispatch form sql() used (packed int64 vector):
-            # a tuple here would change the jit signature and silently
-            # re-trace + re-compile the plan (review finding)
-            return entry, entry.prepared.bind(pz.values, entry.dtypes)
-        return entry, bind(pz.values, entry.dtypes)
+        # the SAME dispatch form sql() used (packed int64 vector): a
+        # tuple here would change the jit signature and silently
+        # re-trace + re-compile the plan (review finding)
+        return entry, entry.prepared.bind(pz.values, entry.dtypes)
 
     def _cache_key(self, norm_key: str, pz, executor=None) -> tuple:
         return self._key_parts(norm_key, pz, executor)[0]
@@ -512,11 +513,11 @@ class Session:
         under the active statement span. Works for CACHED plans too: the
         exchange layout rides the prepared plan from compile time."""
         tr = self.tracer
-        exchanges = getattr(prepared, "px_exchanges", None)
+        exchanges = prepared.px_exchanges
         if tr is None or not tr.enabled or exchanges is None:
             return
         ctx = tr.current_ctx()
-        nsh = getattr(prepared, "px_nsh", 1)
+        nsh = prepared.px_nsh
         coord = tr.record_span("px coordinator", ctx, start, end, dop=nsh)
         cctx = (coord.trace_id, coord.span_id) if coord is not None else ctx
         if exchanges:
@@ -612,8 +613,8 @@ class Session:
             if got is not None:
                 _meta, prepared = got
                 compile_s = time.perf_counter() - t0
-                entry = CacheEntry(prepared, planned.output_names, pz.dtypes)
-                entry.json_specs, entry.json_hidden = jspecs, jhidden
+                entry = CacheEntry(prepared, planned.output_names, pz.dtypes,
+                                   json_specs=jspecs, json_hidden=jhidden)
                 if self.plan_monitor is not None and self.plan_monitor.enabled:
                     entry.monitor = self.plan_monitor.register(
                         norm_key, compile_s)
@@ -627,8 +628,8 @@ class Session:
             compile_s = time.perf_counter() - t0
             if tl is not None:
                 tl.leaf_end()
-            entry = CacheEntry(prepared, planned.output_names, pz.dtypes)
-            entry.json_specs, entry.json_hidden = jspecs, jhidden
+            entry = CacheEntry(prepared, planned.output_names, pz.dtypes,
+                               json_specs=jspecs, json_hidden=jhidden)
             if self.plan_monitor is not None and self.plan_monitor.enabled:
                 entry.monitor = self.plan_monitor.register(norm_key, compile_s)
             if use_cache:
@@ -636,7 +637,7 @@ class Session:
         rs = self._execute_entry(
             entry, pz.values, ex=ex, was_hit=was_hit, fast=False,
             plan_s=plan_s, compile_s=compile_s, fastparse_s=fastparse_s,
-            profiling=profiling, h2d0=h2d0, plan_obj=pz.plan,
+            profiling=profiling, h2d0=h2d0,
         )
         # text-tier registration AFTER a successful execution: one entry
         # per kind-marked normalized text, carrying the logical key parts
@@ -679,175 +680,97 @@ class Session:
         return rs
 
     def _execute_entry(self, entry, values, *, ex, was_hit, fast, plan_s,
-                       compile_s, fastparse_s, profiling, h2d0,
-                       plan_obj) -> ResultSet:
+                       compile_s, fastparse_s, profiling, h2d0) -> ResultSet:
         """Bind + dispatch a cached/compiled entry and assemble the
-        ResultSet, profile, monitor row, phase breakdown and metrics.
-        Shared by the full path (run_ast) and the fast path
-        (fast_execute) — the fast path arrives with plan_s=compile_s=0.
+        ResultSet with the execution's record on it (profile, phase
+        breakdown), the monitor row and the metrics. Shared by the full
+        path (run_ast) and the fast path (fast_execute) — the fast path
+        arrives with plan_s=compile_s=0.
 
-        Single-chip plans take the LAZY route: dispatch is async
-        (PreparedPlan.run_device returns device references immediately),
-        the cursor starts the device-to-host copies its sync will read
-        right behind the program, sql_audit/metrics/trace host work
-        overlaps both, and the only in-statement wait is that sync. A
-        frame over DeviceResult.FRAME_PREFETCH_BYTES stays device-resident
-        behind the cursor until the caller touches it."""
+        One route for every plan: `prepared.dispatch` is async (it
+        returns the cursor as soon as ONE program is enqueued, a streamed
+        plan's after its chunk loop), the cursor has started the
+        device-to-host copies its sync will read right behind the
+        program, sql_audit/metrics/trace host work overlaps both, and the
+        only in-statement wait is that sync. A frame over
+        DeviceResult.FRAME_PREFETCH_BYTES stays device-resident behind
+        the cursor until the caller touches it."""
         from ..share.errsim import errsim_point
-        from ..sql.json_host import apply_host_json
+        from .executor import DeviceResult
 
-        if not getattr(ex, "host_fallback", False):
+        if not ex.host_fallback:
             # device OOM injection point (EN_DEVICE_OOM): covers the fast
             # path, the full path and chunked dispatch alike. A host-
             # fallback executor never device-OOMs, which is what lets the
             # degradation ladder's final rung terminate.
             errsim_point("EN_DEVICE_OOM")
-        jn = getattr(entry, "json_specs", ())
         prepared = entry.prepared
-        retries0 = getattr(prepared, "retries", 0)
-        ann0 = getattr(
-            getattr(prepared, "params", None), "ann_escalations", 0)
+        retries0 = prepared.retries
+        ann0 = getattr(prepared.params, "ann_escalations", 0)
         # streaming pipeline counters are cumulative on the prepared plan
         # (plan-cache shared): fold per-run deltas, like overflow retries
-        sstats = getattr(prepared, "stream_stats", None)
+        sstats = prepared.stream_stats
         stream0 = sstats.snapshot() if sstats is not None else None
         tl = _gap.tracing()
         if tl is not None:
             tl.leaf("param pack")
         t0 = time.perf_counter()
-        if hasattr(prepared, "run_host"):
-            # packed parameter upload: ONE host->device transfer for the
-            # whole parameter set
-            qparams = prepared.bind(values, entry.dtypes)
-        else:
-            # chunked / PX prepared plans: legacy tuple contract
-            qparams = bind(values, entry.dtypes)
+        # packed parameter upload where the plan allows it: ONE
+        # host->device transfer for the whole parameter set
+        qparams = prepared.bind(values, entry.dtypes)
         bind_s = time.perf_counter() - t0
-        d2h_bytes = 0
-        fetch_s = 0.0
         if tl is not None:
             tl.leaf("device dispatch")
         exec_t0 = time.perf_counter()
-        lazy = hasattr(prepared, "run_device") and not jn
-        self.last_op_profile = None
         op_samples = prof_digest = prof_reason = None
-        narrow = None  # (novf, ncap) when the dispatch was fused-narrowed
-        if lazy:
-            from .executor import DeviceResult, NarrowDeviceResult
+        pp = self.plan_profiler
+        if pp is not None and pp.enabled:
+            from . import plan_profile as _PP
 
-            pp = self.plan_profiler
-            if pp is not None and pp.enabled:
-                from . import plan_profile as _PP
-
-                if _PP.profile_eligible(prepared):
-                    # the server layer hands the statement digest down
-                    # thread-locally; direct engine use falls back to the
-                    # monitor's normalized text as the sampling key
-                    mon0 = getattr(entry, "monitor", None)
-                    prof_digest = pp.take_pending() or (
-                        mon0.sql if mon0 is not None else None)
-                    if prof_digest is not None:
-                        prof_reason = pp.decide(prof_digest)
-            out = None
-            if prof_reason is not None:
-                from . import plan_profile as _PP
-
-                try:
-                    # profiled segmented run: fenced per-operator stages,
-                    # bit-identical (out, ovf_vec) — the statement is
-                    # served FROM this run, nothing executes twice
-                    out, ovf_vec, op_samples = _PP.run_profiled(
-                        prepared, qparams)
-                except Exception:
-                    # a broken profile never fails the statement — fall
-                    # back to the fused dispatch below
-                    out = None
-            if out is None:
-                # whole-statement fusion: compile the final result-frame
-                # gather INTO the plan's device program — one dispatch,
-                # and the completion sync moves only the frame's bytes
-                nfn = self.narrow_enabled_fn
-                # AOT-hydrated plans stay un-narrowed until a natural
-                # recompile makes them traceable again: building the
-                # narrow program would force the honest recompile that
-                # the zero-compile warm-boot promise forbids
-                if ((nfn is None or nfn()) and ex is self.executor
-                        and getattr(prepared, "_traceable", True)
-                        and hasattr(prepared, "narrow_frame")):
-                    ncap = prepared.narrow_frame(
-                        self.narrow_default_rows, self.narrow_max_rows)
-                    if ncap:
-                        out, ovf_vec, novf = prepared.run_device_narrow(
-                            qparams, ncap)
-                        narrow = (novf, ncap)
-            if out is None:
-                out, ovf_vec = prepared.run_device(qparams=qparams)
-            dispatch_s = time.perf_counter() - exec_t0
-            if tl is not None:
-                tl.leaf_end()
-            if narrow is not None:
-                cursor = NarrowDeviceResult(
-                    prepared, qparams, out, ovf_vec, narrow[0], narrow[1],
-                    self.narrow_max_rows)
+            if _PP.profile_eligible(prepared):
+                # the server layer hands the statement digest down
+                # thread-locally; direct engine use falls back to the
+                # monitor's normalized text as the sampling key
+                mon0 = entry.monitor
+                prof_digest = pp.take_pending() or (
+                    mon0.sql if mon0 is not None else None)
+                if prof_digest is not None:
+                    prof_reason = pp.decide(prof_digest)
+        cursor = None
+        if prof_reason is not None:
+            try:
+                # profiled segmented run: fenced per-operator stages,
+                # bit-identical (out, ovf_vec) — the statement is
+                # served FROM this run, nothing executes twice
+                out, ovf_vec, op_samples = _PP.run_profiled(
+                    prepared, qparams)
+            except Exception:
+                # a broken profile never fails the statement — fall
+                # back to the plan's own dispatch below
+                pass
             else:
                 cursor = DeviceResult(prepared, qparams, out, ovf_vec)
+                cursor.start_copies()
+        if cursor is None:
             # every leaf the completion sync will read starts crossing
-            # the link now, behind the program: the bookkeeping below
-            # overlaps the program AND the transfers
-            cursor.start_copies()
-            rs = LazyResultSet(entry.output_names, cursor,
-                               plan_cache_hit=was_hit, fast_path_hit=fast)
-        elif hasattr(prepared, "run_host"):
-            # eager single-device_get dispatch (kept for JSON-split
-            # statements whose host formatting needs every column anyway)
-            from ..core.column import host_rows
-
-            hcols, hvalid, hsel, oschema, odicts = prepared.run_host(
-                qparams=qparams)
-            dispatch_s = time.perf_counter() - exec_t0
-            if tl is not None:
-                tl.leaf_end()
-            if profiling:
-                d2h_bytes = sum(
-                    int(getattr(a, "nbytes", 0))
-                    for d in (hcols, hvalid)
-                    for a in d.values()
-                ) + int(getattr(hsel, "nbytes", 0))
-            host = host_rows(oschema, odicts, hcols, hvalid, hsel)
-            rs = None
-        else:
-            # chunked / PX prepared plans: device-batch contract
-            out_batch = prepared.run(qparams=qparams)
-            dispatch_s = time.perf_counter() - exec_t0
-            if tl is not None:
-                tl.leaf_end()
-            host = batch_to_host(out_batch)
-            if profiling:
-                d2h_bytes = sum(
-                    int(getattr(a, "nbytes", 0)) for a in host.values()
-                )
-            rs = None
+            # the link in here, behind the program: the bookkeeping
+            # below overlaps the program AND the transfers
+            cursor = prepared.dispatch(qparams)
+        dispatch_s = time.perf_counter() - exec_t0
+        if tl is not None:
+            tl.leaf_end()
         self._emit_px_spans(prepared, exec_t0, time.perf_counter())
-        if rs is None:
-            # order columns per select list
-            cols = {n: host[n] for n in entry.output_names}
-            out_names = entry.output_names
-            if jn:
-                out_names, cols = apply_host_json(
-                    jn, entry.json_hidden, out_names, cols)
-            rs = ResultSet(out_names, cols, plan_cache_hit=was_hit,
-                           fast_path_hit=fast)
         profile = None
         if profiling:
             from ..server.diag import QueryProfile
 
             device_bytes = 0
-            input_spec = getattr(prepared, "input_spec", None)
+            input_spec = prepared.input_spec
             if input_spec is not None:
                 # warm statements reuse the footprint walk: device inputs
                 # only change via an upload, and every upload moves the
                 # executor's lifetime h2d counter (serving-path diet)
-                memo = getattr(prepared, "_dev_bytes_memo", None)
+                memo = prepared._dev_bytes_memo
                 if (memo is not None and memo[0] == ex.h2d_bytes
                         and memo[1] is ex):
                     device_bytes = memo[2]
@@ -855,83 +778,69 @@ class Session:
                     device_bytes = ex.input_device_bytes(input_spec)
                     prepared._dev_bytes_memo = (
                         ex.h2d_bytes, ex, device_bytes)
-            if lazy:
-                # result footprint from the frame's static shapes (no
-                # transfer): the cursor adds actual d2h bytes as fetches
-                # happen
-                result_bytes = cursor.frame_bytes
-            else:
-                result_bytes = d2h_bytes
             # peak working set: device-resident inputs + the result's
-            # footprint + PX exchange lane capacity (the collective's
-            # buffers are live simultaneously with both)
-            peak = device_bytes + result_bytes
-            for _kind, ncols, cap in getattr(prepared, "px_exchanges", ()):
-                nsh = getattr(prepared, "px_nsh", 1)
+            # footprint (the frame's static shapes, no transfer: the
+            # cursor adds actual d2h bytes as fetches happen) + PX
+            # exchange lane capacity (the collective's buffers are live
+            # simultaneously with both)
+            peak = device_bytes + cursor.frame_bytes
+            nsh = prepared.px_nsh
+            for _kind, ncols, cap in prepared.px_exchanges or ():
                 lanes = nsh if _kind == "broadcast" else nsh * nsh
                 peak += ncols * cap * lanes * 8
             profile = QueryProfile(
                 compile_hit=was_hit,
                 compile_s=compile_s,
                 h2d_bytes=ex.h2d_bytes - h2d0,
-                d2h_bytes=d2h_bytes,
                 device_bytes=device_bytes,
                 peak_bytes=peak,
                 fastparse_s=fastparse_s,
                 bind_s=bind_s,
                 dispatch_s=dispatch_s,
-                fetch_s=fetch_s,
                 fast_path_hit=fast,
             )
-        self.last_profile = profile
-        self.last_plan = plan_obj
         phases = {
             "plan_s": plan_s, "compile_s": compile_s,
             "fastparse_s": fastparse_s, "bind_s": bind_s,
-            "dispatch_s": dispatch_s, "fetch_s": fetch_s,
+            "dispatch_s": dispatch_s, "fetch_s": 0.0,
             "cache_hit": was_hit, "fast_hit": fast,
         }
-        self.last_phases = phases
-        if lazy:
-            # wire the in-place observability sinks, THEN force the sync
-            # point: the overflow check + what start_copies put in flight.
-            # All the host work above overlapped device compute. The sync
-            # wall IS the statement's device wait — time it (host-tax ledger's
-            # "device wait" phase reads fetch_s; leaving it 0.0 hid the
-            # chip time inside exec_s).
-            cursor.profile = profile
-            cursor.phases = phases
-            if tl is not None:
-                tl.leaf("device wait")
-            tf = time.perf_counter()
-            nrows = rs.nrows
-            fetch_s = time.perf_counter() - tf
-            if tl is not None:
-                tl.leaf_end()
-            phases["fetch_s"] = fetch_s
-            if profile is not None:
-                profile.fetch_s = fetch_s
-        else:
-            nrows = rs.nrows
+        # the record rides the cursor, THEN the sync point is forced: the
+        # overflow check + what the dispatch put in flight. All the host
+        # work above overlapped device compute. The sync wall IS the
+        # statement's device wait — time it (host-tax ledger's "device
+        # wait" phase reads fetch_s; leaving it 0.0 hid the chip time
+        # inside exec_s).
+        cursor.profile = profile
+        cursor.phases = phases
+        if tl is not None:
+            tl.leaf("device wait")
+        tf = time.perf_counter()
+        nrows = cursor.nrows
+        fetch_s = time.perf_counter() - tf
+        if tl is not None:
+            tl.leaf_end()
+        phases["fetch_s"] = fetch_s
+        if profile is not None:
+            profile.fetch_s = fetch_s
+        json_cols = None
+        if entry.json_specs:
+            # JSON-split statement: the host formats the JSON text from
+            # every argument column, so the result is eager
+            from ..sql.json_host import apply_host_json
+
+            host = cursor.fetch_columns()
+            json_cols = apply_host_json(
+                entry.json_specs, entry.json_hidden, entry.output_names,
+                {n: host[n] for n in entry.output_names})
         exec_s = time.perf_counter() - exec_t0
         phases["exec_s"] = exec_s
         phases["rows"] = nrows
-        acc = self.access
-        if acc is not None and acc.enabled:
-            # access heat: the profile resolves to live stat objects once
-            # per (prepared, epoch); every execution after that folds
-            # through direct references (no dict lookups)
-            memo = getattr(prepared, "_access_memo", None)
-            if memo is None or memo[0] != acc.epoch:
-                memo = (acc.epoch, acc.resolve(
-                    getattr(prepared, "access_profile", ())))
-                prepared._access_memo = memo
-            if memo[1]:
-                acc.fold_resolved(memo[1])
+        self._fold_access(prepared)
         # mesh-SPMD collective accounting: the MeshPlan rides the prepared
         # plan (filled at first-dispatch trace, restored warm from the
         # artifact store), so cached and warm-booted plans fold identically
-        mesh_plan = getattr(prepared, "mesh_plan", None)
+        mesh_plan = prepared.mesh_plan
         if mesh_plan is not None and not mesh_plan.total_ops:
             mesh_plan = None
         stream_d = None
@@ -946,12 +855,13 @@ class Session:
                 phases["stream_h2d_s"] = d[3]
                 phases["stream_compute_s"] = d[4]
                 phases["stream_overlap_s"] = d[5]
-        mon = getattr(entry, "monitor", None)
+        retries = prepared.retries - retries0
+        mon = entry.monitor
         if mon is not None:
             mon.runs += 1
             mon.total_exec_s += exec_s
             mon.last_rows = nrows
-            mon.overflow_retries = getattr(prepared, "retries", 0)
+            mon.overflow_retries = prepared.retries
             if profile is not None:
                 mon.total_transfer_bytes += profile.transfer_bytes
                 mon.last_device_bytes = profile.device_bytes
@@ -966,17 +876,17 @@ class Session:
                 h2d_d, overlap_d = stream_d[3], stream_d[5]
                 mon.h2d_overlap_pct = (
                     100.0 * overlap_d / h2d_d if h2d_d else 0.0)
-        if op_samples is not None and self.plan_profiler is not None:
+        if op_samples is not None:
             # fold the (estimate, actual) calibration pairs into the
             # bounded store + per-op-kind sysstat counters; EXPLAIN
-            # ANALYZE reads last_op_profile right after this run
-            est = getattr(prepared, "node_estimates", None)
-            self.plan_profiler.store.fold(
+            # ANALYZE reads the result's op_profile right after this run
+            est = prepared.node_estimates
+            pp.store.fold(
                 prof_digest, op_samples, est,
                 plan_id=mon.plan_id if mon is not None else 0,
             )
             seg = getattr(prepared, "_segmented", None)
-            self.last_op_profile = {
+            cursor.op_profile = {
                 "digest": prof_digest,
                 "reason": prof_reason,
                 "estimates": dict(est or {}),
@@ -999,17 +909,15 @@ class Session:
                 m.observe("sql compile", compile_s)
             m.observe("sql execute", exec_s)
             m.add("result rows returned", nrows)
-            if narrow is not None:
+            if cursor.narrowed:
                 m.add("stmt fused dispatches")
-            if lazy:
-                # by what the sync read: a narrow frame that fell back
-                # over the ceiling finished lazy
-                m.add("result frames prefetched" if cursor.prefetched
-                      else "result frames lazy")
-            retries = getattr(prepared, "retries", 0) - retries0
+            # by what the sync read: a narrow frame that gave up fusion
+            # over the ceiling finished lazy
+            m.add("result frames prefetched" if cursor.prefetched
+                  else "result frames lazy")
             if retries > 0:
                 m.add("overflow recompiles", retries)
-            if getattr(prepared, "px_nsh", 0):
+            if prepared.px_nsh:
                 # the served PX route prepares and dispatches here, not
                 # through PxExecutor.execute: count it where it runs
                 m.add("px executions")
@@ -1017,7 +925,7 @@ class Session:
                     # an exchange lane or a join capacity of a mesh
                     # program overflowed: each is one more PX compile
                     m.add("px overflow recompiles", retries)
-            params = getattr(prepared, "params", None)
+            params = prepared.params
             vts = getattr(params, "vector_topns", None)
             if vts:
                 m.add("ann probes",
@@ -1048,11 +956,18 @@ class Session:
             # compile/result-transfer interference. Batched cohorts skip
             # this path — their ONE shared dispatch is fed by the batcher
             tl.record_exec(dispatch_s, 0.0 if was_hit else compile_s,
-                           d2h_bytes)
+                           profile.d2h_bytes if profile is not None else 0)
             if mesh_plan is not None:
                 tl.record_collective(
                     mesh_plan.total_ops, mesh_plan.total_bytes)
             if stream_d is not None:
                 tl.record_stream(stream_d[0], stream_d[3], stream_d[4],
                                  stream_d[5], stream_d[6])
-        return rs
+        if json_cols is not None:
+            return ResultSet(*json_cols, plan_cache_hit=was_hit,
+                             fast_path_hit=fast, profile=profile,
+                             phases=phases, plan=prepared.plan,
+                             op_profile=cursor.op_profile)
+        return LazyResultSet(entry.output_names, cursor,
+                             plan_cache_hit=was_hit, fast_path_hit=fast,
+                             plan=prepared.plan)

@@ -267,6 +267,9 @@ class PxExecutor(Executor):
         # prepare path degrades to chunk streaming (engine.Executor.prepare
         # multiplies its budget by this)
         self.budget_scale = self.nsh
+        # no narrow frame under PX: the result is the mesh program's own
+        # output (Executor.fuses_frame; ROADMAP S4)
+        self.fuses_frame = False
         # per-compile mesh-plan recorder; bound (and reset) at trace entry
         # of the compiled program — jit traces lazily, so the MeshPlan
         # attached at prepare() time fills in during the first dispatch
@@ -349,7 +352,7 @@ class PxExecutor(Executor):
             t0 = _time.perf_counter()
             prepared = self.prepare(plan)
             compile_s = _time.perf_counter() - t0
-            retries0 = getattr(prepared, "retries", 0)
+            retries0 = prepared.retries
             t0 = _time.perf_counter()
             out = prepared.run(max_retries)
             exec_s = _time.perf_counter() - t0
@@ -359,8 +362,8 @@ class PxExecutor(Executor):
                 # the prepared plan, not self._exch_log: the layout rides
                 # the plan (filled at first-dispatch trace), so CACHED
                 # plans — which never retrace — still get their spans.
-                exch = getattr(prepared, "px_exchanges", self._exch_log)
-                for i, (kind, ncols, cap) in enumerate(exch):
+                for i, (kind, ncols, cap) in enumerate(
+                        prepared.px_exchanges):
                     with tr.span("px_worker", dfo=i, exchange=kind,
                                  lane_cap=cap, cols=ncols):
                         pass
@@ -368,13 +371,13 @@ class PxExecutor(Executor):
                 root.tags["exec_us"] = int(exec_s * 1e6)
             if m is not None:
                 m.add("px executions")
-                retries = getattr(prepared, "retries", 0) - retries0
+                retries = prepared.retries - retries0
                 if retries > 0:
                     m.add("px overflow recompiles", retries)
                 m.observe("px compile", compile_s)
                 m.observe("px execute", exec_s)
                 m.wait("px dispatch", exec_s)
-            mp = getattr(prepared, "mesh_plan", None)
+            mp = prepared.mesh_plan
             if mp is not None and mp.total_ops:
                 if m is not None:
                     for coll, cnt in mp.ops_by_collective().items():

@@ -370,7 +370,7 @@ class StatementBatcher:
         # hook: share/timeline.ServingTimeline — each cohort's ONE device
         # dispatch plus its lane-occupancy land on the serving timeline
         self.timeline = None
-        # A/B switch (latency_bench --sessions: batching on vs off)
+        # A/B switch: batching on vs off
         self.enabled = True
         # bucket-shape coalescing (ob_enable_batch_coalesce): a leader
         # about to dispatch adopts ONE queued group of a DIFFERENT plan

@@ -785,24 +785,6 @@ class Database:
         # spill-segment corruption counting (storage/tmp_file.py) reaches
         # sysstat through the executor the grace-hash pipeline holds
         self.engine.executor.metrics = self.metrics
-        # whole-statement fusion: the engine fuses the final result-frame
-        # gather into the plan's device program (one dispatch, one D2H of
-        # final bytes). Knobs: ob_enable_result_narrow,
-        # ob_result_narrow_rows, ob_result_narrow_max_rows
-        self.engine.narrow_enabled_fn = (
-            lambda: self.config["ob_enable_result_narrow"])
-        self.engine.narrow_default_rows = int(
-            self.config["ob_result_narrow_rows"])
-        self.engine.narrow_max_rows = int(
-            self.config["ob_result_narrow_max_rows"])
-        self.config.on_change(
-            "ob_result_narrow_rows",
-            lambda _n, _o, v: setattr(
-                self.engine, "narrow_default_rows", int(v)))
-        self.config.on_change(
-            "ob_result_narrow_max_rows",
-            lambda _n, _o, v: setattr(
-                self.engine, "narrow_max_rows", int(v)))
         # cross-session continuous-batching scheduler: concurrent
         # fast-path hits fold into batched device dispatches behind ONE
         # cluster-shared DispatchGate (like cluster._timeline) — the
@@ -897,17 +879,6 @@ class Database:
             "ob_enable_batch_coalesce",
             lambda _n, _o, v: setattr(
                 self.batcher, "coalesce_enabled", bool(v)))
-        # completion drain: statement accounting (audit/summary/metrics/
-        # timeline folds, governor release) moves behind the wire write
-        # when ob_enable_completion_drain is on
-        from .completion import CompletionDrain
-
-        self.completion = CompletionDrain(
-            depth=int(self.config["ob_completion_drain_depth"]),
-            metrics=self.metrics)
-        self.config.on_change(
-            "ob_completion_drain_depth",
-            lambda _n, _o, v: setattr(self.completion, "depth", int(v)))
         # one shared virtual-clock closure: sql() builds a statement
         # Deadline from it on every call — no per-statement lambda
         self._bus_clock = lambda: self.cluster.bus.now
@@ -1323,11 +1294,6 @@ class Database:
         b = getattr(self, "batcher", None)
         if b is not None:
             b.shutdown()
-        # deferred completion folds must land before the process goes:
-        # close() drains the backlog inline (exactly-once accounting)
-        cd = getattr(self, "completion", None)
-        if cd is not None:
-            cd.close()
         pa = getattr(self, "plan_artifact", None)
         if pa is not None:
             # fold this boot's statement-summary exec counts into the
@@ -1506,7 +1472,6 @@ class Database:
             if self.plan_cache.get(key, count_miss=False) is None:
                 entry = CacheEntry(prepared, tuple(meta.output_names),
                                    list(meta.dtypes))
-                entry.json_specs, entry.json_hidden = (), ()
                 self.plan_cache.put(key, entry)
             if meta.fast and meta.text_key:
                 try:
@@ -1531,14 +1496,6 @@ class Database:
             p = PROVIDERS.get(name)
             if p is None:
                 continue
-            if not any_vt:
-                # read-your-own-accounting barrier: deferred completion
-                # folds (audit/summary/metrics) must land before a
-                # diagnostic snapshot materializes, or `SELECT ... FROM
-                # sql_audit` would miss the statements just served
-                cd = getattr(self, "completion", None)
-                if cd is not None and cd.submitted > cd.drained:
-                    cd.flush()
             self.catalog[name] = p(self)
             self._invalidate(name)
             any_vt = True
@@ -2531,6 +2488,14 @@ class _OpenTx:
         self.db.location.invalidate(ls_id)
 
 
+def _carve_engine_window(led, rs) -> None:
+    """Close a statement's "engine host" window, carved with the phases
+    its OWN result carries; none where it failed or never reached the
+    engine (a result of another worker's statement cannot get here)."""
+    led.window_end_carved(
+        (rs.phases if rs is not None else None) or {}, "engine host")
+
+
 class DbSession:
     """One client session: statement dispatch + transaction state."""
 
@@ -2540,7 +2505,10 @@ class DbSession:
         self._tx: _OpenTx | None = None
         self.session_id = next(db._session_ids)
         self._last_stmt_type = ""
-        self._stmt_cache_hit = False
+        # the engine result of the statement's inner SELECT (a DML
+        # statement's qualification scan): the DML result carries its
+        # record. Per connection, so never another worker's.
+        self._scan_rs = None
         self._retry_ctrl = None
         self._stmt_adds: list = []
         # (fkey, params, kinds) from the statement fast path — also the
@@ -2630,7 +2598,7 @@ class DbSession:
         cpu0 = _time.thread_time()
         err, rs = "", None
         self._last_stmt_type = ""  # "": did not parse
-        self._stmt_cache_hit = False  # set by any inner _select
+        self._scan_rs = None  # set by any inner _select
         # the statement's id: the interrupt registration's, the `stmt`
         # tag of the `sql` span, and the `stmt` stat of every ob:<phase>
         # annotation in a profiler trace
@@ -2726,12 +2694,6 @@ class DbSession:
     def _sql_inner(self, text: str, t0, cpu0) -> ResultSet:
         db = self.db
         err, rs = "", None
-        # last_profile is per-run_ast; statements that never reach run_ast
-        # (pure DDL, SHOW) must not inherit the previous statement's.
-        # last_phases likewise: the host-tax carve reads it after the
-        # engine window and must never see a previous statement's walls
-        db.engine.last_profile = None
-        db.engine.last_phases = {}
         # retry bookkeeping spans attempts but the statement keeps ONE
         # span tree, ASH activity and audit record — retries are an
         # internal redrive, not new statements. The controller is built
@@ -2782,13 +2744,10 @@ class DbSession:
                     elapsed_s = _time.perf_counter() - t0
                     stype = self._last_stmt_type or "Unknown"
                     m = db.metrics
-                    prof = db.engine.last_profile
-                    if rs is not None \
-                            and getattr(rs, "profile", None) is not None:
-                        # batched fast path: the per-lane profile rides
-                        # the ResultSet (engine.last_profile is shared
-                        # across sessions and races under concurrency)
-                        prof = rs.profile
+                    # the execution's record rides the result: a
+                    # statement that failed or never reached the engine
+                    # (pure DDL, SHOW) has none, and no other's
+                    prof = rs.profile if rs is not None else None
                     bi = (getattr(rs, "batch_info", None)
                           if rs is not None else None)
                     led = self._gap
@@ -2821,20 +2780,15 @@ class DbSession:
                             if self._retry_ctrl else 0,
                             rs, bi is not None, prof,
                         )
-                    snap = None
                     if led is not None:
                         # the return path + digest + summary fold are host
                         # wall too: cut everything since the engine window
-                        # closed, then freeze e2e/residual/chip-idle.
-                        # Deferred folds must NOT hold the live ledger —
-                        # begin() re-arms it in place for this session's
-                        # next statement — so they read a frozen snapshot
+                        # closed, then freeze e2e/residual/chip-idle
                         if led.stmt:
                             led.tag(digest=str(digest))
                         led.cut("completion fold")
                         led.close()
                         led.cpu_s = _time.thread_time() - cpu0
-                        snap = _GL.LedgerSnapshot(led)
                     retry_cnt = (self._retry_ctrl.retry_cnt
                                  if self._retry_ctrl else 0)
                     retry_info = (self._retry_ctrl.retry_info
@@ -2846,11 +2800,10 @@ class DbSession:
                     stmt_adds = self._stmt_adds
 
                     def _complete():
-                        # statement accounting, exactly once — inline on
-                        # the serving thread, or behind the wire write on
-                        # the completion drain (ob_enable_completion_drain)
-                        if snap is not None:
-                            db.host_tax.fold(digest, snap)
+                        # statement accounting, exactly once, inline on
+                        # the serving thread
+                        if led is not None:
+                            db.host_tax.fold(digest, led)
                         # hot-path diet: when metrics/audit are disabled,
                         # skip even the counter lookups and kwargs
                         # construction — the serving path pays zero for
@@ -2866,19 +2819,19 @@ class DbSession:
                                 adds.append(("sql fail count", 1))
                             observes = [("sql response time", elapsed_s)]
                             waits = ()
-                            if snap is not None:
+                            if led is not None:
                                 # per-phase wait events: sysstat/
                                 # system_event rows AND prometheus
                                 # summaries for free
                                 adds.append(("host tax statements", 1))
                                 observes.append(("host chip idle pct",
-                                                 snap.chip_idle_pct))
+                                                 led.chip_idle_pct))
                                 waits = [("host tax: " + k, v)
-                                         for k, v in snap.phases.items()]
-                                if snap.unattributed_s > 0.0:
+                                         for k, v in led.phases.items()]
+                                if led.unattributed_s > 0.0:
                                     waits.append(
                                         ("host tax: unattributed",
-                                         snap.unattributed_s))
+                                         led.unattributed_s))
                             m.bulk(adds=adds, observes=tuple(observes),
                                    waits=tuple(waits))
                         tl = db.timeline
@@ -2925,19 +2878,17 @@ class DbSession:
                                 batch_wait_us=(bi[2]
                                                if bi is not None else 0),
                                 chip_idle_us=int(
-                                    max(0.0, snap.e2e_s - snap.device_s)
-                                    * 1e6) if snap is not None else 0,
+                                    max(0.0, led.e2e_s - led.device_s)
+                                    * 1e6) if led is not None else 0,
                                 unattributed_us=int(
-                                    snap.unattributed_s * 1e6)
-                                if snap is not None else 0,
+                                    led.unattributed_s * 1e6)
+                                if led is not None else 0,
                             )
 
-                    cd = db.completion
-                    if (cd is not None
-                            and db.config["ob_enable_completion_drain"]):
-                        cd.submit(_complete)
-                    else:
-                        _complete()
+                    _complete()
+                    # the scan's cursor pins its device frame: let it go
+                    # with the statement
+                    self._scan_rs = None
                     if stype not in ("Show", "SetVar", ""):
                         if self._vars.get("ob_enable_show_trace"):
                             self._last_trace_id = sp.trace_id
@@ -2978,16 +2929,13 @@ class DbSession:
         reserve_bytes = self._reserve_estimate(text)
         while True:
             res = None
-            ok = False
             try:
                 if reserve_bytes > 0:
                     # admission-time device-memory reservation, held for
                     # the whole attempt (re-taken per attempt so post-OOM
                     # attempts charge the SHRUNK pool)
                     res = self._reserve_device_memory(reserve_bytes)
-                out = self._dispatch(text)
-                ok = True
-                return out
+                return self._dispatch(text)
             except Exception as e:
                 if ctrl is None:
                     ctrl = _R.RetryController(deadline=_R.current_deadline())
@@ -3069,18 +3017,10 @@ class DbSession:
                     raise ctrl.timeout_error(e) from e
             finally:
                 # the ledger must balance: release THIS attempt's grant on
-                # every exit — success, retry, or surfaced error. A
-                # successful attempt's release may ride the completion
-                # drain (the client isn't waiting on ledger arithmetic);
-                # failed attempts release inline so the next attempt/rung
-                # charges an honest pool.
+                # every exit — success, retry, or surfaced error — so the
+                # next attempt/rung charges an honest pool.
                 if res is not None:
-                    cd = db.completion
-                    if (ok and cd is not None
-                            and db.config["ob_enable_completion_drain"]):
-                        cd.submit(res.release)
-                    else:
-                        res.release()
+                    res.release()
 
     def _maybe_flight_record(self, text, sp, elapsed_s, rs, err,
                              prof) -> None:
@@ -3120,7 +3060,7 @@ class DbSession:
             # very run already carried a profile: a profiled run is
             # slower (fences), so re-arming on its own slowness would
             # lock a watermark-straddling digest into profiling forever
-            opp = db.engine.last_op_profile
+            opp = rs.op_profile if rs is not None else None
             if opp is None or opp.get("digest") != digest:
                 pp.mark_slow(digest)
             op_profile = pp.store.digest_profile(digest)
@@ -3139,7 +3079,7 @@ class DbSession:
             "rows": rs.nrows if rs is not None else 0,
             "error": err,
             "profile": prof.as_dict() if prof is not None else {},
-            "plan": repr(db.engine.last_plan),
+            "plan": repr(rs.plan if rs is not None else None),
             "spans": spans,
             "config": {
                 n: v for n, v, _p in db.config.snapshot()
@@ -3355,11 +3295,13 @@ class DbSession:
         # (plan/compile/bind/dispatch/fetch) carves the window wall; the
         # rest is the named measured remainder "engine host"
         led.window_start("engine host")
+        rs = None
         try:
-            return self._dispatch_stmt(stmt, norm_key,
-                                       fast_reg=self._fast_reg)
+            rs = self._dispatch_stmt(stmt, norm_key,
+                                     fast_reg=self._fast_reg)
+            return rs
         finally:
-            led.window_end_carved(self.db.engine.last_phases, "engine host")
+            _carve_engine_window(led, rs)
 
     def _fast_select(self, text: str) -> "ResultSet | None":
         """Server half of the statement fast path. Eligibility mirrors the
@@ -3448,7 +3390,6 @@ class DbSession:
             if rs is not None:
                 if led is not None:
                     led.cut("result cache")
-                self._stmt_cache_hit = True
                 return rs
         # cross-session micro-batching: concurrent hits on the SAME entry
         # fold into one batched device dispatch. Admission honors the
@@ -3486,7 +3427,6 @@ class DbSession:
                             dispatch_s=rs.batch_info[3],
                             fast_path_hit=True,
                         )
-                    self._stmt_cache_hit = True
                     return rs
                 # None = degrade to the solo fast path (idle gate,
                 # bypass, follower timeout, dispatch error, shutdown).
@@ -3494,28 +3434,27 @@ class DbSession:
                 # this solo run; solo_done hands it to the next queued
                 # cohort — the release is what keeps the
                 # continuous-batching queue draining.
+                rs = None
                 try:
                     rs = db.engine.fast_execute(
                         hit, fastparse_s=fastparse_s, rc_key=rc_key)
+                    return rs
                 finally:
                     db.batcher.solo_done()
                     if led is not None:
-                        led.window_end_carved(
-                            db.engine.last_phases, "engine host")
-                self._stmt_cache_hit = True
-                return rs
+                        _carve_engine_window(led, rs)
             finally:
                 db.batcher.admit_done()
         if led is not None:
             led.window_start("engine host")
+        rs = None
         try:
             rs = db.engine.fast_execute(hit, fastparse_s=fastparse_s,
                                         rc_key=rc_key)
+            return rs
         finally:
             if led is not None:
-                led.window_end_carved(db.engine.last_phases, "engine host")
-        self._stmt_cache_hit = True
-        return rs
+                _carve_engine_window(led, rs)
 
     def _sequence_ddl(self, text: str) -> ResultSet:
         from ..share.privilege import AccessDenied
@@ -3768,9 +3707,8 @@ class DbSession:
                     if n in PROVIDERS:
                         self.db.catalog.pop(n, None)
                         self.db._invalidate(n)
+        record = {}
         if analyze:
-            engine.last_phases = {}
-            engine.last_op_profile = None
             pp = self.db.plan_profiler
             if pp is not None and pp.enabled:
                 # EXPLAIN ANALYZE always profiles: force exactly one
@@ -3784,12 +3722,15 @@ class DbSession:
             ta = _time.perf_counter()
             rs = self._select(ast, P.normalize_for_cache(text)[0])
             wall_s = _time.perf_counter() - ta
-            ph = engine.last_phases
+            # the analyzed statement's own record (none of it where the
+            # statement took a route that keeps none: a recursive CTE)
+            record = rs.record()
+            ph = rs.phases or {}
 
             def us(s: float) -> int:
                 return int(s * 1e6)
 
-            opp = engine.last_op_profile
+            opp = rs.op_profile
             lines = list(lines)
             if opp is not None:
                 from ..sql.explain import annotate_plan_lines
@@ -3816,7 +3757,7 @@ class DbSession:
                     f"  chip_idle_pct: {idle:.1f} "
                     f"(device {us(dev_s)} us of {us(wall_s)} us e2e)"
                 )
-        return ResultSet(("plan",), {"plan": lines})
+        return ResultSet(("plan",), {"plan": lines}, **record)
 
     # ------------------------------------------------------------------ XA
     def _xa(self, text: str) -> ResultSet:
@@ -4442,7 +4383,7 @@ class DbSession:
         db.refresh_catalog([n for n in names if n not in views], tx=None)
         with db.catalog.tx_scope(views):
             rs = db.engine.run_ast(ast, norm_key)
-        self._stmt_cache_hit = rs.plan_cache_hit
+        self._scan_rs = rs
         self.last_follower_read = (snap, stale_us)
         db.metrics.add("follower read hits")
         return rs
@@ -4481,13 +4422,15 @@ class DbSession:
                 ctx = jax.default_device(jax.devices("cpu")[0])
             except Exception:  # no CPU device handle: backend IS the host
                 ctx = contextlib.nullcontext()
+        # a stand-in for the session's executor: the plain frame
+        ex.fuses_frame = False
         ex.timeline = base.timeline
         in_tx = self._tx is not None and self._tx.ctx is not None
         views = self._tx.views if in_tx else None
         with ctx, db.catalog.tx_scope(views):
             rs = db.engine.run_ast(ast, norm_key, use_cache=False,
                                    executor=ex)
-        self._stmt_cache_hit = False
+        self._scan_rs = rs
         return rs
 
     def _select(self, ast: A.Select, norm_key: str, fast_reg=None
@@ -4521,7 +4464,7 @@ class DbSession:
             )
             with self.db.catalog.tx_scope(route):
                 rs = self.db.engine.run_ast(ast, norm_key)
-            self._stmt_cache_hit = rs.plan_cache_hit
+            self._scan_rs = rs
             return rs
         self.db.refresh_catalog(names, tx=self._tx)
         in_tx = self._tx is not None and self._tx.ctx is not None
@@ -4555,9 +4498,10 @@ class DbSession:
                     executor=px,
                     fast_reg=reg,
                 )
-            # surfaces in the audit record; for DML the qualification
-            # scan's plan reuse IS the statement's plan-cache behavior
-            self._stmt_cache_hit = rs.plan_cache_hit
+            # for DML the qualification scan's plan reuse IS the
+            # statement's plan-cache behavior, and its record the
+            # statement's (_dml)
+            self._scan_rs = rs
             return rs
         finally:
             if px_granted:
@@ -4671,8 +4615,12 @@ class DbSession:
             raise
         if auto:
             self._end_tx(commit=True)
+        scan = self._scan_rs
+        if scan is None:  # e.g. INSERT ... VALUES: no scan, no record
+            return ResultSet((), {}, affected=affected)
         return ResultSet((), {}, affected=affected,
-                         plan_cache_hit=self._stmt_cache_hit)
+                         plan_cache_hit=scan.plan_cache_hit,
+                         **scan.record())
 
     def _end_tx(self, commit: bool) -> None:
         tx = self._tx

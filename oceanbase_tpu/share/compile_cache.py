@@ -8,8 +8,8 @@ per-tool folder) never hits.
 
 Nothing turns the cache on at import, in ``Database.__init__`` or in the
 tests' ``conftest.py``: the entry points that want compiles to persist
-call ``enable_compile_cache()`` themselves (``chip_smoke.py``,
-``bench.py``, the plan-artifact store when its mode is on).
+call ``enable_compile_cache()`` themselves (``chip_smoke.py``, the
+benchmark, the plan-artifact store when its mode is on).
 """
 
 from __future__ import annotations

@@ -131,20 +131,6 @@ def default_params() -> list[Param]:
               "continuous batching: max forming groups queued per tenant "
               "at the dispatch gate; arrivals beyond it shed to the solo "
               "fast path", min=1, max=4096),
-        Param("ob_enable_result_narrow", "bool", True,
-              "whole-statement fusion: compile the final result-frame "
-              "gather (compaction + projection to the rows the client "
-              "gets) INTO the plan's device program — one dispatch, one "
-              "D2H of final bytes"),
-        Param("ob_result_narrow_rows", "int", 256,
-              "fused result frame seed width (rows) when the plan root "
-              "gives no exact bound (LIMIT/scalar aggregate do); grows "
-              "pow2 on frame overflow", min=1, max=1 << 20),
-        Param("ob_result_narrow_max_rows", "int", 4096,
-              "fused result frame ceiling: a statement whose live result "
-              "exceeds this falls back to the plain lazy cursor (wide "
-              "results want the transfer-on-touch contract anyway)",
-              min=1, max=1 << 24),
         Param("ob_enable_result_cache", "bool", True,
               "device-resident result cache: repeated dashboard "
               "statements (same text, literals, snapshot watermark) "
@@ -156,17 +142,6 @@ def default_params() -> list[Param]:
         Param("ob_result_cache_entry_limit", "capacity", 65536,
               "max bytes one cached result may occupy (dashboards are "
               "small; big results stay on the lazy cursor path)", min=0),
-        Param("ob_enable_completion_drain", "bool", False,
-              "serve-then-account: move audit/summary/metrics/timeline "
-              "completion folds and governor release behind the wire "
-              "write onto a bounded drain worker (exactly-once; "
-              "observability surfaces lag the response by the drain "
-              "depth — tools that read sql_audit synchronously should "
-              "leave this off)"),
-        Param("ob_completion_drain_depth", "int", 256,
-              "completion drain: max queued statement-completion folds "
-              "before submitters fold inline (backpressure, no drops)",
-              min=1, max=1 << 16),
         Param("ob_enable_batch_coalesce", "bool", True,
               "micro-batching: let two heterogeneous-plan cohorts "
               "sharing a pow2 bucket shape coalesce into one fused "

@@ -321,7 +321,7 @@ class GapLedger:
                           include_fastparse: bool = False,
                           served_stream_hints: bool = True) -> float:
         """Fused carve + window_end for the serving hot path: pushes the
-        engine's measured subphases (``Session.last_phases``) into the
+        engine's measured subphases (the result's ``phases``) into the
         open window as hints and closes it, in ONE call instead of
         carve + N add() + device() + window_end (the per-statement call
         count is the ledger's main serving cost)."""
@@ -365,8 +365,8 @@ class GapLedger:
     def from_phases(cls, e2e_s: float, phases: dict,
                     device_s: float = 0.0) -> "GapLedger":
         """Build a conservation-complete ledger from an engine-level
-        ``Session.last_phases`` dict (bench.py drives the engine Session
-        directly, without the serving stack around it)."""
+        result's ``phases`` dict (for a caller that drives the engine
+        Session directly, without the serving stack around it)."""
         led = cls(clock=lambda: 0.0)
         led.t0 = 0.0
         hints, dev = carve_engine_phases(phases)
@@ -398,7 +398,7 @@ def carve_engine_phases(phases: dict,
                         include_fastparse: bool = True,
                         served_stream_hints: bool = False
                         ) -> Tuple[Dict[str, float], float]:
-    """Map an engine ``Session.last_phases`` dict onto ledger phase
+    """Map an engine result's ``phases`` dict onto ledger phase
     names.  Returns ``(hints, device_busy_s)``.
 
     Nesting rules: the per-chunk stream H2D wall sits INSIDE dispatch_s
@@ -475,31 +475,6 @@ def tracing() -> Optional[GapLedger]:
     ``leaf``/``leaf_end`` tests first."""
     led = getattr(_tls, "led", None)
     return led if led is not None and led.stmt else None
-
-
-class LedgerSnapshot:
-    """Frozen copy of a closed ledger's fold-relevant surface.
-
-    The serving session REUSES one GapLedger per statement (begin()
-    re-arms it in place), so completion work deferred behind the wire
-    write (server/completion.py) must never hold the live object — it
-    would read the NEXT statement's numbers. HostTaxRegistry.fold reads
-    exactly these five attributes, so a snapshot substitutes."""
-
-    __slots__ = ("e2e_s", "device_s", "unattributed_s", "phases", "cpu_s")
-
-    def __init__(self, led: GapLedger):
-        self.e2e_s = led.e2e_s
-        self.device_s = led.device_s
-        self.unattributed_s = led.unattributed_s
-        self.phases = dict(led.phases)
-        self.cpu_s = led.cpu_s
-
-    @property
-    def chip_idle_pct(self) -> float:
-        if self.e2e_s <= 0.0:
-            return 0.0
-        return max(0.0, min(1.0, 1.0 - self.device_s / self.e2e_s)) * 100.0
 
 
 class HostTaxRegistry:

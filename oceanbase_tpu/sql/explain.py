@@ -141,7 +141,7 @@ def explain_plan(executor, plan, params) -> list[str]:
 def annotate_plan_lines(lines, op_profile, miss_mark: float = 8.0
                         ) -> list[str]:
     """EXPLAIN ANALYZE: fold a profiled run's per-operator measurements
-    (engine/plan_profile.py, via Session.last_op_profile) into the plan
+    (engine/plan_profile.py, the result's `op_profile`) into the plan
     rendering. explain_plan emits exactly one line per operator in the
     SAME pre-order _number_nodes assigns, so line i annotates node i:
     est vs actual rows, the misestimation factor (`>>` marker at >=

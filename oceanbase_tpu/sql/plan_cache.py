@@ -430,6 +430,10 @@ class CacheEntry:
     dtypes: list
     hits: int = 0
     monitor: object = None  # server/diag.PlanMonitorEntry (if enabled)
+    # JSON_OBJECT/JSON_ARRAY select items the host formats at result
+    # assembly (sql/json_host.split_host_json)
+    json_specs: tuple = ()
+    json_hidden: tuple = ()
 
 
 @dataclass
@@ -472,7 +476,7 @@ class PlanCache:
         # logical entry holds, and a text entry whose logical entry was
         # evicted self-invalidates on its next hit anyway.
         self._fast: OrderedDict[str, FastEntry] = OrderedDict()
-        # A/B switch (latency_bench --no-fastpath, tests): disabled means
+        # A/B switch (tests): disabled means
         # lookups miss and registrations drop; the logical tier is
         # untouched so only the text tier's contribution is isolated
         self.fast_enabled = True

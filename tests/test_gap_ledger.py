@@ -465,32 +465,32 @@ def test_q1_wide_groupby_serves_from_narrowed_frame():
     t0 = _time.perf_counter()
     rs = sess.sql(QUERIES[1])  # warm rep: fused narrowed dispatch
     cur = rs._cursor
-    assert getattr(cur, "narrowed", False)
+    assert cur.narrowed
     warm_rows = rs.rows()
     e2e = _time.perf_counter() - t0
-    phases = dict(sess.last_phases)
+    phases = dict(rs.phases)
     # built ONCE, reused warm — a retrace per rep would be its own tail
     assert sess.executor.narrow_compiles == nc0 + 1
-    assert not cur._fallback
     # Q1's root is an order-by, so the frame seeds at the 256-row
     # default — a 4-group answer never grows it, and the committed
     # host frame IS that pow2 width (the completion sync moved ncap
     # rows per column, not the group table's capacity)
-    assert cur._ncap <= sess.narrow_default_rows
+    assert cur._ncap <= type(cur).NARROW_SEED_ROWS
     assert int(cur._hsel.shape[-1]) == cur._ncap
     frame_bytes = sum(
         int(getattr(a, "nbytes", 0))
         for d in (cur._hcols, cur._hvalid) for a in d.values()
     ) + int(cur._hsel.nbytes)
-    # unfused A/B off the SAME cached plan: full-capacity result frame
-    sess.narrow_enabled_fn = lambda: False
+    # unfused A/B off the SAME cached plan, through the plan's own
+    # opt-out: full-capacity result frame
+    cur.prepared._narrow_off = True
     try:
         rs_off = sess.sql(QUERIES[1])
         off_rows = rs_off.rows()
         cur_off = rs_off._cursor
     finally:
-        sess.narrow_enabled_fn = None
-    assert not getattr(cur_off, "narrowed", False)
+        cur.prepared._narrow_off = False
+    assert cur_off.prepared is cur.prepared and not cur_off.narrowed
     assert warm_rows == off_rows  # bit-identical through the fusion
     # the D2H diet, pinned scale-independently: every committed leaf is
     # exactly frame-width, so the completion roundtrip moves O(ncap)

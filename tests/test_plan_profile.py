@@ -68,8 +68,9 @@ def test_profiled_run_bit_identical_to_fused(db, fused, name):
     s = db.session()
     q = MIX[name]
     db.plan_profiler.force_next(P.digest_text(q))
-    got = s.sql(q).rows()
-    opp = db.engine.last_op_profile
+    rs = s.sql(q)
+    got = rs.rows()
+    opp = rs.op_profile
     assert opp is not None and opp["reason"] == "forced"
     assert got == fused[name]
     assert opp["samples"], "profiled run yielded no operator samples"
@@ -87,8 +88,9 @@ def test_every_plan_node_lands_in_plan_monitor_vt(db, fused, name):
     q = MIX[name]
     digest = P.digest_text(q)
     db.plan_profiler.force_next(digest)
-    s.sql(q).rows()
-    opp = db.engine.last_op_profile
+    rs = s.sql(q)
+    rs.rows()
+    opp = rs.op_profile
     assert opp is not None
     absorbed = set(opp["absorbed"])
     if name == "q3":  # Q3's inner join is absorbed by the clustered agg
@@ -126,8 +128,9 @@ def test_operator_device_time_reconciles_with_gap_ledger(db, fused):
     s = db.session()
     q = MIX["q6"]
     db.plan_profiler.force_next(P.digest_text(q))
-    s.sql(q).rows()
-    opp = db.engine.last_op_profile
+    rs = s.sql(q)
+    rs.rows()
+    opp = rs.op_profile
     assert opp is not None
     led = s._gap
     assert led is not None and led.closed
@@ -391,8 +394,9 @@ def test_warm_artifact_hit_profiles_identically(tmp_path):
         f"({i}, {i % 5}, {i})" for i in range(64)))
     digest = P.digest_text(ART_Q)
     db.plan_profiler.force_next(digest)
-    rows0 = s.sql(ART_Q).rows()
-    opp0 = db.engine.last_op_profile
+    rs = s.sql(ART_Q)
+    rows0 = rs.rows()
+    opp0 = rs.op_profile
     assert opp0 is not None and opp0["estimates"]
     db._save_node_meta()
     db.close()
@@ -405,9 +409,10 @@ def test_warm_artifact_hit_profiles_identically(tmp_path):
     c0 = ex.compiles + ex.batched_compiles
     s = db.session()
     db.plan_profiler.force_next(digest)
-    rows1 = s.sql(ART_Q).rows()
+    rs = s.sql(ART_Q)
+    rows1 = rs.rows()
     assert ex.compiles + ex.batched_compiles == c0  # warm artifact hit
-    opp1 = db.engine.last_op_profile
+    opp1 = rs.op_profile
     assert opp1 is not None
     assert rows1 == rows0
     assert opp1["estimates"] == opp0["estimates"]
@@ -436,8 +441,9 @@ def test_slow_statement_forces_next_profile(db, fused):
         db.config.set("trace_log_slow_query_watermark", "3600")
     execs0 = {r["node_id"]: r["executions"]
               for r in db.plan_profiler.store.digest_profile(digest)}
-    s.sql(q).rows()               # forced by the slow mark
-    opp = db.engine.last_op_profile
+    rs = s.sql(q)               # forced by the slow mark
+    rs.rows()
+    opp = rs.op_profile
     assert opp is not None and opp["reason"] == "forced"
     execs1 = {r["node_id"]: r["executions"]
               for r in db.plan_profiler.store.digest_profile(digest)}
@@ -458,12 +464,14 @@ def test_profiled_slow_run_does_not_rearm(db, fused):
         db.config.set("trace_log_slow_query_watermark", "0")
         db.plan_profiler.force_next(digest)
         marks0 = db.plan_profiler.slow_marks
-        s.sql(q).rows()       # profiled AND recorded slow
-        assert db.engine.last_op_profile is not None
+        rs = s.sql(q)       # profiled AND recorded slow
+        rs.rows()
+        assert rs.op_profile is not None
         assert db.plan_profiler.slow_marks == marks0
         # the next run is not dragged into another forced profile
-        s.sql(q).rows()
-        opp = db.engine.last_op_profile
+        rs = s.sql(q)
+        rs.rows()
+        opp = rs.op_profile
         assert opp is None or opp["reason"] != "forced"
     finally:
         db.config.set("trace_log_slow_query_watermark", "3600")

@@ -1,12 +1,14 @@
-"""The result frame crosses the link in one wait (ISSUE 29).
+"""The result frame crosses the link in one wait (ISSUE 29), behind one
+cursor with one overflow loop (ISSUE 30).
 
 Every leaf the completion sync is going to read has its device-to-host copy
 started at dispatch (`DeviceResult.start_copies`), so `_sync` waits for the
 program once instead of paying one blocking round trip a leaf. These tests
 hold the order and the counts of that, never a time: which copies start,
 that they start before the first blocking read, that a frame over the
-threshold stays lazy, that a redriven output gets its copies too, and that
-the two sysstat counters say which way each served SELECT went.
+threshold stays lazy, that a redriven output gets its copies too whatever
+state the cursor is in and whichever plan dispatched it, and that the two
+sysstat counters say which way each served SELECT went.
 """
 
 from __future__ import annotations
@@ -18,12 +20,12 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from oceanbase_tpu.core.column import host_rows
+from oceanbase_tpu.core.column import batch_to_host
 from oceanbase_tpu.core.dtypes import DataType, Field, Schema, TypeKind
 from oceanbase_tpu.core.table import Table
 from oceanbase_tpu.engine import Session
 from oceanbase_tpu.engine import executor as EX
-from oceanbase_tpu.engine.executor import DeviceResult, NarrowDeviceResult
+from oceanbase_tpu.engine.executor import ROOT_COMPACT, DeviceResult, PreparedPlan
 from oceanbase_tpu.server.database import Database
 from oceanbase_tpu.share.metrics import MetricsRegistry
 
@@ -68,8 +70,8 @@ def stand_in_cursor(kind: str, rows: int):
     prepared = SimpleNamespace(_overflows=lambda hovf: {})
     ovf = Leaf(log, "ovf", np.zeros(3, dtype=np.int64))
     if kind == "narrow":
-        cur = NarrowDeviceResult(prepared, (), out, ovf,
-                                 Leaf(log, "novf", np.int64(0)), rows, 4096)
+        cur = DeviceResult(prepared, (), out, ovf,
+                           novf=Leaf(log, "novf", np.int64(0)), ncap=rows)
     else:
         cur = DeviceResult(prepared, (), out, ovf)
     return cur, log, int(sel.sum())
@@ -143,22 +145,23 @@ def test_large_frame_stays_lazy(sess):
     one 16-row gather a leaf, not the frame."""
     q = "select fk, val, flt from probe where flt < 9"
     # the first run tries the 256-row narrow frame, overflows past the
-    # ceiling and finishes on the plain contract: lazy all the same
+    # ceiling and finishes in the plain state: lazy all the same
     first = sess.sql(q)._cursor
-    assert first.narrowed and first._fallback and not first.prefetched
+    assert not first.narrowed and not first.prefetched
+    assert first.prepared._narrow_off, "the plan did not give up fusion"
     rs = sess.sql(q)
     cur = rs._cursor
-    assert type(cur) is DeviceResult and not cur.prefetched
+    assert not cur.narrowed and not cur.prefetched
     assert cur.frame_bytes > DeviceResult.FRAME_PREFETCH_BYTES
     assert cur._hsel is None and not cur._hcols, "the frame moved unasked"
     assert sess.metrics.counter(LAZY) == 2
     assert sess.metrics.counter(PREFETCHED) == 0
-    synced = sess.last_profile.d2h_bytes
+    synced = rs.profile.d2h_bytes
     assert synced == cur._ovf.nbytes + 8
     head = rs.rows(limit=10)
     assert len(head) == 10
     # 16 rows (10 rounded up to a power of two) of fk, val (8 B) and flt (4 B)
-    assert sess.last_profile.d2h_bytes - synced == 16 * (8 + 8 + 4) + sum(
+    assert rs.profile.d2h_bytes - synced == 16 * (8 + 8 + 4) + sum(
         16 * v.dtype.itemsize for v in cur._out.valid.values())
     assert cur._hsel is None and not cur._hcols
     p = sess.catalog["probe"].data
@@ -168,83 +171,170 @@ def test_large_frame_stays_lazy(sess):
                             p["flt"][keep][:10].tolist()))
 
 
-def plain_rows(cur) -> dict:
-    """The same statement and literals through `run_host`, the eager path
-    that fetches by one `device_get` and knows nothing of the cursors."""
-    hcols, hvalid, hsel, schema, dicts = cur.prepared.run_host(
-        qparams=cur._qparams)
-    return host_rows(schema, dicts, hcols, hvalid, hsel)
-
-
-def spy_start_copies(monkeypatch):
-    """Log (cursor, the output it was started on) of every call."""
-    calls = []
-    for cls in (DeviceResult, NarrowDeviceResult):
-        real = cls.start_copies
-
-        def spied(self, _real=real):
-            calls.append((self, self._out))
-            return _real(self)
-
-        monkeypatch.setattr(cls, "start_copies", spied)
-    return calls
+def shrink(prepared, cap):
+    """Cut a plan's root capacity under the rows it is about to return:
+    its next run overflows, and each bump is fourfold."""
+    prepared.params.join_cap[ROOT_COMPACT] = cap
+    prepared.recompile()
 
 
 Q_RANGE = "select fk, sum(val) as s from probe where fk >= {} and fk < {} " \
           "group by fk order by fk"
+Q_WIDE = "select fk, val, flt from probe where fk >= {} and fk < {}"
 
 
-def force_capacity(sess):
-    """Seed the capacities on a narrow range, then send a wide one
-    through the same cached plan: the seeded capacity overflows."""
-    sess.sql(Q_RANGE.format(100, 110)).rows()
-    return Q_RANGE.format(0, 700)
+def rows_of(sess, q) -> list:
+    """The statement's rows from the table itself."""
+    p = sess.catalog["probe"].data
+    lo, hi = (int(t) for t in q.replace("group", "and").split("and")[:2]
+              for t in [t.split()[-1]])
+    keep = (p["fk"] >= lo) & (p["fk"] < hi)
+    if "sum(" not in q:
+        return list(zip(p["fk"][keep].tolist(), p["val"][keep].tolist(),
+                        p["flt"][keep].tolist()))
+    keys = np.unique(p["fk"][keep])
+    return [(int(k), int(p["val"][keep][p["fk"][keep] == k].sum()))
+            for k in keys]
 
 
-def force_narrow(sess):
-    """Cut the cached plan's frame to two rows: the narrow frame
-    overflows and grows."""
-    q = Q_RANGE.format(100, 140)
+def warm(sess, q):
+    """Run `q` once; its cached plan and bound parameters."""
     sess.sql(q).rows()
-    entry, _ = sess.cached_entry(q)
-    entry.prepared._narrow_cap = 2
-    return q
+    entry, qparams = sess.cached_entry(q)
+    return entry.prepared, qparams
 
 
-def force_fallback(sess):
-    """...and with a ceiling it cannot grow under, the cursor gives up the
-    fused frame and finishes on the plain contract."""
-    q = force_narrow(sess)
-    sess.narrow_max_rows = 4
-    return q
+def plain_small(sess, monkeypatch):
+    q = Q_RANGE.format(100, 140)
+    p, qp = warm(sess, q)
+    p._narrow_off = True  # the plan's own opt-out
+    shrink(p, 16)
+    return q, p, p, dict(state="plain", whole=True, retries=1)
 
 
-@pytest.mark.parametrize("force,fallback", [
-    (force_capacity, False), (force_narrow, False), (force_fallback, True)])
-def test_redriven_output_gets_its_copies(sess, monkeypatch, force, fallback):
-    """(c) An overflow of either kind redrives at the sync, the copies are
-    started again on the new outputs, and the rows are the plain path's."""
-    q = force(sess)
-    entry, _ = sess.cached_entry(q)
-    retries0, cap0 = entry.prepared.retries, entry.prepared._narrow_cap
-    calls = spy_start_copies(monkeypatch)
-    rs = sess.sql(q)
-    cur = rs._cursor
-    mine = [out for c, out in calls if c is cur]
-    assert len(mine) >= 2, "the redriven output was read without a prefetch"
-    assert mine[0] is not mine[-1] and mine[-1] is cur._out
-    assert (entry.prepared.retries > retries0
-            or entry.prepared._narrow_cap > cap0), "nothing overflowed"
-    assert cur._fallback is fallback
-    got, want = cur.fetch_columns(), plain_rows(cur)
-    assert list(got) == list(want) and len(got["fk"]) == rs.nrows > 2
-    for name in want:
-        assert np.array_equal(np.asarray(got[name]), np.asarray(want[name]))
-        assert np.asarray(got[name]).dtype == np.asarray(want[name]).dtype
-    # the statement is counted once, by what its sync read in the end
-    assert cur.prefetched is (cur.frame_bytes
-                              <= DeviceResult.FRAME_PREFETCH_BYTES)
-    assert sess.metrics.counter(PREFETCHED) + sess.metrics.counter(LAZY) == 2
+def plain_large(sess, monkeypatch):
+    q = Q_WIDE.format(0, 700)
+    p, qp = warm(sess, q)  # 17 k rows: gave up the fused frame by itself
+    shrink(p, 8192)
+    return q, p, p, dict(state="plain", whole=False, retries=1)
+
+
+def narrow(sess, monkeypatch):
+    q = Q_RANGE.format(100, 140)
+    p, qp = warm(sess, q)
+    shrink(p, 16)
+    return q, p, p, dict(state="narrow", whole=True, retries=1)
+
+
+def narrow_grown(sess, monkeypatch):
+    q = Q_RANGE.format(100, 140)
+    p, qp = warm(sess, q)
+    p._narrow_cap = 2  # a frame of two rows for forty
+    return q, p, p, dict(state="narrow", whole=True, retries=0, ncap=64)
+
+
+def narrow_surrendered(sess, monkeypatch):
+    q, p, _, _ = narrow_grown(sess, monkeypatch)
+    # ...and a ceiling it cannot grow under: the plan gives up fusion
+    monkeypatch.setattr(DeviceResult, "NARROW_MAX_ROWS", 4)
+    # (the plain frame is the root capacity's 4096 rows: over the
+    # threshold, lazy)
+    return q, p, p, dict(state="plain", whole=False, retries=0, off=True)
+
+
+def chunked_merge(sess, monkeypatch):
+    # a budget the probe table does not fit: the plan streams it in
+    # chunks and its cursor is the merge plan's
+    sess.executor.device_budget = 64 * 1024
+    q = Q_RANGE.format(100, 140)
+    cp, qp = warm(sess, q)
+    assert type(cp).__name__ == "ChunkedPreparedPlan"
+    shrink(cp._merge_prepared, 16)
+    return q, cp, cp._merge_prepared, dict(
+        state="plain", whole=True, retries=1)
+
+
+def batched(sess, monkeypatch):
+    q = Q_RANGE.format(100, 140)
+    p, qp = warm(sess, q)
+    shrink(p, 16)
+    return q, p, p, dict(state="batched", retries=1)
+
+
+@pytest.mark.parametrize("case", [
+    plain_small, plain_large, narrow, narrow_grown, narrow_surrendered,
+    chunked_merge, batched])
+def test_one_overflow_loop_whatever_the_entry(sess, monkeypatch, case):
+    """(c) Every way into a prepared plan redrives an overflow by the same
+    step: one error text when the retries are spent, `retries` counted
+    once a recompile, the copies started again on the redriven output,
+    and nothing of an overflowed attempt in the rows."""
+    q, entry_plan, p, want = case(sess, monkeypatch)
+    _, qp = sess.cached_entry(q)
+    recompiles = []
+    real = PreparedPlan.recompile
+    monkeypatch.setattr(PreparedPlan, "recompile", lambda self: (
+        recompiles.append(self), real(self))[1])
+    calls = []
+    real_start = DeviceResult.start_copies
+    monkeypatch.setattr(DeviceResult, "start_copies", lambda self: (
+        calls.append((self, self._out)), real_start(self))[1])
+    retries0, cap0, off0 = p.retries, p._narrow_cap, p._narrow_off
+
+    # out of retries: the one error, and nothing bumped on the way to it
+    with pytest.raises(RuntimeError,
+                       match=r"^capacity overflow after 0 retries: \{"):
+        if want["state"] == "batched":
+            p.run_batched_host(np.stack([qp, qp]), max_retries=0)
+        else:
+            entry_plan.dispatch(qp, max_retries=0).nrows
+    assert (p.retries, p._narrow_cap, recompiles) == (retries0, cap0, [])
+    assert p._narrow_off is off0
+    del calls[:]
+
+    rows = rows_of(sess, q)
+    assert len(rows) > 16
+    if want["state"] == "batched":
+        other = Q_RANGE.format(100, 130)
+        _, qp2 = sess.cached_entry(other)
+        hcols, hvalid, hsel, schema, dicts = p.run_batched_host(
+            np.stack([qp, qp2, qp]))
+        for lane, text in enumerate([q, other, q]):
+            keep = hsel[lane]
+            assert list(zip(hcols["fk"][lane][keep].tolist(),
+                            hcols["s"][lane][keep].tolist())) \
+                == rows_of(sess, text)
+    else:
+        rs = sess.sql(q)
+        cur = rs._cursor
+        assert cur.prepared is p
+        mine = [out for c, out in calls if c is cur]
+        assert len(mine) >= 2, "the redriven output was read unprefetched"
+        assert mine[0] is not mine[-1] and mine[-1] is cur._out
+        assert cur.narrowed is (want["state"] == "narrow")
+        assert cur.prefetched is want["whole"]
+        assert cur.prefetched is (cur.narrowed or cur.frame_bytes
+                                  <= DeviceResult.FRAME_PREFETCH_BYTES)
+        if cur.prefetched:
+            # what the sync kept is the redriven frame's, leaf for leaf
+            assert cur._hsel.shape == cur._out.sel.shape
+            assert all(cur._hcols[n].shape == a.shape
+                       for n, a in cur._out.cols.items())
+        else:
+            assert cur._hsel is None and not cur._hcols
+        assert rs.nrows == len(rows) and rs.rows() == rows
+        # the same run read leaf by leaf, with none of the cursor's cache
+        host = batch_to_host(entry_plan.run(qparams=qp))
+        assert [tuple(r) for r in zip(*(host[n] for n in rs.names))] == [
+            tuple(r) for r in rows]
+        # the statement is counted once, by what its sync read in the end
+        assert sess.metrics.counter(PREFETCHED) + sess.metrics.counter(LAZY) \
+            == 2
+        assert sess.metrics.counter("overflow recompiles") == (
+            want["retries"] if p is entry_plan else 0)
+    assert p.retries - retries0 == want["retries"] == len(recompiles)
+    assert p._narrow_cap == want.get("ncap", cap0) or want.get("off")
+    assert p._narrow_off is (off0 or bool(want.get("off")))
 
 
 # ---- (d) the served PX route --------------------------------------------------

@@ -148,11 +148,11 @@ def ratio_leg(db, s, queries, reps: int, fails: list) -> dict:
         return {}
     prepared = entry.prepared
     binds = [eng.cached_entry(_qtext(q, where))[1] for q in queries]
-    out = prepared.run(qparams=binds[0])  # warm + capacity check
+    prepared.run(qparams=binds[0])  # warm + capacity check
     t0 = time.perf_counter()
     for qp in binds:
-        out = prepared.run_nocheck(qparams=qp)
-    int(out.nrows)  # one sync for the burst
+        cur = prepared.dispatch(qp, fused=False)
+    cur.nrows  # one sync for the burst
     dev = (time.perf_counter() - t0) / len(binds)
     ratio = e2e / dev if dev > 0 else float("inf")
     if ratio > E2E_VS_DEVICE_GATE:
@@ -170,40 +170,37 @@ def wire_leg(db, queries, seconds: float, fails: list) -> dict:
     closed loop, distinct embeddings — the batcher must coalesce."""
     import threading
 
-    import latency_bench as LB
+    from benchmark.harness.wire import WireClient
     from oceanbase_tpu.server.async_front import AsyncMySqlFrontend
 
     # distinct vectors per lane and per iteration; result cache off so
     # every statement actually dispatches (and can coalesce)
-    setup = ["set ob_enable_result_cache = 0"]
     texts = [[_qtext(queries[(i * 7 + j) % len(queries)])
               for j in range(16)] for i in range(WIRE_SESSIONS)]
     afe = AsyncMySqlFrontend(db, workers=16).start()
-    try:
-        from concurrent.futures import ThreadPoolExecutor
+    stop = threading.Event()
+    done = [0] * WIRE_SESSIONS
 
-        with ThreadPoolExecutor(max_workers=8) as pool:
-            socks = list(pool.map(
-                lambda _i: LB._wire_handshake(afe.port, setup),
-                range(WIRE_SESSIONS)))
-        conns = [LB._WireConn(sk, t) for sk, t in zip(socks, texts)]
-        stop = threading.Event()
-        record = [True]
+    def drive(i: int) -> None:
+        c = WireClient(afe.port, timeout=30.0)
+        try:
+            c.query("set ob_enable_result_cache = 0")
+            while not stop.is_set():
+                c.query(texts[i][done[i] % len(texts[i])])
+                done[i] += 1
+        finally:
+            c.close()
+
+    try:
         c0 = db.metrics.counters_snapshot()
-        threads = [threading.Thread(
-            target=LB._wire_drive, args=([c], stop, record), daemon=True)
-            for c in conns]
+        threads = [threading.Thread(target=drive, args=(i,), daemon=True)
+                   for i in range(WIRE_SESSIONS)]
         for t in threads:
             t.start()
         time.sleep(seconds)
         stop.set()
         for t in threads:
             t.join(timeout=30)
-        for sk in socks:
-            try:
-                sk.close()
-            except OSError:
-                pass
         c1 = db.metrics.counters_snapshot()
     finally:
         afe.stop()
@@ -211,7 +208,7 @@ def wire_leg(db, queries, seconds: float, fails: list) -> dict:
     def delta(name: str) -> int:
         return int(c1.get(name, 0) - c0.get(name, 0))
 
-    stmts = sum(len(c.lat) for c in conns)
+    stmts = sum(done)
     max_lanes = 0
     for name in c1:
         if name.startswith("stmt batch size ") and delta(name) > 0:

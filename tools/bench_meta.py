@@ -4,7 +4,7 @@
 A bench number without its provenance is unreproducible: two artifacts
 with the same metric can come from different engine revisions or from a
 run that flipped a server knob mid-experiment. Every emitted bench
-summary (bench.py, tools/latency_bench.py) carries a `meta` block:
+summary (the smokes, tools/chaos_bench.py) carries a `meta` block:
 
   git_rev             HEAD short rev, "-dirty<hash>" when the working
                       tree diff touches the engine or the bench drivers
@@ -25,7 +25,7 @@ import subprocess
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_BENCH_SOURCES = ("oceanbase_tpu", "bench.py", "tools")
+_BENCH_SOURCES = ("oceanbase_tpu", "tools")
 
 
 def git_rev(repo: str = _REPO) -> str:

@@ -100,9 +100,18 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=40)
     args = ap.parse_args()
 
-    import latency_bench as LB
+    import numpy as np
 
-    db, s = LB.build_db(2000)
+    from oceanbase_tpu.server.database import Database
+
+    db = Database(n_nodes=1, n_ls=1)
+    s = db.session()
+    s.sql("create table kv (id int primary key, k int, v int, grp int)")
+    vals = np.random.default_rng(7).integers(0, 1000, size=2000)
+    for lo in range(0, 2000, 500):
+        s.sql("insert into kv values " + ", ".join(
+            f"({i + 1}, {i}, {int(vals[i])}, {i % 16})"
+            for i in range(lo, lo + 500)))
     fails = []
 
     # -- warmup: register the fast path (varying literals) + cache Q6 --

@@ -75,8 +75,8 @@ def main() -> int:
     for rep in range(WARM_REPS):
         for q in QIDS:
             db.plan_profiler.force_next(digests[q])
-            got = s.sql(QUERIES[q]).rows()
-            opp = db.engine.last_op_profile
+            rs = s.sql(QUERIES[q])
+            got, opp = rs.rows(), rs.op_profile
             if opp is None:
                 return fail(f"Q{q} rep {rep}: forced profile did not run")
             profiled_stmts += 1

@@ -4,34 +4,14 @@
 # 8 virtual devices via conftest.py), skips slow-marked tests, and
 # bounds the whole run with a timeout so a hung test can't wedge CI.
 #
-#   tools/run_tier1.sh [--chaos] [--latency] [--serve] [--awr] [--health]
-#                      [--advisor] [--warmboot] [--elastic] [--oom] [--mesh]
-#                      [--stream] [--scrub] [--hosttax] [--hostpath]
-#                      [--planprof] [--ann] [extra pytest args...]
+#   tools/run_tier1.sh [--chaos] [--awr] [--health] [--advisor] [--warmboot]
+#                      [--elastic] [--oom] [--mesh] [--stream] [--scrub]
+#                      [--hosttax] [--planprof] [--ann] [extra pytest args...]
 #
 # --chaos additionally runs the slow-marked chaos workload drives
 # (tests/test_chaos.py) with their fixed seeds after the tier-1 pass;
 # on failure the fault schedule is in the assertion detail (replay with
 # tools/chaos_bench.py --seed N).
-#
-# --latency additionally runs a small serving-latency smoke
-# (tools/latency_bench.py --strict): warm repeated statements must hit
-# the text-keyed fast path 100% of the time, else the smoke fails.
-#
-# --serve additionally runs the concurrent-serving smokes:
-#   1. tools/latency_bench.py --sessions 16 --serve-strict: the
-#      statement micro-batcher must actually form batches (mean batch
-#      size > 1) and keep batched XLA compiles within the pow2 bucket
-#      bound.
-#   2. tools/latency_bench.py --wire-sessions 128 --wire-strict: 128
-#      real MySQL connections driven closed-loop against the threaded
-#      solo-path baseline then the async front end with continuous
-#      batching — async aggregate throughput must be no worse, its p99
-#      must stay <= 3x its p50, and its p99 must beat the threaded
-#      stack's blown-out tail by >= 3x.
-#   3. tools/latency_bench.py --fairness --fairness-strict: a weight-4
-#      quiet tenant flooded by a weight-1 tenant through the shared
-#      dispatch gate must keep its p99 within 2x of its solo run.
 #
 # --awr additionally runs the workload-repository smoke
 # (tools/awr_smoke.py): mixed workload bracketed by two SNAPSHOT
@@ -109,17 +89,6 @@
 # under its frozen budget, and the VT/sysstat/audit surfaces live; the
 # last stdout line is the JSON verdict.
 #
-# --hostpath additionally runs the dispatch-lean serving-spine smoke
-# (tools/hostpath_smoke.py): warm TPC-H Q6 through the engine session
-# must stay within 3x of the amortized device-only time through the
-# same cached executable with fused/narrowed rows bit-identical to the
-# unfused path, a warm point read's median host overhead (gap-ledger
-# e2e x chip-idle) must stay under the frozen 1ms budget, and a
-# repeated-dashboard statement mix must serve >= 90% from the
-# device-resident result cache bit-identical to an opted-out session;
-# the JSON verdict (with bench_meta provenance) lands in $BENCH_OUT
-# when set.
-#
 # --planprof additionally runs the plan-profile smoke
 # (tools/planprof_smoke.py): a warm TPC-H Q1/Q6/Q3 mix profiled
 # through the segmented per-operator executor must return rows
@@ -152,8 +121,6 @@ cd "$(dirname "$0")/.."
 rm -f /tmp/_t1.log
 
 chaos=0
-latency=0
-serve=0
 awr=0
 health=0
 advisor=0
@@ -164,14 +131,11 @@ mesh=0
 stream=0
 scrub=0
 hosttax=0
-hostpath=0
 planprof=0
 ann=0
 while true; do
     case "$1" in
         --chaos) chaos=1; shift ;;
-        --latency) latency=1; shift ;;
-        --serve) serve=1; shift ;;
         --awr) awr=1; shift ;;
         --health) health=1; shift ;;
         --advisor) advisor=1; shift ;;
@@ -182,7 +146,6 @@ while true; do
         --stream) stream=1; shift ;;
         --scrub) scrub=1; shift ;;
         --hosttax) hosttax=1; shift ;;
-        --hostpath) hostpath=1; shift ;;
         --planprof) planprof=1; shift ;;
         --ann) ann=1; shift ;;
         *) break ;;
@@ -203,31 +166,6 @@ if [ "$chaos" = "1" ] && [ "$rc" = "0" ]; then
     timeout -k 10 600 env JAX_PLATFORMS=cpu python -m pytest \
         tests/test_chaos.py -q -m slow \
         -p no:cacheprovider -p no:xdist -p no:randomly
-    rc=$?
-fi
-
-if [ "$latency" = "1" ] && [ "$rc" = "0" ]; then
-    timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/latency_bench.py \
-        --rows 2000 --stmts 80 --warmup 10 --strict
-    rc=$?
-fi
-
-if [ "$serve" = "1" ] && [ "$rc" = "0" ]; then
-    timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/latency_bench.py \
-        --rows 1000 --sessions 16 --serve-seconds 2 --serve-strict
-    rc=$?
-fi
-
-if [ "$serve" = "1" ] && [ "$rc" = "0" ]; then
-    timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/latency_bench.py \
-        --rows 1000 --wire-sessions 128 --wire-seconds 2 --wire-strict \
-        --wire-min-speedup 1.0 --wire-min-tail-win 3.0
-    rc=$?
-fi
-
-if [ "$serve" = "1" ] && [ "$rc" = "0" ]; then
-    timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/latency_bench.py \
-        --fairness --fairness-seconds 1.5 --fairness-strict
     rc=$?
 fi
 
@@ -278,11 +216,6 @@ fi
 
 if [ "$hosttax" = "1" ] && [ "$rc" = "0" ]; then
     timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/hosttax_smoke.py
-    rc=$?
-fi
-
-if [ "$hostpath" = "1" ] && [ "$rc" = "0" ]; then
-    timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/hostpath_smoke.py
     rc=$?
 fi
 
