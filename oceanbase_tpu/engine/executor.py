@@ -41,6 +41,7 @@ from ..expr import ir as E
 from ..expr.compile import (
     bind_value,
     compile_predicate,
+    count_lowering,
     derive_dict_column,
     evaluate,
     infer_type,
@@ -274,6 +275,36 @@ class ClusteredAggSpec:
     build_table: str
     pk_col: str
     input_alias: str  # inputs key carrying the (starts, ends) arrays
+    # the ranges tile the probe table (Executor._fk_ranges proved it on
+    # the host): a group's lower bound IS its neighbour's upper bound, so
+    # the program gathers the running table once per group, not twice
+    tiled: bool = False
+
+
+def segment_bounds(running: dict, starts, ends, tiled: bool):
+    """(at_hi, at_lo): every running column of the clustered-FK aggregate
+    at row `ends - 1` and at row `starts - 1` of the probe table, one
+    value per build row (`upto(x) = c[x-1] if x > 0 else 0`; the caller
+    masks x == 0). ONE packed row-gather per bound materializes every
+    aggregate's running value (ops/gather.py). When the ranges tile
+    (ClusteredAggSpec.tiled), starts[j] == ends[j-1] on every real build
+    row, so the lower bound's row is the one the neighbour just fetched:
+    the second gather is the first one's output shifted down a row, bit
+    for bit (row 0 and the padded tail have starts == 0)."""
+    from ..ops.gather import gather_rows
+
+    cap = next(iter(running.values())).shape[0]
+    at_hi = gather_rows(running, jnp.clip(ends - 1, 0, cap - 1))
+    if tiled:
+        count_lowering("clustered agg bounds shared")
+        at_lo = {
+            k: jnp.concatenate([jnp.zeros(1, v.dtype), v[:-1]])
+            for k, v in at_hi.items()
+        }
+    else:
+        count_lowering("clustered agg bounds gathered")
+        at_lo = gather_rows(running, jnp.clip(starts - 1, 0, cap - 1))
+    return at_hi, at_lo
 
 
 def _number_nodes(plan: LogicalOp) -> dict[int, LogicalOp]:
@@ -644,7 +675,7 @@ class Executor:
         return total
 
     def fk_ranges(self, probe_table: str, fk_col: str,
-                  build_table: str, pk_col: str):
+                  build_table: str, pk_col: str, tiled: bool = False):
         """Device (starts, ends) int32 arrays over build-table rows: build
         row i joins exactly the probe rows [starts[i], ends[i]) — valid
         because the probe's fk column is stored CLUSTERED (monotone
@@ -654,13 +685,36 @@ class Executor:
         reference's ordered-index row ranges (an FK sstable scan range per
         PK, cf. storage/access table scan ranges) and what lets a PK-FK
         join + group-by collapse into segment reductions with no sort and
-        no per-probe-row gather."""
+        no per-probe-row gather.
+
+        `tiled` is what the calling program was compiled to assume
+        (ClusteredAggSpec.tiled, carried by its '#fkr:' input_spec entry):
+        a program that shares each group's lower bound with its
+        neighbour's upper bound must never meet ranges that stopped
+        tiling, so that raises the recompile signal. The reverse is
+        harmless (two gathers are exact over any ranges)."""
+        dev, tiles = self._fk_ranges(probe_table, fk_col, build_table, pk_col)
+        if tiled and not tiles:
+            raise ClusteredPremiseInvalidated(
+                f"{probe_table}.{fk_col} ranges no longer tile over "
+                f"{build_table}.{pk_col}"
+            )
+        return dev
+
+    def _fk_ranges(self, probe_table: str, fk_col: str,
+                   build_table: str, pk_col: str):
+        """((starts, ends), tiles) of fk_ranges, cached by the two table
+        versions. `tiles`: the ranges of the real build rows partition a
+        prefix of the probe table in build order — starts[0] == 0 and
+        starts[j] == ends[j-1] — which holds whenever the build table lies
+        in key order and every fk value below the last pk has its pk (any
+        valid foreign key over tables stored by key)."""
         vp = self._table_version.get(probe_table, 0)
         vb = self._table_version.get(build_table, 0)
         key = (probe_table, ("#fkr", fk_col, build_table, pk_col))
         hit = self._batch_cache.get(key)
         if hit is not None and hit[0] == (vp, vb):
-            return hit[1]
+            return hit[1], hit[2]
         # data changed since the spec was detected: the clustering premise
         # must be re-proven, not assumed — a cached plan over a now
         # unsorted fk would binary-search garbage and silently mis-group
@@ -674,14 +728,17 @@ class Executor:
         pk = np.asarray(tb.data[pk_col])
         lo = np.searchsorted(fk, pk, side="left").astype(np.int32)
         hi = np.searchsorted(fk, pk, side="right").astype(np.int32)
+        tiles = bool(
+            len(lo) >= 1 and lo[0] == 0 and np.array_equal(lo[1:], hi[:-1])
+        )
         cap = max(1024, -(-max(tb.nrows, 1) // 1024) * 1024)
         if cap > len(lo):
             pad = np.zeros(cap - len(lo), dtype=np.int32)
             lo = np.concatenate([lo, pad])
             hi = np.concatenate([hi, pad])
         dev = (jnp.asarray(lo), jnp.asarray(hi))
-        self._batch_cache[key] = ((vp, vb), dev)
-        return dev
+        self._batch_cache[key] = ((vp, vb), dev, tiles)
+        return dev, tiles
 
     def input_batch(self, alias: str, table: str, cols: tuple):
         """One jit input from its input_spec entry: a table ColumnBatch,
@@ -1717,9 +1774,11 @@ class Executor:
         input_alias = (
             f"#fkr:{base.table}.{fk_col}->{build_table}.{pk_col}"
         )
+        _ranges, tiled = self._fk_ranges(
+            base.table, fk_col, build_table, pk_col)
         return ClusteredAggSpec(
             ji, base.table, fk_col, fk_name, build_table, pk_col,
-            input_alias,
+            input_alias, tiled,
         )
 
     def _emit_grouping_sets(self, op: Aggregate, nid, inputs, emit, params):
@@ -1800,7 +1859,6 @@ class Executor:
         semantics match the generic paths (NULL args skipped via
         validity; sum over an empty/all-NULL group yields 0 like
         sort_groupby's masked segmented cumsum)."""
-        from ..ops.gather import gather_rows
         from ..sql.planner import _substitute
 
         ji = spec.ji
@@ -1824,11 +1882,7 @@ class Executor:
                     else v.dtype
                 )
                 running[i] = jnp.cumsum(jnp.where(am, v, 0).astype(acc))
-        cap = L.capacity
-        # ONE packed row-gather per bound materializes every aggregate's
-        # running value (ops/gather.py); `upto(x) = c[x-1] if x>0 else 0`
-        at_hi = gather_rows(running, jnp.clip(ends - 1, 0, cap - 1))
-        at_lo = gather_rows(running, jnp.clip(starts - 1, 0, cap - 1))
+        at_hi, at_lo = segment_bounds(running, starts, ends, spec.tiled)
 
         def seg(k):
             h = jnp.where(ends > 0, at_hi[k], 0)
@@ -1921,7 +1975,7 @@ class Executor:
                             spec.input_alias,
                             spec.probe_table,
                             (spec.probe_table, spec.fk_col,
-                             spec.build_table, spec.pk_col),
+                             spec.build_table, spec.pk_col, spec.tiled),
                         ))
 
         overflow_nodes: list[int] = sorted(
@@ -3773,6 +3827,10 @@ class PreparedPlan(Dispatchable):
         from .plan_artifact import ArtifactStale
 
         for _attempt in range(3):
+            # inputs before the executable: assembling them re-proves the
+            # clustered premises and may recompile, which drops every
+            # narrow executable built on the old program
+            inputs = self._inputs()
             fn = self._narrow.get(ncap)
             if fn is None:
                 if not self._traceable:
@@ -3780,6 +3838,7 @@ class PreparedPlan(Dispatchable):
                     # a fresh jit — one honest recompile restores
                     # traceability (the backend hits the XLA disk cache)
                     self.recompile()
+                    inputs = self._inputs()
                 # build + first-trace under the lock: tracing re-enters
                 # plan emission's process-global parameter frame, exactly
                 # like the batched buckets
@@ -3789,14 +3848,14 @@ class PreparedPlan(Dispatchable):
                         fn = self._build_narrow(ncap)
                         self.executor.narrow_compiles += 1
                         try:
-                            res = fn(self._inputs(), qparams)
+                            res = fn(inputs, qparams)
                         except ArtifactStale:
                             self.recompile()
                             continue
                         self._narrow[ncap] = fn
                         return res
             try:
-                return fn(self._inputs(), qparams)
+                return fn(inputs, qparams)
             except ArtifactStale:
                 self._narrow.pop(ncap, None)
                 self.recompile()
@@ -3830,6 +3889,8 @@ class PreparedPlan(Dispatchable):
 
         for attempt in range(max_retries + 1):
             checkpoint()
+            # inputs before the executable, as in _run_narrow
+            inputs = self._inputs()
             fn = self._batched.get(bucket)
             if fn is None and not self._traceable:
                 # warm (artifact-loaded) plan: vmap over a deserialized
@@ -3844,6 +3905,7 @@ class PreparedPlan(Dispatchable):
                     self._batched[bucket] = fn
                 else:
                     self.recompile()
+                    inputs = self._inputs()
             if fn is None:
                 # build + first-trace under the lock: tracing re-enters
                 # plan emission, which installs the process-global active
@@ -3855,7 +3917,7 @@ class PreparedPlan(Dispatchable):
                         fn = jax.jit(jax.vmap(self.jitted,
                                               in_axes=(None, 0)))
                         self.executor.batched_compiles += 1
-                        out, ovf_vec = fn(self._inputs(), qblock)
+                        out, ovf_vec = fn(inputs, qblock)
                         self._batched[bucket] = fn
                         if self.artifact_ref is not None:
                             try:
@@ -3864,10 +3926,10 @@ class PreparedPlan(Dispatchable):
                             except Exception:
                                 pass
                     else:
-                        out, ovf_vec = fn(self._inputs(), qblock)
+                        out, ovf_vec = fn(inputs, qblock)
             else:
                 try:
-                    out, ovf_vec = fn(self._inputs(), qblock)
+                    out, ovf_vec = fn(inputs, qblock)
                 except ArtifactStale:
                     # catalog drift under a hydrated bucket executable:
                     # drop it and redrive through a clean rebuild

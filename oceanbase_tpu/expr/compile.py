@@ -248,12 +248,21 @@ _lookup_tls = threading.local()
 
 def set_lookup_metrics(metrics):
     """Install the registry (share/metrics.py) that counts this thread's
-    dict_lookup choices as `dict lookup <lowering>`; returns the previous
-    one. The server sets it for the length of a statement: lowerings are
-    chosen at trace time, so the counters move per compiled program."""
+    lowering choices (`dict lookup <lowering>`, the executor's `clustered
+    agg bounds <shared|gathered>`); returns the previous one. The server
+    sets it for the length of a statement: lowerings are chosen at trace
+    time, so the counters move per compiled program."""
     prev = getattr(_lookup_tls, "metrics", None)
     _lookup_tls.metrics = metrics
     return prev
+
+
+def count_lowering(name: str) -> None:
+    """Count one trace-time choice between lowerings into the registry
+    set_lookup_metrics installed on this thread (none installed: no-op)."""
+    m = getattr(_lookup_tls, "metrics", None)
+    if m is not None:
+        m.add(name)
 
 
 def _true_runs(table: np.ndarray) -> list[tuple[int, int]]:
@@ -280,27 +289,22 @@ def dict_lookup(table: np.ndarray, codes) -> jnp.ndarray:
     table's size (150 entries or 200 K)."""
     table = np.asarray(table)
     n = len(table)
-    m = getattr(_lookup_tls, "metrics", None)
-
-    def chose(lowering):
-        if m is not None:
-            m.add(f"dict lookup {lowering}")
 
     if n == 0 or (table.dtype == np.bool_ and table.all() == table.any()):
-        chose("constant")
+        count_lowering("dict lookup constant")
         fill = table[0] if n else np.zeros((), table.dtype)
         return jnp.full(jnp.shape(codes), fill, dtype=table.dtype)
     c = jnp.clip(codes, 0, n - 1)
     if table.dtype == np.bool_:
         runs = _true_runs(table)
         if len(runs) <= LOOKUP_MAX_RUNS:
-            chose("runs")
+            count_lowering("dict lookup runs")
             out = None
             for lo, hi in runs:
                 t = c == lo if hi == lo + 1 else (c >= lo) & (c < hi)
                 out = t if out is None else out | t
             return out
-    chose("gather")
+    count_lowering("dict lookup gather")
     return jnp.asarray(table)[c]
 
 

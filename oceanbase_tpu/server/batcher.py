@@ -112,13 +112,16 @@ def _combo_run(pa, pb, qa: np.ndarray, qb: np.ndarray):
     if bb > qb.shape[0]:
         qb = np.concatenate(
             [qb, np.repeat(qb[:1], bb - qb.shape[0], axis=0)])
-    fa, fb = pa.jitted, pb.jitted
-    key = (id(fa), id(fb), ba, bb)
     try:
+        # inputs before the callables: assembling them can recompile a
+        # plan (a clustered premise dissolved), which swaps its callable
+        ia, ib = pa._inputs(), pb._inputs()
+        fa, fb = pa.jitted, pb.jitted
+        key = (id(fa), id(fb), ba, bb)
         hit = _COMBO_CACHE.get(key)
         if hit is not None:
             _COMBO_CACHE.move_to_end(key)
-            outs = hit[0](pa._inputs(), qa, pb._inputs(), qb)
+            outs = hit[0](ia, qa, ib, qb)
         else:
             # build + first-trace under the batch compile lock: tracing
             # re-enters plan emission's process-global parameter frame,
@@ -133,12 +136,12 @@ def _combo_run(pa, pb, qa: np.ndarray, qb: np.ndarray):
                         )
 
                     fn = jax.jit(run)
-                    outs = fn(pa._inputs(), qa, pb._inputs(), qb)
+                    outs = fn(ia, qa, ib, qb)
                     _COMBO_CACHE[key] = (fn, fa, fb)
                     while len(_COMBO_CACHE) > _COMBO_CAP:
                         _COMBO_CACHE.popitem(last=False)
                 else:
-                    outs = hit[0](pa._inputs(), qa, pb._inputs(), qb)
+                    outs = hit[0](ia, qa, ib, qb)
         (outa, ovfa), (outb, ovfb) = outs
         hovfa, hca, hva, hsa, hovfb, hcb, hvb, hsb = jax.device_get(
             (ovfa, outa.cols, outa.valid, outa.sel,

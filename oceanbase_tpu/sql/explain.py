@@ -77,7 +77,8 @@ def explain_plan(executor, plan, params) -> list[str]:
             mode = (
                 f"clustered-FK segment reduction over "
                 f"{spec.probe_table}.{spec.fk_col} -> "
-                f"{spec.build_table}.{spec.pk_col}"
+                f"{spec.build_table}.{spec.pk_col}, "
+                + ("bounds shared" if spec.tiled else "two bound gathers")
                 if spec is not None else
                 "grouping sets expand" if op.grouping_sets is not None
                 else "sort/direct group-by"

@@ -90,12 +90,43 @@ def _entry(sess, text):
     return entry.prepared, qp
 
 
+@pytest.fixture(scope="module")
+def q3_v5e(chip, tpch):
+    """(prepared, v5e text) of Q3's plan, compiled once for the tests
+    that read it."""
+    shapes, _ = chip
+    sess = Session(tpch, unique_keys=UNIQUE_KEYS)
+    prepared, qp = _entry(sess, QUERIES[3])
+    compiled = _compile(
+        prepared.jitted, shapes(prepared._inputs()), shapes(qp))
+    return prepared, compiled.as_text()
+
+
 @pytest.mark.parametrize("q", [6, 1, 14, 3, 15])
-def test_tpch_plan_compiles_for_v5e(chip, tpch, q):
+def test_tpch_plan_compiles_for_v5e(chip, tpch, q, request):
+    if q == 3:
+        request.getfixturevalue("q3_v5e")
+        return
     shapes, _ = chip
     sess = Session(tpch, unique_keys=UNIQUE_KEYS)
     prepared, qp = _entry(sess, QUERIES[q])
     _compile(prepared.jitted, shapes(prepared._inputs()), shapes(qp))
+
+
+def test_q3_aggregate_gathers_one_bound_on_v5e(q3_v5e):
+    """datagen's orders lie in key order and every l_orderkey has its
+    order, so Q3's clustered-FK ranges tile and the aggregate's lower
+    bounds are its upper bounds shifted: the v5e text has ONE gather under
+    the Aggregate node (two where the ranges do not tile)."""
+    prepared, text = q3_v5e
+    (spec,) = prepared.params.clustered_aggs.values()
+    assert spec.tiled
+    gathers = re.findall(r' gather\(.*op_name="([^"]*)"', text)
+    # the innermost plan node of the op's scope (the aggregate emits the
+    # joins below it, whose own gathers are theirs)
+    own = [n for n in gathers
+           if re.findall(r"/([A-Za-z:]+)#\d+", n)[-1:] == ["Aggregate"]]
+    assert len(own) == 1, gathers
 
 
 @pytest.fixture(scope="module")
@@ -176,6 +207,39 @@ def test_dict_lookup_limit_compiles_for_v5e_without_a_gather(chip):
         C.set_lookup_metrics(prev)
     assert reg.counter("dict lookup runs") == 1
     assert " gather(" not in text
+
+
+def test_shared_bounds_aggregate_compiles_for_v5e_with_one_gather(chip):
+    """The clustered-FK aggregate's bounds at the join cell's shapes
+    (lineitem's 6,000,640 rows, orders' 1,500,160 groups, a row count and
+    one 64-bit sum as Q3 has them) with the ranges proved tiling: the v5e
+    compiler takes the shift and the program gathers once."""
+    from oceanbase_tpu.engine.executor import segment_bounds
+    from oceanbase_tpu.expr import compile as C
+
+    shapes, _ = chip
+    jnp = jax.numpy
+    rows = jax.ShapeDtypeStruct((6_000_640,), np.bool_)
+    price = jax.ShapeDtypeStruct((6_000_640,), np.int64)
+    bound = jax.ShapeDtypeStruct((1_500_160,), np.int32)
+
+    def agg(live, v, starts, ends):
+        running = {"#cnt": jnp.cumsum(live.astype(jnp.int64)),
+                   0: jnp.cumsum(jnp.where(live, v, 0))}
+        at_hi, at_lo = segment_bounds(running, starts, ends, tiled=True)
+        return [jnp.where(ends > 0, at_hi[k], 0)
+                - jnp.where(starts > 0, at_lo[k], 0) for k in running]
+
+    reg = MetricsRegistry()
+    prev = C.set_lookup_metrics(reg)
+    try:
+        text = _compile(
+            jax.jit(agg), *shapes((rows, price, bound, bound))).as_text()
+    finally:
+        C.set_lookup_metrics(prev)
+    assert reg.counter("clustered agg bounds shared") == 1
+    assert reg.counter("clustered agg bounds gathered") == 0
+    assert text.count(" gather(") == 1
 
 
 def test_filtered_knn_compiles_for_v5e(chip):

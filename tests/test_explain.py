@@ -40,8 +40,24 @@ def test_q6_shows_projection_slice(db):
 def test_q3_shows_clustered_aggregation(db):
     t = _text(db, QUERIES[3])
     assert "clustered-FK segment reduction" in t
-    assert "lineitem.l_orderkey -> orders.o_orderkey" in t
+    # which program the user got: datagen's orders lie in key order and
+    # every l_orderkey has its order, so the ranges tile
+    assert "lineitem.l_orderkey -> orders.o_orderkey, bounds shared" in t
     assert "direct-address (affine build key)" in t  # orders x customer
+
+
+def test_q3_counts_its_bounds_in_sysstat(db):
+    """The program EXPLAIN names is the one a served Q3 compiles, and the
+    tenant's sysstat counts it per compiled program."""
+    s = db.session()
+    assert len(s.sql(QUERIES[3]).rows()) > 0
+    stat = {
+        r[0]: float(r[1]) for r in s.sql(
+            "select name, value from __all_virtual_sysstat "
+            "where name like 'clustered agg bounds%'").rows()
+    }
+    assert stat.get("clustered agg bounds shared", 0) >= 1
+    assert stat.get("clustered agg bounds gathered", 0) == 0
 
 
 def test_ann_route_annotated(db):

@@ -25,11 +25,15 @@ F64 = DataType(TypeKind.FLOAT64)
 I64N = DataType(TypeKind.INT64, nullable=True)
 
 
-def _tables(seed=7, nprobe=5000, nbuild=400):
+def _tables(seed=7, nprobe=5000, nbuild=400, fk_pool=None, pk=None):
     rng = np.random.default_rng(seed)
     # clustered fk: sorted, with runs, referencing ~half the build keys,
     # plus some fk values that exist in no build row
-    fk = np.sort(rng.integers(0, nbuild * 2, nprobe)).astype(np.int64)
+    if fk_pool is None:
+        fk = rng.integers(0, nbuild * 2, nprobe)
+    else:
+        fk = rng.choice(fk_pool, nprobe)
+    fk = np.sort(fk).astype(np.int64)
     val = rng.integers(-50, 50, nprobe).astype(np.int64)
     val_null = rng.random(nprobe) < 0.15
     flt = rng.integers(0, 10, nprobe).astype(np.int32)
@@ -43,7 +47,10 @@ def _tables(seed=7, nprobe=5000, nbuild=400):
         {"fk": fk, "val": val, "flt": flt},
         valid={"val": ~val_null},
     )
-    pk = rng.permutation(nbuild * 2)[:nbuild].astype(np.int64)
+    if pk is None:
+        pk = rng.permutation(nbuild * 2)[:nbuild]
+    pk = np.asarray(pk, dtype=np.int64)
+    nbuild = len(pk)
     battr = rng.integers(0, 5, nbuild).astype(np.int32)
     build = Table(
         "build",
@@ -228,6 +235,111 @@ def test_clustered_premise_revalidated_after_dml():
     rs2 = sess.sql(Q_CLUSTERED)
     # grouped sums are permutation-invariant: identical rows expected
     assert rs2.rows() == rs1.rows()
+
+
+def _run_counted(sess, q):
+    """(rows, the one clustered spec, counter moves) of one statement run
+    with a registry installed as the server installs its tenant's."""
+    from oceanbase_tpu.expr import compile as C
+    from oceanbase_tpu.share.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    prev = C.set_lookup_metrics(reg)
+    try:
+        rows = sess.sql(q).rows()
+    finally:
+        C.set_lookup_metrics(prev)
+    entry, _ = sess.cached_entry(q)
+    (spec,) = entry.prepared.params.clustered_aggs.values()
+    moved = {k: int(reg.counter(f"clustered agg bounds {k}"))
+             for k in ("shared", "gathered")}
+    return rows, spec, moved
+
+
+# build keys 0..n-1 in storage order unless said; the probe's fk values
+# are drawn from fk_pool. Tiled = every real build row's range starts
+# where its neighbour's ends and the first starts at row 0.
+RANGE_SHAPES = {
+    # (a) sorted build, every fk has its pk, no padded tail (cap == n)
+    "sorted_complete": (dict(pk=np.arange(1024),
+                             fk_pool=np.arange(1024)), True),
+    # (b) build rows that own no probe row: at the head, in the middle
+    # (a run of them) and at the tail — empty ranges still tile
+    "empty_ranges": (dict(pk=np.arange(1024), fk_pool=np.r_[
+        3:200, 260:1000]), True),
+    # (c) build capacity not a multiple of 1024 (padded tail of
+    # starts == ends == 0), with fk values past the last pk
+    "padded_tail": (dict(pk=np.arange(1500),
+                         fk_pool=np.arange(1520)), True),
+    # (d) orphan fk values before the first pk: starts[0] > 0
+    "orphans_before_first_pk": (dict(pk=np.arange(5, 1029),
+                                     fk_pool=np.arange(1029)), False),
+    # (e) the build a permutation with orphan fks (Q_CLUSTERED's own)
+    "permuted_build": (dict(), False),
+}
+
+
+@pytest.mark.parametrize("shape", list(RANGE_SHAPES))
+def test_clustered_agg_bounds_by_range_shape(shape):
+    """Where the host proves that the FK ranges tile, the program fetches
+    each group's bound once (the lower bound is the neighbour's upper
+    bound); elsewhere it gathers both. Either way the rows are the generic
+    path's, and the counter says which program was compiled."""
+    kw, tiled = RANGE_SHAPES[shape]
+    sess = Session(_tables(**kw), unique_keys={"build": (("pk",),)})
+    got, spec, moved = _run_counted(sess, Q_CLUSTERED)
+    assert spec.tiled is tiled
+    assert moved == {"shared": int(tiled), "gathered": int(not tiled)}
+    want = _run(_tables(**kw), Q_CLUSTERED, clustered=False)
+    assert len(got) > 5 and got == want
+
+
+def _delete_pk(cat):
+    """A PK deleted that FKs still name: its probe rows become orphans
+    between two build rows' ranges."""
+    b = cat["build"]
+    keep = b.data["pk"] != 500
+    b.data = {c: a[keep] for c, a in b.data.items()}
+    return ["build"]
+
+
+def _append_out_of_key_order(cat):
+    """Key 700 arrives late: its build row is appended after key 1023,
+    its probe rows are merged in at their clustered position."""
+    b, p = cat["build"], cat["probe"]
+    b.data = {"pk": np.r_[b.data["pk"], 700],
+              "battr": np.r_[b.data["battr"], 1].astype(np.int32)}
+    at = int(np.searchsorted(p.data["fk"], 700))
+    ins = {"fk": [700] * 3, "val": [5, -7, 11], "flt": [1, 2, 9]}
+    p.data = {c: np.insert(a, at, ins[c]) for c, a in p.data.items()}
+    p.valid = {"val": np.insert(p.valid["val"], at, [True, False, True])}
+    return ["build", "probe"]
+
+
+@pytest.mark.parametrize("dml", [_delete_pk, _append_out_of_key_order])
+def test_tiled_premise_revalidated_after_build_dml(dml):
+    """A plan compiled to share bounds must not meet ranges that stopped
+    tiling: the DML bumps a version, fk_ranges re-proves on the host, and
+    the plan recompiles once into the two-gather program."""
+    keys = np.r_[0:700, 701:1024]  # key 700 is not there yet
+    kw = dict(pk=keys, fk_pool=keys)
+    cat = _tables(**kw)
+    sess = Session(cat, unique_keys={"build": (("pk",),)})
+    _rows, spec, _moved = _run_counted(sess, Q_CLUSTERED)
+    assert spec.tiled
+    for name in dml(cat):
+        sess.executor.invalidate_table(name)
+    compiles = sess.executor.compiles
+    got, spec, moved = _run_counted(sess, Q_CLUSTERED)
+    assert not spec.tiled
+    assert sess.executor.compiles == compiles + 1
+    assert moved == {"shared": 0, "gathered": 1}
+    cat2 = _tables(**kw)
+    dml(cat2)
+    assert got == _run(cat2, Q_CLUSTERED, clustered=False)
+    # settled: the next statement compiles nothing
+    assert _run_counted(sess, Q_CLUSTERED)[0] == got
+    assert sess.executor.compiles == compiles + 1
 
 
 def test_topn_prefilter_hazards():

@@ -16,7 +16,8 @@ stderr beside its own lines:
                     modules
   named_counters    thread-CPU ms per statement and the front end's pool
                     hand-off wait per statement over the measured window;
-                    the `dict lookup <lowering>` counters since the start;
+                    the `dict lookup <lowering>` and `clustered agg bounds
+                    <shared|gathered>` counters since the start;
                     the `result frames prefetched` / `result frames lazy`
                     counters' deltas over the window;
                     on a PX deployment the `px ...` counters' deltas over
@@ -345,7 +346,10 @@ def main(argv) -> int:
                 # program is traced, which the warm-up does
                 "dict_lookup": {
                     k: db.metrics.counter(f"dict lookup {k}")
-                    for k in ("constant", "runs", "gather")}}})
+                    for k in ("constant", "runs", "gather")},
+                "clustered_agg_bounds": {
+                    k: db.metrics.counter(f"clustered agg bounds {k}")
+                    for k in ("shared", "gathered")}}})
             log({"slow_statements": slow_statements(db, a["at"], b["at"])})
             if b["px"] is not None:
                 before = (a["px"] or {}).get("counters", {})
