@@ -1111,19 +1111,19 @@ class Executor:
                 params.topn_cand[nid] = max(
                     256, -(-4 * (op.n + op.offset) // 64) * 64
                 )
-            if (
-                isinstance(op, Aggregate) and len(op.group_keys) > 1
-                and op.grouping_sets is None
-            ):
+            if isinstance(op, Aggregate) and op.grouping_sets is None:
                 # multi-key sort group-bys pack into ONE int64 sort key
                 # when every key's domain is statically known: wide
                 # multi-operand sorts go superlinear past ~16M rows on
-                # v5e, a packed key keeps the canonical fast sort shape
+                # v5e, a packed key keeps the canonical fast sort shape.
+                # The keys that are sorted: dependent ones are carried
                 ranges = [
                     self._static_key_range(op.child, e)
-                    for _n, e in op.group_keys
+                    for _n, e in op.sorted_keys
                 ]
-                if all(r is not None for r in ranges) and sum(
+                if len(ranges) > 1 and all(
+                    r is not None for r in ranges
+                ) and sum(
                     b for _v, b in ranges
                 ) <= 62:
                     params.pack_guard[nid] = tuple(ranges)
@@ -3217,7 +3217,20 @@ class Executor:
                 params.pack_guard.get(nid)
                 if nid not in params.groupby_nopack else None
             )
-            if n_nullable:
+            # keys the others determine (`Aggregate.dependent_keys`) are
+            # no sort operand: they are carried to their group's row
+            dep = [n for n, _t in op.dependent_keys]
+            if dep:
+                count_lowering("group keys dependent", len(dep))
+            carry, sort_keys = {}, []
+            for (name, _e), v, vv in zip(op.group_keys, key_vals, key_valids):
+                if name not in dep:
+                    sort_keys.append((name, v, vv))
+                    continue
+                carry[name] = v
+                if vv is not None:
+                    carry["valid:" + name] = vv
+            if any(vv is not None for _n, _v, vv in sort_keys):
                 # validity planes don't fit the static pack spec: take the
                 # multi-operand sort path (nullable keys are rare and never
                 # the TPC-H hot group-bys)
@@ -3229,7 +3242,7 @@ class Executor:
                 # recompiles rather than mis-grouping
                 pk = jnp.zeros(child.capacity, dtype=jnp.int64)
                 invalid = jnp.zeros(child.capacity, dtype=jnp.bool_)
-                for v, (vmin, bits) in zip(key_vals, pack_spec):
+                for (_n, v, _vv), (vmin, bits) in zip(sort_keys, pack_spec):
                     off = v.astype(jnp.int64) - vmin
                     invalid = invalid | (off < 0) | (off >= (1 << bits))
                     pk = (pk << bits) | jnp.clip(off, 0, (1 << bits) - 1)
@@ -3237,36 +3250,39 @@ class Executor:
                 ovf[PACK_GUARD_BASE + nid] = jnp.sum(
                     invalid & child.sel, dtype=jnp.int64
                 )
-                skeys_p, sel, agg_cols, order = sort_groupby(
-                    [pk], child.sel, agg_ops, agg_vals, agg_masks
+                skeys_p, sel, agg_cols, carried = sort_groupby(
+                    [pk], child.sel, agg_ops, agg_vals, agg_masks, carry
                 )
                 # decode the original key columns from the packed bits
                 cols = {}
                 shift = 0
-                for (name, _e), v, (vmin, bits) in zip(
-                    reversed(op.group_keys), reversed(key_vals),
-                    reversed(pack_spec),
+                for (name, v, _vv), (vmin, bits) in zip(
+                    reversed(sort_keys), reversed(pack_spec),
                 ):
                     part = (skeys_p[0] >> shift) & ((1 << bits) - 1)
                     cols[name] = (part + vmin).astype(v.dtype)
                     shift += bits
             else:
                 vplanes = [
-                    vv.astype(jnp.int32) for vv in key_valids
+                    vv.astype(jnp.int32) for _n, _v, vv in sort_keys
                     if vv is not None
                 ]
-                skeys, sel, agg_cols, order = sort_groupby(
-                    key_vals + vplanes, child.sel, agg_ops, agg_vals,
-                    agg_masks
+                skeys, sel, agg_cols, carried = sort_groupby(
+                    [v for _n, v, _vv in sort_keys] + vplanes, child.sel,
+                    agg_ops, agg_vals, agg_masks, carry
                 )
                 cols = {}
-                for (name, _e), kv in zip(op.group_keys, skeys):
+                for (name, _v, _vv), kv in zip(sort_keys, skeys):
                     cols[name] = kv
-                vi = len(op.group_keys)
-                for (name, _e), vv in zip(op.group_keys, key_valids):
+                vi = len(sort_keys)
+                for name, _v, vv in sort_keys:
                     if vv is not None:
                         out_valid[name] = skeys[vi].astype(jnp.bool_)
                         vi += 1
+            for name in dep:
+                cols[name] = carried[name]
+                if "valid:" + name in carried:
+                    out_valid[name] = carried["valid:" + name]
             for (name, _, _, _), av in zip(op.aggs, agg_cols):
                 cols[name] = av
         else:
@@ -3613,6 +3629,16 @@ class Dispatchable:
         dispatch and the solo path already amortizes it via the XLA
         result cache; vector/legacy-tuple plans opted out of packing)."""
         return bool(self._qparam_spec)
+
+    @property
+    def exchange_slots(self) -> int:
+        """Rows of capacity this plan's row exchanges deliver in one
+        execution, over all shards (counter `px exchange slots`; over it
+        `px exchange rows`, the live ones, is lane occupancy): each of
+        `px_nsh` shards receives `px_nsh` lanes of an exchange's capacity,
+        from an all_to_all and from an all_gather alike. Static; 0 off PX."""
+        return self.px_nsh * self.px_nsh * sum(
+            cap for _kind, _ncols, cap in self.px_exchanges or ())
 
     def run(self, max_retries: int = 3, qparams: tuple = ()):
         """The synced device batch at the plan's own capacities — what
@@ -4033,6 +4059,15 @@ class DeviceResult:
         # whole of it was started (and so is what _sync reads)
         self.frame_bytes = 0
         self.prefetched = False
+        self._hovf = None      # the clean run's overflow vector, on the host
+
+    @property
+    def exchange_rows(self) -> int:
+        """Live rows the program's exchanges delivered, over all shards: the
+        element a PX program appends to its overflow vector (0 off PX),
+        which the sync has read anyway."""
+        self._sync()
+        return int(self._hovf[len(self.prepared.overflow_nodes):].sum())
 
     @property
     def narrowed(self) -> bool:
@@ -4102,6 +4137,7 @@ class DeviceResult:
             overflows = p._overflows(hovf)
             if not overflows and not short:
                 self._nrows = hn
+                self._hovf = hovf
                 if whole:
                     # commit ONLY on a clean run: an overflowed attempt's
                     # arrays are garbage and must not seed the host cache

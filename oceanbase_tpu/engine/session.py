@@ -870,6 +870,8 @@ class Session:
                 mon.px_collective_ops += mesh_plan.total_ops
                 mon.px_collective_bytes += mesh_plan.total_bytes
                 mon.px_exchanges = mesh_plan.describe()
+                mon.px_exchange_rows += cursor.exchange_rows
+                mon.px_exchange_slots += prepared.exchange_slots
             if stream_d is not None:
                 mon.stream_chunks += stream_d[0]
                 mon.spill_partitions += stream_d[6]
@@ -921,6 +923,10 @@ class Session:
                 # the served PX route prepares and dispatches here, not
                 # through PxExecutor.execute: count it where it runs
                 m.add("px executions")
+                # what its exchanges delivered and what they hold room
+                # for: the quotient is lane occupancy
+                m.add("px exchange rows", cursor.exchange_rows)
+                m.add("px exchange slots", prepared.exchange_slots)
                 if retries > 0:
                     # an exchange lane or a join capacity of a mesh
                     # program overflowed: each is one more PX compile

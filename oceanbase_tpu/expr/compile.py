@@ -257,12 +257,13 @@ def set_lookup_metrics(metrics):
     return prev
 
 
-def count_lowering(name: str) -> None:
-    """Count one trace-time choice between lowerings into the registry
-    set_lookup_metrics installed on this thread (none installed: no-op)."""
+def count_lowering(name: str, n: int = 1) -> None:
+    """Count a trace-time choice between lowerings (`n` of them) into the
+    registry set_lookup_metrics installed on this thread (none installed:
+    no-op)."""
     m = getattr(_lookup_tls, "metrics", None)
     if m is not None:
-        m.add(name)
+        m.add(name, n)
 
 
 def _true_runs(table: np.ndarray) -> list[tuple[int, int]]:

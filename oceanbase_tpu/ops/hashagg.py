@@ -274,6 +274,7 @@ def sort_groupby(
     agg_ops: list[str],
     agg_values: list[jnp.ndarray | None],
     agg_masks: list[jnp.ndarray | None] = None,
+    carry: dict[str, jnp.ndarray] | None = None,
 ):
     """Sort-based group-by: the TPU default for unbounded key domains.
 
@@ -285,9 +286,13 @@ def sort_groupby(
     key order).
 
     Returns (group_keys: list [N] arrays, sel [N] bool group-start mask,
-    aggs: list [N] arrays, order [N] int32 the sort permutation).
+    aggs: list [N] arrays, carried: {name: [N] array}).
     agg_masks[i] (optional) restricts which rows feed aggregate i (SQL
-    null-skipping); rows outside `mask` never contribute.
+    null-skipping); rows outside `mask` never contribute. `carry` holds
+    columns the keys determine (equal on every row of a group): they are
+    no sort operand, ride the packed row-gather of the aggregate inputs
+    into sorted order, and a group's live row (its segment start) then
+    holds its value.
     """
     from .sort import rebuild_i64, split_sort_key
     from .window import (
@@ -348,6 +353,8 @@ def sort_groupby(
         am = agg_masks[i] if agg_masks is not None else None
         if am is not None:
             to_sort[("m", i)] = am
+    for name, a in (carry or {}).items():
+        to_sort[("c", name)] = a
     sorted_in = gather_rows(to_sort, order) if to_sort else {}
 
     # accumulate every per-agg running array, then ONE packed gather at
@@ -382,7 +389,7 @@ def sort_groupby(
     ends = gather_rows(running, seg_end) if running else {}
     aggs_out = [ends[i] for i in range(len(agg_ops))]
     sel = new_seg & ssel
-    return skeys, sel, aggs_out, order
+    return skeys, sel, aggs_out, {n: sorted_in[("c", n)] for n in carry or {}}
 
 
 def scalar_aggregate(

@@ -229,6 +229,13 @@ class PxExecutor(Executor):
     def _dist(self, value: dict[int, str]) -> None:
         self._trace_local.dist = value
 
+    @property
+    def _delivered(self) -> list:
+        """Of the program being traced: the live rows each row exchange
+        (`_gather_batch`, `_exchange_dest`) left on this shard, one traced
+        scalar per exchange, in the order of `prepared.px_exchanges`."""
+        return self._trace_local.__dict__.setdefault("delivered", [])
+
     def __init__(self, catalog, mesh: Mesh, unique_keys=None,
                  default_rows_estimate=1 << 16,
                  broadcast_threshold: int = 1 << 16,
@@ -354,7 +361,9 @@ class PxExecutor(Executor):
             compile_s = _time.perf_counter() - t0
             retries0 = prepared.retries
             t0 = _time.perf_counter()
-            out = prepared.run(max_retries)
+            cursor = prepared.dispatch(
+                (), max_retries=max_retries, fused=False)
+            out = cursor.batch()
             exec_s = _time.perf_counter() - t0
             if tr is not None:
                 # per-DFO worker spans (one per exchange boundary the
@@ -371,6 +380,8 @@ class PxExecutor(Executor):
                 root.tags["exec_us"] = int(exec_s * 1e6)
             if m is not None:
                 m.add("px executions")
+                m.add("px exchange rows", cursor.exchange_rows)
+                m.add("px exchange slots", prepared.exchange_slots)
                 retries = prepared.retries - retries0
                 if retries > 0:
                     m.add("px overflow recompiles", retries)
@@ -569,6 +580,7 @@ class PxExecutor(Executor):
             else:
                 out, mask = broadcast_rows(payload, b.sel)
             nrows = jnp.sum(mask, dtype=jnp.int64)
+        self._delivered.append(nrows)
         return ColumnBatch(
             cols={n: out[f"c:{n}"] for n in b.cols},
             valid={n: out[f"v:{n}"] for n in b.valid},
@@ -591,6 +603,7 @@ class PxExecutor(Executor):
             out, mask, ovf = repartition(
                 payload, b.sel, dest_of(), self.nsh, cap)
             nrows = jnp.sum(mask, dtype=jnp.int64)
+        self._delivered.append(nrows)
         nb = ColumnBatch(
             cols={n: out[f"c:{n}"] for n in b.cols},
             valid={n: out[f"v:{n}"] for n in b.valid},
@@ -1182,10 +1195,11 @@ class PxExecutor(Executor):
             self._dist[id(op)] = REPLICATED
             return out, ovf
 
-        # generic hash group-by: co-partition rows on the group keys, then
-        # each shard owns its key space entirely
+        # generic hash group-by: co-partition rows on the group keys (the
+        # ones no other key determines: rows equal on those are equal on
+        # all), then each shard owns its key space entirely
         child2, xovf = self._exchange_hash(
-            child, [e for _, e in op.group_keys],
+            child, [e for _n, e in op.sorted_keys],
             params.exchange_cap[lane], lane)
         out, ovf = super()._emit_aggregate(
             op, nid, inputs, _override(emit, op.child, (child2, covf)), params)
@@ -1271,6 +1285,7 @@ class PxExecutor(Executor):
                     dicts=dicts,
                 )
             self._dist = {}
+            del self._delivered[:]
             prev = expr_compile.set_params(qparams if qparams else None)
             try:
                 out, ovf = emit(plan, inputs)
@@ -1291,12 +1306,15 @@ class PxExecutor(Executor):
             # overflow counters must leave the shard_map replicated; psum
             # may multiply already-replicated counters by nsh, which is
             # harmless (the driver only tests >0)
+            # one more element rides the vector the host reads at the sync
+            # anyway: the live rows the exchanges delivered, over all shards
+            # (`DeviceResult.exchange_rows`, counter `px exchange rows`)
+            delivered = sum(self._delivered, jnp.zeros((), jnp.int64))
             ovf_vec = jnp.stack([
-                lax.psum(
-                    ovf.get(n, jnp.zeros((), jnp.int64)), SHARD_AXIS
-                )
-                for n in overflow_nodes
-            ]) if overflow_nodes else jnp.zeros((0,), jnp.int64)
+                lax.psum(v, SHARD_AXIS) for v in (
+                    *(ovf.get(n, jnp.zeros((), jnp.int64))
+                      for n in overflow_nodes), delivered)
+            ])
             return out, ovf_vec
 
         def run(raw_inputs, qparams):

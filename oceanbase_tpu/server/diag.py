@@ -304,6 +304,11 @@ class PlanMonitorEntry:
     px_collective_ops: int = 0
     px_collective_bytes: int = 0
     px_exchanges: str = ""
+    # lane occupancy of its row exchanges, cumulative: live rows they
+    # delivered over the rows they hold room for, all shards (the
+    # sysstat counters `px exchange rows` / `px exchange slots`, per plan)
+    px_exchange_rows: int = 0
+    px_exchange_slots: int = 0
     # streaming pipeline (engine/pipeline.py): chunks streamed through
     # this plan, last run's H2D/compute overlap fraction, and grace-hash
     # partitions spilled to host segments

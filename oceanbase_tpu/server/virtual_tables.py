@@ -188,6 +188,8 @@ def _plan_monitor(db) -> Table:
             "px_collective_ops": e.px_collective_ops,
             "px_collective_bytes": e.px_collective_bytes,
             "px_exchanges": e.px_exchanges,
+            "px_exchange_rows": e.px_exchange_rows,
+            "px_exchange_slots": e.px_exchange_slots,
             "stream_chunks": e.stream_chunks,
             "h2d_overlap_pct": round(e.h2d_overlap_pct, 3),
             "spill_partitions": e.spill_partitions,
@@ -206,6 +208,7 @@ def _plan_monitor(db) -> Table:
                 "total_transfer_bytes": 0, "last_device_bytes": 0,
                 "peak_bytes": 0, "px_collective_ops": 0,
                 "px_collective_bytes": 0, "px_exchanges": "",
+                "px_exchange_rows": 0, "px_exchange_slots": 0,
                 "stream_chunks": 0, "h2d_overlap_pct": 0.0,
                 "spill_partitions": 0,
                 "est_rows": r["est_rows"],
@@ -236,6 +239,10 @@ def _plan_monitor(db) -> Table:
         ("px_collective_ops", DataType.int64()),
         ("px_collective_bytes", DataType.int64()),
         ("px_exchanges", DataType.varchar()),
+        # lane occupancy: live rows the plan's row exchanges delivered and
+        # the rows they hold room for, cumulative over its executions
+        ("px_exchange_rows", DataType.int64()),
+        ("px_exchange_slots", DataType.int64()),
         # streaming pipeline (engine/pipeline.py): chunks streamed through
         # the plan, last run's H2D/compute overlap percentage, grace-hash
         # partitions spilled; zeros for resident plans

@@ -10,6 +10,8 @@ compiles: everything shown is host-side planning state."""
 
 from __future__ import annotations
 
+from collections import Counter
+
 from .logical import (
     Aggregate,
     Distinct,
@@ -83,7 +85,12 @@ def explain_plan(executor, plan, params) -> list[str]:
                 "grouping sets expand" if op.grouping_sets is not None
                 else "sort/direct group-by"
             )
-            keys = [n for n, _ in op.group_keys]
+            keys = str([n for n, _ in op.sorted_keys])
+            # keys the sort and the hash leave out: a unique key of
+            # `table` among the group keys determines them
+            for table, n in Counter(
+                    t for _n, t in op.dependent_keys).items():
+                keys += f" (+{n} dependent on the unique key of {table})"
             lines.append(
                 f"{pad}AGGREGATE [{mode}] keys={keys} "
                 f"aggs={[f'{f}({n})' for n, f, _a, _d in op.aggs]} {est(op)}"

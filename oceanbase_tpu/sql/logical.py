@@ -70,6 +70,33 @@ class Aggregate(LogicalOp):
     # executor aggregates once per set and NULL-fills absent keys
     # (the reference's EXPAND operator, ob_phy_operator_type.h)
     grouping_sets: tuple[tuple[int, ...], ...] | None = None
+    # (key name, table) of group keys that the other keys determine: plain
+    # columns of a base-table instance whose declared unique key is wholly
+    # among the group keys (the reference keeps such facts as ObFdItem and
+    # drops the keys in ObTransformSimplifyGroupby). They stay in
+    # `group_keys`, so names, order and schema are what was written and an
+    # emitter that ignores the fact groups as before; one that reads it
+    # sorts and hashes the other keys only and takes each dependent from
+    # any row of its group (`Executor._emit_aggregate`, `_emit_agg_px`).
+    dependent_keys: tuple[tuple[str, str], ...] = ()
+
+    @property
+    def sorted_keys(self) -> tuple[tuple[str, E.Expr], ...]:
+        """The group keys no other key determines: what a group-by has
+        to sort and a hash exchange to hash."""
+        dep = {n for n, _t in self.dependent_keys}
+        return tuple(k for k in self.group_keys if k[0] not in dep)
+
+    def __repr__(self) -> str:
+        # the dataclass's own text, with the new field only where it says
+        # something: a plan without dependents keeps the fingerprint, so
+        # the program name and the lowered text, it had before the field
+        text = (f"Aggregate(child={self.child!r}, "
+                f"group_keys={self.group_keys!r}, aggs={self.aggs!r}, "
+                f"grouping_sets={self.grouping_sets!r}")
+        if self.dependent_keys:
+            text += f", dependent_keys={self.dependent_keys!r}"
+        return text + ")"
 
 
 @dataclass
@@ -147,6 +174,40 @@ def op_kind(op) -> str:
     if k in ("JoinOp", "SetOp") and kind:
         return f"{k[:-2] if k == 'JoinOp' else k}:{kind}"
     return k
+
+
+def dependent_group_keys(group_keys, base_tables: dict,
+                         unique_keys: dict) -> tuple:
+    """The `(key name, table)` pairs of `group_keys` that the other keys
+    determine. `base_tables` maps the alias of each base-table instance
+    whose rows are never null-extended to `(table, its NOT NULL columns)`.
+    A key is dependent when it is a plain column of an instance whose
+    declared unique key has every column, each NOT NULL, among the group
+    keys as a plain column of that same instance: rows that agree on the
+    unique key are one row of the table, so they agree on its other
+    columns (NULLs would group as one and be many rows). Nothing is
+    inferred through join equalities (`o_custkey = c_custkey` makes no key
+    of `customer` present), so TPC-H Q3's keys stay three and Q10's seven
+    become two."""
+    by_alias: dict[str, dict[str, str]] = {}  # alias -> column -> key name
+    for name, e in group_keys:
+        if isinstance(e, E.ColRef) and "." in e.name:
+            alias, col = e.name.split(".", 1)
+            if alias in base_tables:
+                by_alias.setdefault(alias, {})[col] = name
+    names = [n for n, _e in group_keys]
+    if len(set(names)) != len(names):
+        return ()
+    out = []
+    for alias, cols in by_alias.items():
+        table, not_null = base_tables[alias]
+        held = next((uk for uk in unique_key_sets(unique_keys, table)
+                     if uk and set(uk) <= set(cols) & not_null), None)
+        if held is not None:
+            out += [(name, table) for col, name in cols.items()
+                    if col not in held]
+    order = {n: i for i, n in enumerate(names)}
+    return tuple(sorted(out, key=lambda d: order[d[0]]))
 
 
 def unique_key_sets(unique_keys: dict, table: str) -> tuple:

@@ -40,6 +40,7 @@ from .logical import (
     Sort,
     TopN,
     Window,
+    dependent_group_keys,
     output_schema,
     unique_key_sets,
 )
@@ -634,6 +635,15 @@ class Planner:
             plan, agg_out_sub = self._build_aggregate(
                 plan, key_exprs, r.agg_exprs,
                 group_sets=getattr(sel, "group_sets", None),
+                # base-table instances no outer join null-extends: the
+                # ones whose unique key can make other group keys dependent
+                base_tables={} if has_full else {
+                    rel.alias: (rel.scan.table, {
+                        f.name.split(".", 1)[1]
+                        for f in rel.scan.schema.fields
+                        if not f.dtype.nullable})
+                    for rel in relations
+                    if rel.is_scan and rel.alias not in outer_right},
             )
             out_items = [(n, _substitute(e, agg_out_sub)) for n, e in out_items]
             for kind, sub_plan, lkeys, rkeys, resid in scalar_join_after_agg:
@@ -742,9 +752,12 @@ class Planner:
         return plan, r, out_items, visible
 
     # ------------------------------------------------- aggregate helper
-    def _build_aggregate(self, plan, key_exprs, agg_exprs, group_sets=None):
+    def _build_aggregate(self, plan, key_exprs, agg_exprs, group_sets=None,
+                         base_tables=None):
         """Build the Aggregate node; expands DISTINCT aggregates into a
-        pre-dedup (Distinct over keys+arg) + plain aggregate."""
+        pre-dedup (Distinct over keys+arg) + plain aggregate. The plain
+        Aggregate is told which of its keys a unique key among them
+        determines (`dependent_group_keys`)."""
         # group keys that are dictionary TRANSFORMS (substr / json_*)
         # cannot evaluate inside the aggregate (the engine's group-by
         # paths see plain columns): pre-project them below the Aggregate
@@ -797,7 +810,9 @@ class Planner:
         # mixed / multiple / non-count DISTINCT aggregates flow through:
         # the executor masks each distinct agg to first occurrences
         plan = Aggregate(plan, tuple(key_exprs), tuple(agg_exprs),
-                         grouping_sets=group_sets)
+                         grouping_sets=group_sets,
+                         dependent_keys=dependent_group_keys(
+                             key_exprs, base_tables or {}, self.unique_keys))
         sub = {e: E.ColRef(n) for n, e in orig_key_exprs}
         return plan, sub
 

@@ -16,13 +16,15 @@ stderr beside its own lines:
                     modules
   named_counters    thread-CPU ms per statement and the front end's pool
                     hand-off wait per statement over the measured window;
-                    the `dict lookup <lowering>` and `clustered agg bounds
-                    <shared|gathered>` counters since the start;
+                    the `dict lookup <lowering>`, `clustered agg bounds
+                    <shared|gathered>` and `group keys dependent` counters
+                    since the start;
                     the `result frames prefetched` / `result frames lazy`
                     counters' deltas over the window;
                     on a PX deployment the `px ...` counters' deltas over
-                    the window, the mesh's devices and each one's peak and
-                    row-sharded bytes
+                    the window (`px exchange rows` over `px exchange slots`
+                    is `exchange_occupancy_pct`), the mesh's devices and
+                    each one's peak and row-sharded bytes
   slow_statements   the window's three longest statements by the audit ring,
                     with their phases and the garbage collections of 20 ms
                     and more that overlap them
@@ -349,13 +351,21 @@ def main(argv) -> int:
                     for k in ("constant", "runs", "gather")},
                 "clustered_agg_bounds": {
                     k: db.metrics.counter(f"clustered agg bounds {k}")
-                    for k in ("shared", "gathered")}}})
+                    for k in ("shared", "gathered")},
+                "group_keys_dependent":
+                    db.metrics.counter("group keys dependent")}})
             log({"slow_statements": slow_statements(db, a["at"], b["at"])})
             if b["px"] is not None:
                 before = (a["px"] or {}).get("counters", {})
-                log({"named_px": dict(b["px"], counters={
-                    k: v - before.get(k, 0)
-                    for k, v in b["px"]["counters"].items()})})
+                moved = {k: v - before.get(k, 0)
+                         for k, v in b["px"]["counters"].items()}
+                slots = moved.get("px exchange slots")
+                log({"named_px": dict(
+                    b["px"], counters=moved,
+                    # live rows the exchanges delivered over the rows
+                    # they hold room for, all chips, the whole window
+                    exchange_occupancy_pct=100.0 * moved.get(
+                        "px exchange rows", 0) / slots if slots else None)})
         return counters(self)
 
     server.Served.counters = counters_and_mine

@@ -7,6 +7,7 @@ is a device time.
     python tools/compile_sweep.py                  # every phase at SF 0.01
     python tools/compile_sweep.py --sf 10 --phases tpch --only q1,q6,q3,q14
     python tools/compile_sweep.py --phases px      # four-device mesh
+    python tools/compile_sweep.py --sf 1 --phases px --only q10
 
 The parent never imports JAX. Each phase runs in a child process that
 drives chip_smoke's own phase on the CPU (so the plan cache holds exactly
@@ -55,6 +56,8 @@ def run_phase(phase: str, sf: float, only: str, out) -> list[dict]:
                 in_flight = rec["start"]
                 continue
             if "program" not in rec:
+                if rec.get("stmt", "").endswith(" px"):
+                    print(line, end="", flush=True)  # drive_px's comparison
                 continue  # one of the smoke's own lines from the CPU drive
             in_flight = None
             skip.append(rec["program"])  # a restart does not redo it
@@ -81,18 +84,61 @@ def run_phase(phase: str, sf: float, only: str, out) -> list[dict]:
 
 def table(rows: list[dict]) -> str:
     lines = ["| phase | sf | program | result | compile s | args MB | "
-             "temp MB | out MB |", "|---|---|---|---|---|---|---|---|"]
+             "temp MB | out MB | sorts (operands each) |",
+             "|---|---|---|---|---|---|---|---|---|"]
     for r in rows:
         mb = lambda k: (f"{r[k] / 2**20:.1f}" if k in r else "")  # noqa: E731
         lines.append(
             f"| {r['phase']} | {r['sf']:g} | {r['program']} | "
             f"{'ok' if r['ok'] else r['error'][:80]} | "
             f"{r.get('compile_s', 0):.1f} | {mb('argument_bytes')} | "
-            f"{mb('temp_bytes')} | {mb('output_bytes')} |")
+            f"{mb('temp_bytes')} | {mb('output_bytes')} | "
+            f"{r.get('sort_operands', '')} |")
     return "\n".join(lines)
 
 
+def sort_operands(lowered_text: str) -> list[int]:
+    """Operand count of every `sort` in a program's lowered text, in
+    program order: compile seconds on the TPU follow the widest one (ISSUE
+    32: Q10's 10-operand group-by sort against the same plan at 4)."""
+    import re
+
+    return [len(m.split(",")) for m in re.findall(
+        r'"stablehlo\.sort"\(([^)]*)\)', lowered_text)]
+
+
 # ------------------------------------------------------------------- child
+
+def drive_px(ctx, smoke, queries: list[int]) -> None:
+    """`--only` in the px phase: the named statements of the TPC-H suite at
+    `ob_px_dop = 4` over the CPU's four host devices, as `phase_px` drives
+    its three (the tenant parameter, a connection opened after it, each
+    statement twice so the capacities settle), every answer held to the
+    one-chip executor's."""
+    from oceanbase_tpu.models.tpch import schema as S
+    from oceanbase_tpu.models.tpch.sql_suite import QUERIES
+
+    one_chip = ctx.connect()
+    smoke.load_tpch(ctx, one_chip, list(S.TABLES))
+    ctx.connect().query("alter system set ob_px_dop = 4")
+    c = ctx.connect()
+    one_chip.query("set ob_px_dop = 0")
+    moved = ("px overflow recompiles", "px exchange rows",
+             "px exchange slots")
+    for q in queries:
+        before = {n: ctx.db.metrics.counter(n) for n in moved}
+        px_rows = c.query(QUERIES[q])
+        c.query(QUERIES[q])
+        equal = smoke.same_rows(px_rows, one_chip.query(QUERIES[q]))
+        # a lane that overflowed was one more compile of the program, and
+        # rows over slots is what its exchanges carry of what they hold
+        print(json.dumps({"stmt": f"tpch q{q} px", "rows": len(px_rows),
+                          "equal": equal, **{
+                              n: ctx.db.metrics.counter(n) - v
+                              for n, v in before.items()}}), flush=True)
+        if not equal:
+            raise AssertionError(f"Q{q}: dop 4 and dop 0 disagree")
+
 
 def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
     sys.path.insert(0, REPO)
@@ -154,8 +200,12 @@ def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
                       smoke.knn_text("docs", q0, "where grp < 5 "))
             name_stmt("knn docs_ddl", smoke.knn_text("docs_ddl", q0))
         elif phase == "px":
-            smoke.phase_px(ctx)
-            for q in (6, 1, 3):
+            queries = sorted(int(q[1:]) for q in only)
+            if queries:
+                drive_px(ctx, smoke, queries)
+            else:
+                smoke.phase_px(ctx)
+            for q in queries or (6, 1, 3):
                 name_stmt(f"q{q}", QUERIES[q])
 
         # the CPU drive is done; what follows compiles for a chip that is
@@ -185,7 +235,9 @@ def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
             rec = {"program": name}
             t0 = time.perf_counter()
             try:
-                compiled = fn.lower(*args).compile()
+                lowered = fn.lower(*args)
+                rec["sort_operands"] = sort_operands(lowered.as_text())
+                compiled = lowered.compile()
                 m = compiled.memory_analysis()
                 rec.update(ok=True, compile_s=time.perf_counter() - t0,
                            argument_bytes=m.argument_size_in_bytes,
@@ -200,6 +252,8 @@ def child(phase: str, sf: float, only: set[str], skip: set[str]) -> int:
         for key, entry in list(db.plan_cache._entries.items()):
             p = entry.prepared
             name = names.get(key[1], key[1][:60])
+            if phase == "px" and only and not getattr(p, "px_nsh", 0):
+                continue  # the one-chip side of drive_px's comparison
             if not hasattr(p, "jitted") or not hasattr(p, "_inputs"):
                 print(json.dumps({
                     "program": name, "ok": False,
@@ -282,7 +336,9 @@ def main() -> int:
     ap.add_argument("--sf", type=float, default=0.01)
     ap.add_argument("--phases", default=",".join(PHASES))
     ap.add_argument("--only", default="",
-                    help="tpch phase: comma list of queries (q1,q6,...)")
+                    help="tpch and px phases: comma list of queries "
+                         "(q1,q6,...); the px phase drives q6,q1,q3 "
+                         "without it")
     ap.add_argument("--out", default=os.path.join(
         REPO, "chiprun_out", "compile_sweep.jsonl"))
     ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
