@@ -2282,6 +2282,9 @@ class Executor:
                     left.sel & in_range & (bk_at == lkeys[0]) & rsel
                 )
             else:
+                # which programs hold a sort-merge join at all (one count
+                # a join, at trace time): its run heads ride a scan
+                count_lowering("merge join scan-carried")
                 match = merge_join_unique(
                     rkeys[0], right.sel, lkeys[0], left.sel
                 )

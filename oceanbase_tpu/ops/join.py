@@ -4,18 +4,24 @@ Reference surface: ObHashJoinVecOp (sql/engine/join/hash_join/
 ob_hash_join_vec_op.h:316 — build :402, probe :425), merge join, and
 nested-loop join.
 
-TPU redesign, driven by measured v5e costs (8M rows: sort ~20ms, cumsum
-~7ms, random gather ~60-120ms, scatter ~1.1s, open-addressing while-loops
-~30s): the hot joins are SORT-based and scatter-free.
+TPU redesign, driven by what the benchmark's ledger reads on a v5e
+(PERF_LEDGER.jsonl, PR 31 / PR 32; PERF.md section 5): a 1-D element gather
+~20 ns an element whatever its index pattern, a packed row gather 2-6 ns a
+row, a 64-bit (two-plane) scan ~1.3 ns an element, a 32-bit scan ~0.2 ns, a
+5-plane sort of 2.5 M entries ~4.5 ns an entry; a scatter ~1.1 s and an
+open-addressing while-loop ~30 s per 8 M rows (pre-PR-1 probes). So the hot
+joins are SORT-based, scatter-free, and gather nothing a scan or a sort can
+carry.
 
 - merge_join_unique (unique single-int-key build — the PK-FK case that
-  covers most TPC-H/TPC-DS joins): one combined sort of (key, side, row)
-  over build++probe; within a key run the build row (if any) sorts first,
-  a segmented cummax pins it, and an inverse permutation (argsort of the
-  sort permutation — a sort, not a scatter) maps matches back to original
-  probe order. Output keeps the probe side's static capacity: each probe
-  row gets the matching build row index (or -1), and payload columns
-  materialize by gather.
+  covers most TPC-H/TPC-DS joins): one combined sort of (dead, key, side,
+  row) over build++probe; within a key run the build row (if any) sorts
+  first. The value at each run's head rides a scan (a two-plane running
+  max under the head's position), and original probe order is restored by
+  a second sort that carries the match as its operand — no gather, no
+  inverse permutation. Output keeps the probe side's static capacity: each
+  probe row gets the matching build row index (or -1); the caller
+  materializes payload columns by one packed row gather.
 
 - expand_join (M:N general case): sort the build side by key once, binary
   search each probe key's [lo, hi) duplicate range (searchsorted
@@ -117,6 +123,15 @@ def hash_join_probe(
     return match_row
 
 
+def _later_head(a, b):
+    """reduce_window combiner over (head position, carried value) pairs:
+    the pair with the larger position. Positions are distinct (-1 for
+    "no head", whose value is -1 too), so this is a max over a total
+    order — commutative and associative, whatever tree XLA reduces by."""
+    take = b[0] > a[0]
+    return jnp.where(take, b[0], a[0]), jnp.where(take, b[1], a[1])
+
+
 def merge_join_unique(
     build_key: jnp.ndarray,
     build_mask: jnp.ndarray,
@@ -132,6 +147,14 @@ def merge_join_unique(
     Deadness rides as a separate LEADING sort operand rather than an
     in-band sentinel value, so the full int64 key domain (including
     2^62.. and int64 max) joins correctly.
+
+    Two sorts and one scan, no gather: values at run heads ride a scan,
+    order is restored by a carrying sort (a 1-D element gather costs ~20 ns
+    an element on a v5e, the scan ~1, the carrying sort ~2: PERF.md
+    section 5). The scan is two int32 planes and not one 64-bit
+    `lax.cummax`: two of those in one program crash the installed v5e
+    compiler (tests/test_ops.py `test_position_scans_are_32_bit`), and a
+    plan may hold several joins.
     """
     nb = build_key.shape[0]
     npr = probe_key.shape[0]
@@ -154,16 +177,24 @@ def merge_join_unique(
         [jnp.ones(1, jnp.bool_),
          (sk[1:] != sk[:-1]) | (sdead[1:] != sdead[:-1])]
     )
-    run_start = jax.lax.cummax(jnp.where(new_run, pos, 0))
-    b_at_start = sside[run_start] == 0
-    cand = sidx[run_start]
-    match_sorted = jnp.where(
-        (sside == 1) & (sdead == 0) & b_at_start, cand, -1
+    # the run head's build row (-1: the head is no live build row) rides a
+    # running max under the head's position: positions strictly increase,
+    # so the max at any entry IS its own run's head — a two-plane scan,
+    # where sidx[run_start] would be a 1-D element gather. A dead entry's
+    # run has a dead head, so dead probe rows read -1 with no mask of
+    # their own.
+    head_b = new_run & (sside == 0) & (sdead == 0)
+    _, match_sorted = jax.lax.reduce_window(
+        (jnp.where(new_run, pos, -1), jnp.where(head_b, sidx, -1)),
+        (jnp.int32(-1), jnp.int32(-1)),
+        _later_head, (n,), (1,), [(n - 1, 0)],
     )
-    # inverse permutation restricted to probe entries — computed by a
-    # second sort (argsort), never a scatter
-    inv = jnp.argsort(sside.astype(jnp.int64) * n + sidx)
-    return match_sorted[inv[nb:]]
+    # back to probe order by a sort that carries the match: probe entries
+    # first by original row, build entries (dropped) behind them — never
+    # a scatter, and no inverse permutation to gather through
+    back = jnp.where(sside == 1, sidx, sidx + npr)
+    _, out = jax.lax.sort((back, match_sorted), num_keys=1)
+    return out[:npr]
 
 
 def gather_payload(

@@ -52,7 +52,7 @@ def explain_plan(executor, plan, params) -> list[str]:
             return "expand (M:N sort + binary search)"
         if op.left_keys and executor._affine_build_info(op) is not None:
             return "direct-address (affine build key)"
-        return "merge (combined sort, unique build)"
+        return "merge (combined sort, run heads scan-carried, unique build)"
 
     def rec(op, depth):
         pad = "  " * depth

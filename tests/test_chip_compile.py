@@ -209,6 +209,28 @@ def test_dict_lookup_limit_compiles_for_v5e_without_a_gather(chip):
     assert " gather(" not in text
 
 
+def test_two_sort_merge_joins_compile_for_v5e_without_a_gather(chip):
+    """Two `merge_join_unique` in one program, as Q10's PX plan holds them:
+    the v5e compiler takes both (two 64-bit `lax.cummax` in one program
+    are a SIGSEGV in it, so each run head's row rides a two-plane int32
+    scan) and the program is two sorts and one scan a join, no gather."""
+    from oceanbase_tpu.ops.join import merge_join_unique
+
+    shapes, _ = chip
+
+    def side(n):
+        return (jax.ShapeDtypeStruct((n,), np.int64),
+                jax.ShapeDtypeStruct((n,), np.bool_))
+
+    fn = jax.jit(lambda b1, p1, b2, p2: (
+        merge_join_unique(*b1, *p1), merge_join_unique(*b2, *p2)))
+    text = _compile(fn, *shapes(
+        (side(4096), side(8192), side(2048), side(6144)))).as_text()
+    assert " gather(" not in text
+    assert len(re.findall(r" sort\(", text)) == 4
+    assert " reduce-window(" in text
+
+
 def test_shared_bounds_aggregate_compiles_for_v5e_with_one_gather(chip):
     """The clustered-FK aggregate's bounds at the join cell's shapes
     (lineitem's 6,000,640 rows, orders' 1,500,160 groups, a row count and

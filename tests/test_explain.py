@@ -60,6 +60,27 @@ def test_q3_counts_its_bounds_in_sysstat(db):
     assert stat.get("clustered agg bounds gathered", 0) == 0
 
 
+def test_q17_shows_and_counts_its_sort_merge_join(db):
+    """`part` meets a grouped subquery: a unique build that is no table in
+    storage order, so the join sort-merges. EXPLAIN names the lowering and
+    a served Q17 counts the join, once per compiled program; Q3's joins
+    above are direct-address and count nothing."""
+    assert ("merge (combined sort, run heads scan-carried, unique build)"
+            in _text(db, QUERIES[17]))
+    s = db.session()
+
+    def counted():
+        rows = s.sql("select value from __all_virtual_sysstat "
+                     "where name = 'merge join scan-carried'").rows()
+        return float(rows[0][0]) if rows else 0.0
+
+    before = counted()
+    s.sql(QUERIES[3]).rows()
+    assert counted() == before
+    s.sql(QUERIES[17]).rows()
+    assert counted() == before + 1
+
+
 def test_ann_route_annotated(db):
     import numpy as np
 

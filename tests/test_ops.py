@@ -19,6 +19,7 @@ from oceanbase_tpu.ops import (
     sort_indices,
     topn_indices,
 )
+from oceanbase_tpu.ops.join import merge_join_unique
 
 
 def test_pack_keys():
@@ -139,6 +140,94 @@ def test_hash_join_unique_build(rng):
     for i in range(np_):
         want = key_to_row.get(int(probe_keys[i]), -1) if probe_mask[i] else -1
         assert match[i] == want, (i, match[i], want)
+
+
+_I64 = np.iinfo(np.int64)
+
+
+def _mju_case(name, rng):
+    """(build keys, build mask, probe keys, probe mask) of one case."""
+    def unique(n, space):
+        return rng.permutation(space)[:n].astype(np.int64)
+
+    def probes(bk, n, miss=0.3):
+        pk = bk[rng.integers(0, len(bk), n)]
+        return np.where(rng.random(n) < miss, pk + 10**9, pk)
+
+    if name == "dead rows on both sides":
+        bk = unique(700, 5000)
+        return bk, rng.random(700) < 0.6, probes(bk, 3000), \
+            rng.random(3000) < 0.7
+    if name == "no live build row":
+        bk = unique(256, 1000)
+        return bk, np.zeros(256, bool), probes(bk, 1000, 0.0), \
+            rng.random(1000) < 0.9
+    if name == "every probe key missing":
+        bk = unique(512, 2000)
+        return bk, rng.random(512) < 0.9, unique(2048, 5000) + 10**6, \
+            np.ones(2048, bool)
+    if name == "int64 extremes":
+        # deadness is no in-band sentinel: min, max and 2^62 are keys,
+        # on live rows and on dead ones
+        edge = np.array([_I64.min, _I64.min + 1, -1, 0, 1, 2**62,
+                         2**62 + 1, _I64.max - 1, _I64.max], np.int64)
+        bk = np.concatenate([edge, edge[::2] + 7])
+        bm = np.ones(len(bk), bool)
+        bm[[1, 7, 10]] = False
+        pk = np.concatenate([edge, edge, unique(40, 100)])
+        pm = np.ones(len(pk), bool)
+        pm[len(edge):2 * len(edge):2] = False
+        return bk, bm, pk, pm
+    if name == "duplicate build keys":
+        bk = rng.integers(0, 60, 400).astype(np.int64)
+        return bk, rng.random(400) < 0.7, \
+            rng.integers(0, 80, 2000).astype(np.int64), \
+            rng.random(2000) < 0.9
+    if name == "nb >> np":
+        bk = unique(8192, 50000)
+        return bk, rng.random(8192) < 0.9, probes(bk, 37), \
+            rng.random(37) < 0.9
+    if name == "np >> nb":
+        bk = unique(13, 100)
+        return bk, rng.random(13) < 0.8, probes(bk, 9000), \
+            rng.random(9000) < 0.9
+    if name == "5% live build in a power-of-two lane":
+        # Q10's build under PX: exchange lanes at their capacity, the
+        # dead slots holding whatever the lane was padded with (zeros)
+        live = rng.random(4096) < 0.05
+        bk = np.where(live, unique(4096, 30000) + 1, 0)
+        return bk, live, np.where(rng.random(6000) < 0.2, 0,
+                                  probes(bk[live], 6000)), \
+            rng.random(6000) < 0.95
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "dead rows on both sides", "no live build row",
+    "every probe key missing", "int64 extremes", "duplicate build keys",
+    "nb >> np", "np >> nb", "5% live build in a power-of-two lane"])
+def test_merge_join_unique_matches_dictionary_join(name, rng):
+    """merge_join_unique against a plain dictionary join: every probe row,
+    in its original place, gets the live build row with its key (where
+    build keys repeat, the one winner is the first such row), a dead probe
+    row or a key no live build row has gets -1."""
+    bk, bm, pk, pm = _mju_case(name, rng)
+    match = np.asarray(jax.jit(merge_join_unique)(
+        jnp.asarray(bk), jnp.asarray(bm), jnp.asarray(pk), jnp.asarray(pm)))
+    assert match.shape == pk.shape and match.dtype == np.int32
+    first_live = {}
+    for i in range(len(bk) - 1, -1, -1):
+        if bm[i]:
+            first_live[int(bk[i])] = i
+    want = np.array([first_live.get(int(k), -1) if m else -1
+                     for k, m in zip(pk, pm)], np.int32)
+    assert (match == want).all(), np.flatnonzero(match != want)[:10]
+    hit = match >= 0
+    assert bm[match[hit]].all() and (bk[match[hit]] == pk[hit]).all()
+    if name in ("no live build row", "every probe key missing"):
+        assert not hit.any()
+    else:
+        assert hit.any() and (~hit).any()
 
 
 def test_expand_join_mn(rng):

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import sqlite3
 
 import numpy as np
@@ -25,6 +26,7 @@ from benchmark.tests import test_regroup_fault as fault
 from oceanbase_tpu.parallel import px as PX
 from oceanbase_tpu.server.async_front import AsyncMySqlFrontend
 from oceanbase_tpu.server.database import Database
+from test_px_served import lowered_px_text
 
 pytestmark = pytest.mark.multidevice
 
@@ -191,6 +193,47 @@ def test_reference_equals_sqlite_at_the_validation_literal(deployment):
         assert g[0] == w[0] and g[1] == w[1] and g[4:] == tuple(w[4:])
         assert g[2].scaleb(4) == w[2]  # revenue in units of 10^-4
         assert g[3].scaleb(2) == w[3]  # c_acctbal in cents
+
+
+def test_sort_merge_joins_gather_nothing():
+    """At SF 1 `orders` meets `customer` through hash lanes and `lineitem`
+    probes that join's result, so neither build is in storage order and
+    both joins sort-merge (`merge_join_unique`); at SF 0.01 every build is
+    under the broadcast threshold and joins by direct address. Lowering
+    the threshold steers this deployment into SF 1's plan: the answers
+    still equal the plain reference, the counter reads two such joins in
+    the one compiled program, and the program has no 1-D element gather
+    over a join's combined build ++ probe entries (there were three a
+    join: the run head's side, its row, and the way back to probe order)."""
+    d = Deployment()
+    d.db._px_executor().broadcast_threshold = 1 << 10
+    client = WireClient(d.port)
+    try:
+        verdict = check.judge(d.send_pool(client), d.reference_of, LIMIT)
+        assert verdict["correct"], (verdict["compared"], verdict["first_bad"])
+        programs = 1 + d.counter("px overflow recompiles")
+        assert d.counter("merge join scan-carried") == 2 * programs
+        text = lowered_px_text(
+            d.db, gen.render("q10", gen.VALIDATION["q10"]))
+    finally:
+        client.close()
+        d.close()
+    scope = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+
+    def node(loc):  # the plan node an op was emitted under
+        return scope[loc].rsplit("/", 1)[0]
+
+    # each join's combined sort: (dead, key, side, row) over build ++ probe
+    combined = {node(loc): int(n) for n, loc in re.findall(
+        r"^ *\}\) : \(tensor<(\d+)xi32>, tensor<\1xi64>, tensor<\1xi32>, "
+        r"tensor<\1xi32>\) -> [^\n]* loc\((#loc\d+)\)$", text, re.M)}
+    assert len(combined) == 2, combined
+    assert all(re.search(r"Join:inner#\d+$", at) for at in combined)
+    flat_gathers = [(node(loc), int(n)) for n, loc in re.findall(
+        r'"stablehlo\.gather"[^\n]*: \(tensor<(\d+)xi\d+>, '
+        r"tensor<\d+x1xi32>\) -> tensor<\d+xi\d+> loc\((#loc\d+)\)", text)]
+    assert flat_gathers  # the reader finds 1-D gathers where there are some
+    assert not [g for g in flat_gathers if combined.get(g[0]) == g[1]]
 
 
 @pytest.mark.parametrize("left_out", sorted(fault.LEFT_OUT))

@@ -129,6 +129,8 @@ def test_served_px_equals_plain_reference(deployment):
         assert d.counter("px overflow recompiles") == 0
         assert d.counter("px collective all_to_all") > 0  # Q3's hash lanes
         assert d.counter("px collective psum") > 0        # Q14's merge
+        # every build is a table in storage order: direct-address joins
+        assert d.counter("merge join scan-carried") == 0
     finally:
         client.close()
     # the admin connection was open before the ALTER SYSTEM: one chip

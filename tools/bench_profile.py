@@ -17,8 +17,8 @@ stderr beside its own lines:
   named_counters    thread-CPU ms per statement and the front end's pool
                     hand-off wait per statement over the measured window;
                     the `dict lookup <lowering>`, `clustered agg bounds
-                    <shared|gathered>` and `group keys dependent` counters
-                    since the start;
+                    <shared|gathered>`, `group keys dependent` and `merge
+                    join scan-carried` counters since the start;
                     the `result frames prefetched` / `result frames lazy`
                     counters' deltas over the window;
                     on a PX deployment the `px ...` counters' deltas over
@@ -353,7 +353,9 @@ def main(argv) -> int:
                     k: db.metrics.counter(f"clustered agg bounds {k}")
                     for k in ("shared", "gathered")},
                 "group_keys_dependent":
-                    db.metrics.counter("group keys dependent")}})
+                    db.metrics.counter("group keys dependent"),
+                "merge_join_scan_carried":
+                    db.metrics.counter("merge join scan-carried")}})
             log({"slow_statements": slow_statements(db, a["at"], b["at"])})
             if b["px"] is not None:
                 before = (a["px"] or {}).get("counters", {})
