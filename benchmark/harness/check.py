@@ -2,7 +2,10 @@
 
 Every answer of the window is compared with the plain reference of its
 statement and literals: strings and integers exactly, decimals by relative
-error. Three numbers come out, each with a limit of its own:
+error. An OK packet is an answer too: where the reference is an `int` (the
+affected-row count; 0 for BEGIN and COMMIT) the answer has to be that `int`,
+and an `int` where rows were due, or rows where an `int` was due, is wrong.
+Three numbers come out, each with a limit of its own:
 
   missing_answers   statements that failed or never answered      limit 0
   wrong_answers     answers with another shape, key or text       limit 0
@@ -18,6 +21,8 @@ TINY = Decimal("1e-12")
 
 def compare_rows(got, ref):
     """(wrong: bool, widest relative error) of one answer."""
+    if isinstance(got, int) or isinstance(ref, int):
+        return type(got) is not type(ref) or got != ref, 0.0
     if isinstance(got, str) or len(got) != len(ref):
         return True, 0.0
     worst = 0.0

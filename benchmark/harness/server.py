@@ -9,8 +9,27 @@ result. Tables are created by DDL over the wire and filled through
 
 from __future__ import annotations
 
+import glob
+import json
+import os
 import threading
 import time
+
+from . import layer
+
+
+def named_sysstat() -> set:
+    """The `sysstat.<name>` counters that the declarative layer metrics
+    name: the program's registry holds a counter from its first bump, so
+    one of these that it has not bumped yet reads 0.0, not absent."""
+    names = set()
+    for path in glob.glob(os.path.join(layer.DIR, "*.json")):
+        with open(path) as f:
+            spec = json.load(f)
+        for key in ("num", "minus", "den"):
+            if isinstance(spec.get(key), list):
+                names.update(n for n in spec[key] if n.startswith("sysstat."))
+    return names
 
 
 class CompileMeter:
@@ -51,6 +70,7 @@ class Served:
                            n_ls=int(cl["log_streams"]))
         self.front = AsyncMySqlFrontend(self.db).start()
         self.port = self.front.port
+        self.named = named_sysstat()
 
     def apply_settings(self, client, config: dict) -> None:
         """System parameters as an operator sets them: over the wire."""
@@ -87,15 +107,22 @@ class Served:
         return took
 
     def counters(self) -> dict:
-        """The counters the layer metrics name, flat, cumulative."""
+        """The counters the layer metrics name, flat, cumulative: the plan
+        cache's fast hits, XLA compiles, the host-tax registry, and every
+        named counter of the program (`__all_virtual_sysstat`) as
+        `sysstat.<name>`."""
         db = self.db
-        out = {"plan_cache.fast_hits": float(db.plan_cache.stats.fast_hits),
-               "xla.compiles": float(self.compiles.read()[0])}
+        out = dict.fromkeys(self.named, 0.0)
+        out.update((f"sysstat.{name}", float(v))
+                   for name, v in db.metrics.counters_snapshot().items())
+        out["plan_cache.fast_hits"] = float(db.plan_cache.stats.fast_hits)
+        out["xla.compiles"] = float(self.compiles.read()[0])
         tax = db.host_tax.snapshot()["digests"]
         out["host_tax.statements"] = float(sum(a["count"] for a in tax.values()))
         out["host_tax.e2e_s"] = float(sum(a["e2e_s"] for a in tax.values()))
         out["host_tax.unattributed_s"] = float(
             sum(a["unattributed_s"] for a in tax.values()))
+        out["host_tax.cpu_s"] = float(sum(a["cpu_s"] for a in tax.values()))
         for a in tax.values():
             for ph, v in a["phases"].items():
                 key = f"host_tax.phase.{ph}"

@@ -25,12 +25,14 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from benchmark.generators import sysbench, tpch  # noqa: E402
+from benchmark.generators import sysbench, sysbench_oltp, tpch  # noqa: E402
 from benchmark.harness import peaks  # noqa: E402
 from benchmark.harness import trace as T  # noqa: E402
 
 REHEARSE = {"tpch": "scale_factor=0.01",
-            "sysbench": "tables=2,table_size=2000,warm_window_s=1"}
+            "tpch_regroup": "scale_factor=0.01",
+            "sysbench": "tables=2,table_size=2000,warm_window_s=1",
+            "sysbench_oltp": "tables=2,table_size=2000,warm_window_s=1"}
 
 
 def check_trace() -> None:
@@ -100,6 +102,16 @@ def check_pools() -> None:
     d1, d2 = sysbench.generate(cfg, big), sysbench.generate(cfg, big)
     assert (d1["sbtest2"]["c"] == d2["sbtest2"]["c"]).all()
     assert len(d1["sbtest1"]["c"][0]) == 119 and len(d1["sbtest1"]["pad"][0]) == 59
+    ro = json.load(open(os.path.join(ROOT, "benchmark", "traffic",
+                                     "read_only.json")))
+    t1 = sysbench_oltp.Stream(ro, cfg, big, 5, {})
+    t2 = sysbench_oltp.Stream(ro, cfg, big, 5, {})
+    sent = [t1.next() for _ in range(64)]
+    assert sent == [t2.next() for _ in range(64)]
+    assert [s[0] for s in sent[:16]] == (
+        ["begin"] + ["point_select"] * 10 + list(sysbench_oltp.RANGES)
+        + ["commit"]) and sent[16][0] == "begin"
+    assert sysbench_oltp.generate is sysbench.generate
     print("pools, streams and data reproducible from the seed ok")
 
 
@@ -141,8 +153,19 @@ def check_references() -> None:
         assert int(q1["count"][key]) == r[9]
         assert abs(float(r[2]) - q1["sum_qty"][key] / 100) < 1e-6
         assert abs(float(r[5]) - q1["sum_ch"][key] / 1e6) < 1e-3
+    cfg = {"tables": 1, "table_size": 300}
+    rows = sysbench_oltp.generate(cfg, 77)
+    c = [v.decode() for v in rows["sbtest1"]["c"]]
+    lit = {"table": "sbtest1", "id": 250, "id_end": 349}  # past table_size
+    ref = lambda k: sysbench_oltp.reference(k, lit, rows)  # noqa: E731
+    assert ref("begin") == 0 and ref("commit") == 0
+    assert ref("point_select") == [(c[249],)]
+    assert ref("simple_range") == [(v,) for v in c[249:]]
+    assert ref("sum_range") == [(sum(map(int, rows["sbtest1"]["k"][249:])),)]
+    assert ref("order_range") == [(v,) for v in sorted(c[249:])]
+    assert ref("distinct_range") == [(v,) for v in sorted(set(c[249:]))]
     print("parameterised references equal the program's at the validation "
-          "literals ok")
+          "literals, and sysbench_oltp's the rows by hand ok")
 
 
 def rehearse_cells() -> None:
