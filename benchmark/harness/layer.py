@@ -5,7 +5,8 @@ A metric is `<name>.json` (declarative) or `<name>.py` with
 
   counters        sum of the deltas of `num` (less `minus`) over the counted
                   window, divided by the deltas of `den` (a list of counters,
-                  or "statements": those the clients completed), times `scale`
+                  "statements": those the clients completed, or "window_s":
+                  the window's seconds), times `scale`
   trace           `num` / `den` over the traced sub-windows, of: window_s,
                   busy_s, idle_s, launches, statements, necessary_s (the
                   statements' necessary bytes over the chip's peak bytes/s);
@@ -56,7 +57,8 @@ def evaluate(spec: dict, ctx: dict):
         den = spec.get("den")
         if den is None:
             return num * scale
-        d = ctx["statements"] if den == "statements" else _delta(ctx, den)
+        d = (ctx.get(den) if den in ("statements", "window_s")
+             else _delta(ctx, den))
         return num / d * scale if d else None
     if src == "trace":
         kinds = spec.get("kinds")

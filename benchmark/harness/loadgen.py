@@ -12,7 +12,9 @@ length before it on stdout:
 A reply is a list of records `(kind, literals, t_send, t_done, rows, client)`
 with times on this process's `time.time()`; `rows` is the exception text
 where the statement failed. Statements in flight when a window closes are
-waited for and recorded: their latency counts the wait.
+waited for and recorded: their latency counts the wait. A stream that has a
+method `done(kind, literals, rows)` is told how each of its statements ended
+before it is asked for the next.
 """
 
 from __future__ import annotations
@@ -78,9 +80,13 @@ def main() -> int:
         per = [[] for _ in range(n)]
 
         def lane(i: int) -> None:
+            done = getattr(streams[i], "done", None)
             while time.time() < t_end:
                 kind, lit, text = streams[i].next(only)
-                per[i].append(_timed(clients[i], kind, lit, text, i))
+                rec = _timed(clients[i], kind, lit, text, i)
+                per[i].append(rec)
+                if done is not None:
+                    done(kind, lit, rec[4])
 
         threads = [threading.Thread(target=lane, args=(i,)) for i in range(n)]
         for t in threads:

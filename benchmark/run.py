@@ -14,8 +14,14 @@ the phases together:
             drives closed-loop clients for --seconds
   traced    with --trace 1, a few more seconds under the profiler, one
             statement kind at a time
+  read-back where the generator has `readback(records)`: the admin connection
+            sends the statements it returns (every row a transaction wrote),
+            outside every timed window
   check     the program is stopped and freed, then every answer of the window
-            is compared with the plain reference (harness/check.py)
+            is compared with the plain reference (harness/check.py); where
+            the generator has `judge(records, data, config, traffic)` it
+            judges the log of the whole run instead (warm-up, windows,
+            read-back), and gives the same dictionary back
 
 The last line of stdout is the result. Off the chip the run fails, unless
 `--rehearse key=value,...` (a tiny scale on the CPU) is given: that prints
@@ -119,7 +125,7 @@ def main() -> int:
         return 2
     used = devs[:int(cell["chips"])]
 
-    from benchmark.harness import check, end_to_end, layer, peaks
+    from benchmark.harness import check, end_to_end, layer, loadgen, peaks
     from benchmark.harness import trace as T
     from benchmark.harness.server import Served
     from benchmark.harness.wire import WireClient
@@ -149,8 +155,12 @@ def main() -> int:
         bad = [r for r in warm if isinstance(r[4], str)]
         if bad:
             raise RuntimeError(f"warm-up statement failed: {bad[0][4]}")
+        whole_run = hasattr(gen, "judge")  # the judge replays every write
+        history = list(warm) if whole_run else []
         if traffic.get("warm_window_s"):
-            lg.call(cmd="run", seconds=float(traffic["warm_window_s"]))
+            ww = lg.call(cmd="run", seconds=float(traffic["warm_window_s"]))
+            if whole_run:
+                history += ww["records"]
         n1, s1 = served.compiles.read()
         setup["warm_s"] = time.perf_counter() - t0
         setup["warm_compiles"], setup["warm_compile_s"] = n1 - n0, s1 - s0
@@ -193,6 +203,11 @@ def main() -> int:
                 if widths and ref_cols:
                     traced_bytes[kind] = n * peaks.necessary_bytes(
                         ref_cols, rows, widths) / peak["hbm_bytes_per_s"]
+        # ---- the read-back: acknowledged writes, through the leader
+        back = []
+        if hasattr(gen, "readback"):
+            back = [loadgen._timed(admin, *a, -1)
+                    for a in gen.readback(history + records)]
         lg.stop()
         lg = None
         admin.close()
@@ -214,8 +229,13 @@ def main() -> int:
             refs[key] = gen.reference(kind, lit, data)
         return refs[key]
 
-    verdict = check.judge(records, reference_of,
-                          float(config["correct"]["rel_err_max"]))
+    if whole_run:
+        judged = history + records + back
+        verdict = gen.judge(judged, data, config, traffic)
+    else:
+        judged = records
+        verdict = check.judge(records, reference_of,
+                              float(config["correct"]["rel_err_max"]))
     check_s = time.perf_counter() - t0
 
     # ---- metrics
@@ -235,7 +255,7 @@ def main() -> int:
 
     dev_out = dict(device, memory_peak_bytes=int(peak_bytes))
     result = {"correct": bool(verdict["correct"]),
-              "attempted": len(records),
+              "attempted": len(judged),
               "failed": verdict["compared"]["missing_answers"]["value"]
               + verdict["compared"]["wrong_answers"]["value"],
               "metrics": metrics, "device": dev_out}
@@ -250,9 +270,15 @@ def main() -> int:
                         "compiles": counters1["xla.compiles"]
                         - counters0["xla.compiles"],
                         "by_kind": end_to_end.by_kind(all_window)}
+    if back:
+        result["readback"] = {"statements": len(back),
+                              **verdict.get("transactions", {})}
+    if verdict.get("errors"):
+        result["errors"] = verdict["errors"]
     result["compared"] = verdict["compared"]
     if verdict["first_bad"]:
-        log({"first_bad": verdict["first_bad"]})
+        log({"first_bad": verdict["first_bad"],
+             "errors": verdict.get("errors")})
     for name, c in verdict["compared"].items():
         print(f"compared {name} = {c['value']!r} (limit {c['limit']!r})",
               file=sys.stderr, flush=True)
