@@ -46,6 +46,7 @@ from ..expr.compile import (
     evaluate,
     infer_type,
 )
+from ..ops.compact import live_positions
 from ..ops.hashagg import assign_group_slots, sort_groupby
 from ..ops.hashing import next_pow2, pack_keys
 from ..ops.join import (
@@ -3821,17 +3822,18 @@ class PreparedPlan(Dispatchable):
     def _build_narrow(self, ncap: int):
         """One jitted program = the plan program (inlined: calling the
         live jit inside jit fuses the traces, same mechanism as the
-        batched buckets' vmap) + the final result-frame gather. The
-        stable-ascending nonzero keeps live rows in their original
-        relative order, so the frame is bit-identical to the plain
-        path's host-side sel masking."""
+        batched buckets' vmap) + the final result-frame gather.
+        `live_positions` keeps live rows in their original relative
+        order, so the frame is bit-identical to the plain path's
+        host-side sel masking."""
         inner = self.jitted
 
         def run_narrow(inputs, qparams):
             out, ovf_vec = inner(inputs, qparams)
             with jax.named_scope("frame"):
                 nlive = jnp.sum(out.sel, dtype=jnp.int64)
-                idx = jnp.nonzero(out.sel, size=ncap, fill_value=0)[0]
+                count_lowering("result frame positions searched")
+                idx = live_positions(out.sel, ncap)
                 cols = {n: jnp.take(c, idx, axis=0)
                         for n, c in out.cols.items()}
                 valid = {n: jnp.take(v, idx, axis=0)
@@ -3989,7 +3991,7 @@ _head_gather_traces = [0]
 
 def _head_gather_impl(cols, valid, sel, k):
     _head_gather_traces[0] += 1
-    idx = jnp.nonzero(sel, size=k, fill_value=0)[0]
+    idx = live_positions(sel, k)
     return (
         {n: jnp.take(c, idx) for n, c in cols.items()},
         {n: jnp.take(v, idx) for n, v in valid.items()},
@@ -4038,6 +4040,12 @@ class DeviceResult:
     # no bound, and the width past which a result is not worth fusing
     NARROW_SEED_ROWS = 256
     NARROW_MAX_ROWS = 4096
+    # fetch_head gathers on the device a head of at most one in this
+    # many rows of the capacity; a larger one brings the whole columns,
+    # which is where finding and gathering the rows on a v5e comes to
+    # cost more than the bytes it spares the link (PERF.md section 6,
+    # PR 36)
+    HEAD_GATHER_SHARE = 4
 
     def __init__(self, prepared, qparams, out, ovf_vec, novf=None,
                  ncap: int = 0, max_retries: int = 3):
@@ -4238,21 +4246,21 @@ class DeviceResult:
         capacity. The gather width buckets to a power of two so a client
         sweeping LIMIT values (pagination) reuses log2(cap) executables
         instead of compiling one per distinct k. Serves from the host
-        cache when a full fetch already happened."""
+        cache when a full fetch already happened, and makes that full
+        fetch where the head is a large share of the capacity."""
         import time as _time
 
         from ..core.column import host_rows
 
         self._sync()
         k = min(int(limit), self._nrows)
-        if self._hsel is not None and not (
-            set(f.name for f in self._out.schema.fields) - set(self._hcols)
-        ):
-            host = host_rows(self._out.schema, self._out.dicts, self._hcols,
-                             self._hvalid, self._hsel)
-            return {n: v[:k] for n, v in host.items()}
         cap = int(self._out.sel.shape[-1])
         kb = min(next_pow2(max(k, 1)), cap)
+        fetched = self._hsel is not None and not (
+            set(f.name for f in self._out.schema.fields) - set(self._hcols))
+        if fetched or kb * self.HEAD_GATHER_SHARE > cap:
+            host = self.fetch_columns()
+            return {n: v[:k] for n, v in host.items()}
         arrs, vals = _head_gather(self._out.cols, self._out.valid,
                                   self._out.sel, kb)
         tl = _gap.tracing()

@@ -161,12 +161,18 @@ def test_batched_bucket_compiles_for_v5e(chip, point_read):
 
 def test_narrow_frame_compiles_for_v5e(chip, point_read):
     """Whole-statement fusion: plan program + result-frame gather in one
-    executable (PreparedPlan._build_narrow), the warm served dispatch."""
+    executable (PreparedPlan._build_narrow), the warm served dispatch.
+    The frame finds its rows by `ops/compact.py` `live_positions`: no
+    scatter is left under the `frame` scope of the v5e text (PR 36)."""
     shapes, _ = chip
     prepared, qp = point_read
     assert prepared._narrow, "the CPU run did not take the fused frame"
     for fn in prepared._narrow.values():
-        _compile(fn, shapes(prepared._inputs()), shapes(qp))
+        text = _compile(fn, shapes(prepared._inputs()), shapes(qp)).as_text()
+        frame = [ln for ln in text.splitlines()
+                 if re.search(r'op_name="[^"]*/frame/', ln)]
+        assert frame, "no op of the v5e text names the frame scope"
+        assert not [ln for ln in frame if re.search(r"\bscatter\(", ln)]
 
 
 def test_q14_scopes_survive_the_v5e_compiler(chip, tpch):

@@ -355,7 +355,9 @@ def main(argv) -> int:
                 "group_keys_dependent":
                     db.metrics.counter("group keys dependent"),
                 "merge_join_scan_carried":
-                    db.metrics.counter("merge join scan-carried")}})
+                    db.metrics.counter("merge join scan-carried"),
+                "result_frame_positions_searched":
+                    db.metrics.counter("result frame positions searched")}})
             log({"slow_statements": slow_statements(db, a["at"], b["at"])})
             if b["px"] is not None:
                 before = (a["px"] or {}).get("counters", {})
