@@ -56,6 +56,12 @@ class ColumnBatch:
     def capacity(self) -> int:
         return int(self.sel.shape[0])
 
+    @property
+    def nbytes(self) -> int:
+        """Device bytes: the columns, their validity and the mask."""
+        return sum(int(a.nbytes) for d in (self.cols, self.valid)
+                   for a in d.values()) + int(self.sel.nbytes)
+
     def col(self, name: str) -> jnp.ndarray:
         return self.cols[name]
 

@@ -15,6 +15,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..share import gap_ledger as _gap
 from .column import ColumnBatch, make_batch
 from .dictionary import Dictionary
 from .dtypes import DataType, Field, Schema, TypeKind
@@ -63,9 +64,11 @@ class Table:
         return Table(name, schema, data, dicts)
 
     def to_batch(self, capacity: int | None = None) -> ColumnBatch:
-        return make_batch(
-            self.data, self.schema, self.dicts, capacity=capacity, valid=self.valid
-        )
+        with _gap.span("h2d") as sp:
+            b = make_batch(self.data, self.schema, self.dicts,
+                           capacity=capacity, valid=self.valid)
+            sp.moved(b.nbytes)
+        return b
 
     def column_as_python(self, name: str):
         """Decode a column to python values (strings/decimals) for display."""

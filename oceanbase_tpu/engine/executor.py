@@ -401,14 +401,8 @@ def _device_nbytes(obj) -> int:
     """Sum nbytes over the device arrays inside an executor input — a
     ColumnBatch, the PX raw cols/valid/sel dict, or derived-structure
     tuples (fk_ranges, ivf arrays)."""
-    if hasattr(obj, "nbytes"):
+    if hasattr(obj, "nbytes"):  # an array, or a ColumnBatch's planes
         return int(obj.nbytes)
-    if isinstance(obj, ColumnBatch):
-        return (
-            _device_nbytes(obj.cols)
-            + _device_nbytes(obj.valid)
-            + _device_nbytes(obj.sel)
-        )
     if isinstance(obj, dict):
         return sum(_device_nbytes(v) for v in obj.values())
     if isinstance(obj, (tuple, list)):
@@ -454,6 +448,10 @@ class Executor:
         # around one execution: cache hits upload nothing, which is the
         # point of the per-column device cache)
         self.h2d_bytes = 0
+        # the part of it uploaded into the device cache: what a plan's
+        # footprint memo keys on (an upload past the cache, of a private
+        # or statement-scoped view, changes no input the cache holds)
+        self.cache_h2d_bytes = 0
         # hook: share/timeline.ServingTimeline — cold uploads land as
         # transfer-interference events (the server wires it)
         self.timeline = None
@@ -888,52 +886,21 @@ class Executor:
         )
         n = t.nrows
         cap = max(1024, -(-max(n, 1) // 1024) * 1024)
+        cache = self._batch_cache
+        skey = (name, "#sel")
+        cold = [f for f in sub_schema.fields if (name, f.name) not in cache]
+        if cold or skey not in cache:
+            # one span per table batch that moves columns to the device
+            with _gap.span("h2d") as sp:
+                sp.moved(self._upload_cold(name, t, cold, cap))
         dcols: dict[str, jnp.ndarray] = {}
         dvalid: dict[str, jnp.ndarray] = {}
         for f in sub_schema.fields:
-            key = (name, f.name)
-            hit = self._batch_cache.get(key)
-            if hit is None:
-                from ..core.column import narrowed_upload
-
-                a = np.asarray(t.data[f.name], dtype=f.dtype.storage_np)
-                dev = narrowed_upload(a, cap)
-                vdev = None
-                if f.dtype.nullable:
-                    v = (
-                        np.asarray(t.valid[f.name], dtype=np.bool_)
-                        if f.name in t.valid
-                        else np.ones(n, dtype=np.bool_)
-                    )
-                    if cap > n:
-                        v = np.concatenate(
-                            [v, np.zeros(cap - n, dtype=np.bool_)])
-                    vdev = jnp.asarray(v)
-                hit = (dev, vdev)
-                self._batch_cache[key] = hit
-                nb = int(dev.nbytes) + (
-                    int(vdev.nbytes) if vdev is not None else 0
-                )
-                self.h2d_bytes += nb
-                tl = self.timeline
-                if tl is not None and tl.enabled:
-                    # a cold-column upload steals device time from the
-                    # serving stream: transfer interference
-                    tl.record_transfer(nb)
-            dcols[f.name] = hit[0]
-            if hit[1] is not None:
-                dvalid[f.name] = hit[1]
-        skey = (name, "#sel")
-        sel = self._batch_cache.get(skey)
-        if sel is None:
-            s = np.zeros(cap, dtype=np.bool_)
-            s[:n] = True
-            sel = jnp.asarray(s)
-            self._batch_cache[skey] = sel
-            self.h2d_bytes += int(sel.nbytes)
-            tl = self.timeline
-            if tl is not None and tl.enabled:
-                tl.record_transfer(int(sel.nbytes))
+            dev, vdev = cache[(name, f.name)]
+            dcols[f.name] = dev
+            if vdev is not None:
+                dvalid[f.name] = vdev
+        sel = cache[skey]
         batch = ColumnBatch(
             cols=dcols,
             valid=dvalid,
@@ -945,6 +912,46 @@ class Executor:
         self._assembled[(name, cols)] = (ver, batch)
         return batch
 
+    def _upload_cold(self, name: str, t, fields, cap: int) -> int:
+        """Upload the `fields` of `t` the device cache lacks (and its
+        `#sel` plane if missing) into the cache; returns the bytes."""
+        from ..core.column import narrowed_upload
+
+        n = t.nrows
+        skey = (name, "#sel")
+        nb = 0
+        for f in fields:
+            a = np.asarray(t.data[f.name], dtype=f.dtype.storage_np)
+            dev = narrowed_upload(a, cap)
+            vdev = None
+            if f.dtype.nullable:
+                v = (
+                    np.asarray(t.valid[f.name], dtype=np.bool_)
+                    if f.name in t.valid
+                    else np.ones(n, dtype=np.bool_)
+                )
+                if cap > n:
+                    v = np.concatenate(
+                        [v, np.zeros(cap - n, dtype=np.bool_)])
+                vdev = jnp.asarray(v)
+            self._batch_cache[(name, f.name)] = (dev, vdev)
+            nb += int(dev.nbytes) + (
+                int(vdev.nbytes) if vdev is not None else 0)
+        if skey not in self._batch_cache:
+            s = np.zeros(cap, dtype=np.bool_)
+            s[:n] = True
+            sel = jnp.asarray(s)
+            self._batch_cache[skey] = sel
+            nb += int(sel.nbytes)
+        self.h2d_bytes += nb
+        self.cache_h2d_bytes += nb
+        tl = self.timeline
+        if tl is not None and tl.enabled:
+            # a cold-column upload steals device time from the serving
+            # stream: transfer interference
+            tl.record_transfer(nb)
+        return nb
+
     def _build_batch(self, name: str, cols: tuple[str, ...]) -> ColumnBatch:
         t = self.catalog[name]
         sub_schema = Schema(
@@ -952,12 +959,19 @@ class Executor:
         )
         from ..core.column import make_batch
 
-        return make_batch(
-            {c: t.data[c] for c in sub_schema.names()},
-            sub_schema,
-            {c: d for c, d in t.dicts.items() if c in cols},
-            valid={c: v for c, v in t.valid.items() if c in cols},
-        )
+        with _gap.span("h2d") as sp:
+            b = make_batch(
+                {c: t.data[c] for c in sub_schema.names()},
+                sub_schema,
+                {c: d for c, d in t.dicts.items() if c in cols},
+                valid={c: v for c, v in t.valid.items() if c in cols},
+            )
+            nb = b.nbytes
+            sp.moved(nb)
+        # counted like a cache upload: the plan monitor's and the workload
+        # repository's transfer bytes read the delta
+        self.h2d_bytes += nb
+        return b
 
     # ---- physical parameter seeding ----------------------------------
     def _est_rows(self, op) -> float:

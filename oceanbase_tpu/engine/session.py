@@ -721,6 +721,9 @@ class Session:
         bind_s = time.perf_counter() - t0
         if tl is not None:
             tl.leaf("device dispatch")
+        # table uploads (span "h2d") inside the dispatch and the sync: the
+        # record carries them so the ledger carves them out of those two
+        up0 = _gap.h2d_seconds()
         exec_t0 = time.perf_counter()
         op_samples = prof_digest = prof_reason = None
         pp = self.plan_profiler
@@ -757,6 +760,7 @@ class Session:
             # below overlaps the program AND the transfers
             cursor = prepared.dispatch(qparams)
         dispatch_s = time.perf_counter() - exec_t0
+        up1 = _gap.h2d_seconds()
         if tl is not None:
             tl.leaf_end()
         self._emit_px_spans(prepared, exec_t0, time.perf_counter())
@@ -768,16 +772,16 @@ class Session:
             input_spec = prepared.input_spec
             if input_spec is not None:
                 # warm statements reuse the footprint walk: device inputs
-                # only change via an upload, and every upload moves the
-                # executor's lifetime h2d counter (serving-path diet)
+                # only change via an upload, and every upload into the
+                # device cache moves this counter (serving-path diet)
                 memo = prepared._dev_bytes_memo
-                if (memo is not None and memo[0] == ex.h2d_bytes
+                if (memo is not None and memo[0] == ex.cache_h2d_bytes
                         and memo[1] is ex):
                     device_bytes = memo[2]
                 else:
                     device_bytes = ex.input_device_bytes(input_spec)
                     prepared._dev_bytes_memo = (
-                        ex.h2d_bytes, ex, device_bytes)
+                        ex.cache_h2d_bytes, ex, device_bytes)
             # peak working set: device-resident inputs + the result's
             # footprint (the frame's static shapes, no transfer: the
             # cursor adds actual d2h bytes as fetches happen) + PX
@@ -805,6 +809,8 @@ class Session:
             "dispatch_s": dispatch_s, "fetch_s": 0.0,
             "cache_hit": was_hit, "fast_hit": fast,
         }
+        if up1 > up0:
+            phases["h2d_s"] = up1 - up0
         # the record rides the cursor, THEN the sync point is forced: the
         # overflow check + what the dispatch put in flight. All the host
         # work above overlapped device compute. The sync wall IS the
@@ -815,12 +821,16 @@ class Session:
         cursor.phases = phases
         if tl is not None:
             tl.leaf("device wait")
+        up0 = _gap.h2d_seconds()
         tf = time.perf_counter()
         nrows = cursor.nrows
         fetch_s = time.perf_counter() - tf
         if tl is not None:
             tl.leaf_end()
         phases["fetch_s"] = fetch_s
+        up1 = _gap.h2d_seconds()
+        if up1 > up0:  # an overflow's redrive uploaded again
+            phases["h2d_fetch_s"] = up1 - up0
         if profile is not None:
             profile.fetch_s = fetch_s
         json_cols = None

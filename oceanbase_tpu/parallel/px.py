@@ -71,6 +71,7 @@ from ..engine.executor import (
 from ..expr import ir as E
 from ..expr.compile import evaluate
 from ..ops.hashing import hash32_combine, next_pow2
+from ..share import gap_ledger as _gap
 from ..sql.logical import (
     Aggregate,
     Distinct,
@@ -462,16 +463,19 @@ class PxExecutor(Executor):
         )
         unit = 1024 * self.nsh
         cap = max(unit, -(-(t.nrows or 1) // unit) * unit)
-        b = make_batch(
-            {c: t.data[c] for c in sub_schema.names()},
-            sub_schema,
-            {c: d for c, d in t.dicts.items() if c in cols},
-            capacity=cap,
-            valid={c: v for c, v in t.valid.items() if c in cols},
-        )
-        raw, nbytes = shard_put(self.mesh, b)
+        with _gap.span("h2d") as sp:
+            b = make_batch(
+                {c: t.data[c] for c in sub_schema.names()},
+                sub_schema,
+                {c: d for c, d in t.dicts.items() if c in cols},
+                capacity=cap,
+                valid={c: v for c, v in t.valid.items() if c in cols},
+            )
+            raw, nbytes = shard_put(self.mesh, b)
+            sp.moved(nbytes)
         self.h2d_bytes += nbytes
         if resident:
+            self.cache_h2d_bytes += nbytes
             # partitioned residency: each device of the mesh now holds
             # nbytes/nsh of this table; the governor charges per device
             self.residency.charge(name, nbytes)

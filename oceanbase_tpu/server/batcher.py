@@ -767,6 +767,7 @@ class StatementBatcher:
         tl = _gl.tracing()
         if tl is not None:
             tl.leaf("device dispatch")
+        up0 = _gl.h2d_seconds()
         t0 = time.perf_counter()
         try:
             qa = np.stack([b.rows[i] for i in alive])
@@ -780,8 +781,10 @@ class StatementBatcher:
                 led.leaf_end()
                 # ONE device execution on the ADOPTING leader's ledger;
                 # the rider's lanes hint only their window wait — same
-                # exactly-once discipline as the single-plan dispatch
-                led.add("device dispatch", dispatch_s)
+                # exactly-once discipline as the single-plan dispatch;
+                # a cold upload inside is its span's "h2d" hint
+                led.add("device dispatch",
+                        dispatch_s - (_gl.h2d_seconds() - up0))
                 led.device(dispatch_s)
             b.dispatch_s = rb.dispatch_s = dispatch_s
             (ha, hva, hsa, sca, dca), (hb, hvb, hsb, scb, dcb) = res
@@ -816,6 +819,7 @@ class StatementBatcher:
         tl = _gl.tracing()
         if tl is not None:
             tl.leaf("device dispatch")
+        up0 = _gl.h2d_seconds()
         t0 = time.perf_counter()
         try:
             qblock = np.stack([b.rows[i] for i in alive])
@@ -829,8 +833,10 @@ class StatementBatcher:
                 # _dispatch runs on the leader's thread: the cohort's ONE
                 # batched device execution lands on the LEADER's ledger
                 # (followers hint only their window wait) — the double-
-                # count regression test anchors here
-                led.add("device dispatch", b.dispatch_s)
+                # count regression test anchors here; a cold upload
+                # inside is its span's "h2d" hint
+                led.add("device dispatch",
+                        b.dispatch_s - (_gl.h2d_seconds() - up0))
                 led.device(b.dispatch_s)
             self._scatter(b, alive, hcols, hvalid, hsel, schema, dicts)
             nb = b.nlanes

@@ -2055,45 +2055,51 @@ class Database:
             in_tx = tx is not None and tx.ctx is not None
             if not in_tx and ti.cached_data_version == ti.data_version:
                 continue
-            touched = in_tx and name in tx.touched_tables
-            snap = (
-                tx.ctx.read_snapshot if in_tx else self.cluster.gts.current()
-            )
-            parts = []
-            for ls_id, tablet_id in ti.all_partitions():
-                if touched:
-                    rep = tx.svc.replicas[ls_id]
+            # the snapshot rescan: every statement of a transaction (the
+            # early continue never fires in one), an autocommit one only
+            # after a commit moved the table's data version
+            with _GL.span("catalog refresh") as sp:
+                touched = in_tx and name in tx.touched_tables
+                snap = (tx.ctx.read_snapshot if in_tx
+                        else self.cluster.gts.current())
+                parts = []
+                for ls_id, tablet_id in ti.all_partitions():
+                    if touched:
+                        rep = tx.svc.replicas[ls_id]
+                    else:
+                        rep = self._leader_replica_ls(ls_id)
+                    parts.append(rep.tablets[tablet_id].scan(
+                        snap, tx_id=tx.ctx.tx_id if touched else 0,
+                    ))
+                if len(parts) == 1:
+                    data = parts[0]
                 else:
-                    rep = self._leader_replica_ls(ls_id)
-                parts.append(rep.tablets[tablet_id].scan(
-                    snap, tx_id=tx.ctx.tx_id if touched else 0,
-                ))
-            if len(parts) == 1:
-                data = parts[0]
-            else:
-                data = {
-                    c: np.concatenate([p[c] for p in parts])
-                    for c in parts[0]
-                }
-            dicts = {}
-            for col in ti.dicts:
-                sd, remap = ti.sorted_dict(col)
-                if len(data[col]):
-                    data[col] = remap[data[col]]
-                dicts[col] = sd
-            for f in ti.schema.fields:
-                # tablet cells store vectors as tuples, so the scan
-                # yields a 1-D object column; every downstream consumer
-                # (IVF build, route costing, H2D upload, mesh sharding)
-                # wants the dense (n, d) float32 form — normalize once
-                if f.dtype.kind is TypeKind.VECTOR:
-                    a = data[f.name]
-                    dim = int(f.dtype.precision)
-                    data[f.name] = (
-                        np.asarray(a.tolist(), dtype=np.float32)
-                        .reshape(len(a), dim)
-                        if len(a) else np.zeros((0, dim), np.float32))
-            t = Table(name, ti.schema, data, dicts)
+                    data = {
+                        c: np.concatenate([p[c] for p in parts])
+                        for c in parts[0]
+                    }
+                dicts = {}
+                for col in ti.dicts:
+                    sd, remap = ti.sorted_dict(col)
+                    if len(data[col]):
+                        data[col] = remap[data[col]]
+                    dicts[col] = sd
+                for f in ti.schema.fields:
+                    # tablet cells store vectors as tuples, so the scan
+                    # yields a 1-D object column; every downstream
+                    # consumer (IVF build, route costing, H2D upload, mesh
+                    # sharding) wants the dense (n, d) float32 form —
+                    # normalize once
+                    if f.dtype.kind is TypeKind.VECTOR:
+                        a = data[f.name]
+                        dim = int(f.dtype.precision)
+                        data[f.name] = (
+                            np.asarray(a.tolist(), dtype=np.float32)
+                            .reshape(len(a), dim)
+                            if len(a) else np.zeros((0, dim), np.float32))
+                t = Table(name, ti.schema, data, dicts)
+                sp.count("catalog refreshes")
+                sp.count("catalog refresh rows", t.nrows)
             if in_tx:
                 # tx-private view (BEGIN snapshot + own staged rows): lives
                 # on the tx, activated per-statement via catalog.tx_scope —
@@ -2824,6 +2830,9 @@ class DbSession:
                                 # system_event rows AND prometheus
                                 # summaries for free
                                 adds.append(("host tax statements", 1))
+                                # the spans' counters (catalog refresh,
+                                # h2d), folded here and nowhere else
+                                adds.extend(led.counts.items())
                                 observes.append(("host chip idle pct",
                                                  led.chip_idle_pct))
                                 waits = [("host tax: " + k, v)

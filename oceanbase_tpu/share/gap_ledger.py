@@ -48,6 +48,11 @@ host was in.  The profiler's state is read once per statement
 one attribute test.  One annotation is open at a time (``_mark``), so
 leaves never nest and never overlap on a thread; phases in which the
 thread is parked by design (``BLOCKED_PHASES``) are never written.
+
+Where the work happens deep below the statement path, a site names it
+with :class:`span` (PR 37: ``catalog refresh``, ``h2d``): a hint on the
+thread's ledger, a leaf that hands back to the one it interrupted, and
+counters that ride the statement's one ``metrics.bulk``.
 """
 from __future__ import annotations
 
@@ -69,6 +74,7 @@ PHASE_ORDER = (
     "batch window",
     "governor reserve",
     "plan compile",
+    "catalog refresh",
     "param pack",
     "h2d",
     "device dispatch",
@@ -119,7 +125,8 @@ class GapLedger:
 
     __slots__ = ("clock", "t0", "phases", "device_s", "_pending",
                  "_win_t0", "_cursor", "e2e_s", "unattributed_s", "closed",
-                 "cpu_s", "stmt", "_ann", "_ann_phase", "_win_phase")
+                 "cpu_s", "stmt", "_ann", "_ann_phase", "_win_phase",
+                 "counts")
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self.clock = clock
@@ -144,6 +151,9 @@ class GapLedger:
         self._ann = None
         self._ann_phase: Optional[str] = None
         self._win_phase: Optional[str] = None
+        # the statement's counters from the spans (span.moved / count),
+        # folded once with its completion metrics
+        self.counts: Dict[str, float] = {}
 
     # -- lifecycle ----------------------------------------------------
     def begin(self, t0: Optional[float] = None,
@@ -166,6 +176,8 @@ class GapLedger:
         self.unattributed_s = 0.0
         self.cpu_s = 0.0
         self.closed = False
+        if self.counts:
+            self.counts.clear()
         if self._ann is not None:  # a statement that never closed
             self._mark(None)
         self._win_phase = None
@@ -207,6 +219,19 @@ class GapLedger:
             # the caller measured a span that just ended: advance the
             # serial cursor so a following cut() doesn't re-cover it
             self._cursor = self.clock()
+
+    def inner(self, phase: str, seconds: float) -> None:
+        """Attribute ``seconds`` that a :class:`span` measured inside
+        whatever the thread was doing: in a window a hint, like ``add``;
+        on the serial path taken out of the next ``cut``, whose interval
+        holds the span (the cursor moves by the span, not to now)."""
+        if seconds <= 0.0 or self.closed:
+            return
+        if self._pending is not None:
+            self._pending.append((phase, seconds))
+        else:
+            self.phases[phase] = self.phases.get(phase, 0.0) + seconds
+            self._cursor += seconds
 
     def cut(self, phase: str, then: Optional[str] = None) -> None:
         """Attribute ALL wall since the last cut/add/window (or begin)
@@ -427,6 +452,16 @@ def carve_engine_phases(phases: dict,
         hints["param pack"] = v
     dispatch = g("dispatch_s", 0.0) or 0.0
     fetch = g("fetch_s", 0.0) or 0.0
+    # table uploads (``span("h2d")``) inside the dispatch or, on an
+    # overflow's redrive, inside the sync: carved out of the phase that
+    # held them. Served, the span already hinted them on the ledger.
+    up = min(g("h2d_s", 0.0) or 0.0, dispatch)
+    upf = min(g("h2d_fetch_s", 0.0) or 0.0, fetch)
+    if up > 0.0 or upf > 0.0:
+        dispatch -= up
+        fetch -= upf
+        if not served_stream_hints:
+            hints["h2d"] = up + upf
     # column-data transfers accumulate into BOTH fetch_s and d2h_s
     # (executor.DeviceResult._observe): carve the transfer wall out of
     # the sync wall so "d2h" and "device wait" never overlap
@@ -440,7 +475,7 @@ def carve_engine_phases(phases: dict,
         soverlap = g("stream_overlap_s", 0.0) or 0.0
         h2d_wall = min(max(0.0, sh2d - soverlap), dispatch)
         if not served_stream_hints and h2d_wall > 0.0:
-            hints["h2d"] = h2d_wall
+            hints["h2d"] = hints.get("h2d", 0.0) + h2d_wall
         if dispatch > h2d_wall:
             hints["device dispatch"] = dispatch - h2d_wall
         device_s = wait if served_stream_hints else scompute + wait
@@ -467,6 +502,66 @@ def set_current(led: Optional[GapLedger]) -> None:
 
 def current() -> Optional[GapLedger]:
     return getattr(_tls, "led", None)
+
+
+class span:
+    """``with span(phase) as sp:`` names host work at the site where it
+    happens (PR 37: ``catalog refresh`` in ``Database.refresh_catalog``,
+    ``h2d`` where a table batch is uploaded). On the thread's statement
+    ledger it is a hint (``GapLedger.inner``: carved, never counted
+    twice) and, under a profiler, a leaf ``ob:<phase>`` that hands back
+    to the leaf it interrupted. ``sp.count`` / ``sp.moved`` add to the
+    statement's counters. ``h2d`` seconds also run on per thread
+    (``h2d_seconds``) whether or not a statement is served, so the
+    engine can carve an upload out of the phase it times around it.
+    Outside a statement two clock reads, nothing else."""
+
+    __slots__ = ("phase", "led", "back", "clock", "t0")
+
+    def __init__(self, phase: str):
+        self.phase = phase
+
+    def __enter__(self) -> "span":
+        led = getattr(_tls, "led", None)
+        if led is not None and led.closed:
+            led = None
+        self.led = led
+        if led is not None and led.stmt:
+            # the leaf this one interrupts (an upload inside `device
+            # dispatch`) opens again when it ends, not the window's rest
+            self.back = led._ann_phase
+            led._mark(self.phase)
+        self.clock = led.clock if led is not None else time.perf_counter
+        self.t0 = self.clock()
+        return self
+
+    def __exit__(self, *_exc) -> bool:
+        dt = self.clock() - self.t0
+        if self.phase == "h2d":
+            _tls.h2d_s = getattr(_tls, "h2d_s", 0.0) + dt
+        led = self.led
+        if led is not None:
+            led.inner(self.phase, dt)
+            if led.stmt:
+                led._mark(self.back)
+        return False
+
+    def count(self, name: str, n: float = 1) -> None:
+        led = self.led
+        if led is not None:
+            c = led.counts
+            c[name] = c.get(name, 0) + n
+
+    def moved(self, nbytes: int) -> None:
+        """One table batch uploaded: ``h2d uploads`` and ``h2d bytes``
+        (device bytes, validity and ``#sel`` planes included)."""
+        self.count("h2d uploads")
+        self.count("h2d bytes", nbytes)
+
+
+def h2d_seconds() -> float:
+    """This thread's running total of ``span("h2d")`` seconds."""
+    return getattr(_tls, "h2d_s", 0.0)
 
 
 def tracing() -> Optional[GapLedger]:

@@ -252,6 +252,94 @@ def test_from_phases_builds_conservation_complete_ledger():
                + d["unattributed_s"] - d["e2e_s"]) < 1e-6
 
 
+# ---- spans at the site (PR 37): catalog refresh, h2d ------------------------
+
+
+def test_carve_upload_out_of_the_phase_that_held_it():
+    """An upload inside the dispatch (or an overflow's redrive inside the
+    sync) leaves that phase; served, the span already hinted "h2d"."""
+    phases = {"dispatch_s": 0.010, "fetch_s": 0.006, "d2h_s": 0.001,
+              "h2d_s": 0.004, "h2d_fetch_s": 0.002}
+    served, _ = carve_engine_phases(phases, served_stream_hints=True)
+    assert "h2d" not in served
+    assert served["device dispatch"] == pytest.approx(0.006)
+    assert served["device wait"] == pytest.approx(0.003)  # 6 - 2 - 1
+    solo, _ = carve_engine_phases(phases)
+    assert solo["h2d"] == pytest.approx(0.006)
+    assert sum(solo.values()) == pytest.approx(0.016)  # dispatch + fetch
+
+
+def test_span_in_window_is_a_hint_the_carve_does_not_repeat():
+    """The span hints the upload; the engine's record says it sat inside
+    dispatch_s; together they fill the window without the clamp."""
+    c = FakeClock()
+    led = GapLedger(clock=c).begin()
+    GL.set_current(led)
+    try:
+        led.window_start("engine host")
+        with GL.span("catalog refresh") as sp:
+            c.tick(0.003)
+            sp.count("catalog refresh rows", 64)
+        c.tick(0.001)  # dispatch starts
+        with GL.span("h2d") as sp:
+            c.tick(0.004)
+            sp.moved(1024)
+        c.tick(0.002)
+        led.window_end_carved({"dispatch_s": 0.006, "h2d_s": 0.004},
+                              "engine host")
+    finally:
+        GL.set_current(None)
+    led.close()
+    assert led.phases == pytest.approx({
+        "catalog refresh": 0.003, "h2d": 0.004, "device dispatch": 0.002,
+        "engine host": 0.001})
+    assert led.counts == {"catalog refresh rows": 64, "h2d uploads": 1,
+                          "h2d bytes": 1024}
+    conserved(led)
+
+
+def test_span_on_the_serial_path_comes_out_of_the_next_cut():
+    """Outside a window (the fast tier's refresh after a commit) the span
+    is taken out of the cut that holds it: still no residual."""
+    c = FakeClock()
+    led = GapLedger(clock=c).begin()
+    GL.set_current(led)
+    try:
+        c.tick(0.001)
+        with GL.span("catalog refresh"):
+            c.tick(0.005)
+        c.tick(0.002)
+        led.cut("fast lookup")
+    finally:
+        GL.set_current(None)
+    led.close()
+    assert led.phases == pytest.approx({"catalog refresh": 0.005,
+                                        "fast lookup": 0.003})
+    assert led.unattributed_s == 0.0
+    conserved(led)
+
+
+def test_span_outside_a_statement_counts_only_upload_seconds():
+    """No ledger (a background rebuild) or a closed one: nothing is
+    hinted or counted, but the thread's upload clock still runs."""
+    c = FakeClock()
+    led = GapLedger(clock=c).begin()
+    led.close()
+    GL.set_current(led)
+    try:
+        t0 = GL.h2d_seconds()
+        with GL.span("h2d") as sp:
+            sp.moved(8)
+    finally:
+        GL.set_current(None)
+    with GL.span("h2d") as sp:
+        sp.moved(8)
+    assert GL.h2d_seconds() > t0  # the two spans ran on the real clock
+    assert led.phases == {} and led.counts == {}
+    led.begin()
+    assert led.counts == {}
+
+
 # ---- batched cohorts: busy counted once -------------------------------------
 
 
@@ -531,9 +619,102 @@ def test_vt_sysstat_and_snapshot_surfaces_live(db):
     assert "window_s" in snap["host_tax"]
 
 
+_SPAN_COUNTERS = ("catalog refreshes", "catalog refresh rows",
+                  "h2d uploads", "h2d bytes")
+
+
+def _span_counters(db):
+    c = db.metrics.counters_snapshot()
+    return {k: c.get(k, 0) for k in _SPAN_COUNTERS}
+
+
+def test_tx_select_names_the_rescan_and_the_upload(db):
+    """A SELECT inside BEGIN rescans its tablet and uploads a private
+    table: both are named phases, carved (the dispatch keeps only what is
+    not the upload, no clamp), and counted once in sysstat."""
+    s = db.session()
+    q = "select sum(v) as s from gt where k < 40"
+    s.sql("begin")
+    try:
+        s.sql(q).rows()  # the plan's first run measures its footprint
+        ex = db.engine.executor
+        c0, b0 = _span_counters(db), ex.h2d_bytes
+        rs = s.sql(q)
+        assert rs.rows() == [(sum(3 * i for i in range(40)),)]
+        _assert_live_conserved(s._gap)
+        phases = dict(s._gap.phases)  # the session's ledger is reused
+        c1 = _span_counters(db)
+    finally:
+        s.sql("commit")
+    assert phases["catalog refresh"] > 0.0
+    assert phases["h2d"] == pytest.approx(rs.phases["h2d_s"])
+    assert phases["device dispatch"] == pytest.approx(
+        rs.phases["dispatch_s"] - rs.phases["h2d_s"])
+    moved = {k: c1[k] - c0[k] for k in c1}
+    assert moved["catalog refreshes"] == 1
+    assert moved["catalog refresh rows"] == 64
+    assert moved["h2d uploads"] == 1
+    assert moved["h2d bytes"] == ex.h2d_bytes - b0 > 0
+
+
+def test_autocommit_select_pays_neither(db):
+    s = db.session()
+    q = "select sum(v) as s from gt where k < 30"
+    s.sql(q).rows()
+    c0 = _span_counters(db)
+    for _ in range(3):
+        s.sql(q).rows()
+        assert not {"catalog refresh", "h2d"} & set(s._gap.phases)
+    assert _span_counters(db) == c0
+
+
+def test_private_upload_reaches_the_transfer_bytes(db):
+    """Repair (PR 37): `_build_batch` counts into `h2d_bytes`, so a
+    SELECT inside a transaction reports its upload in its profile and in
+    the plan monitor's transfer bytes, and the footprint memo still
+    hits (one upload a statement, not two)."""
+    s = db.session()
+    q = "select count(*) as n from gt where v > 30"
+    s.sql("begin")
+    try:
+        s.sql(q).rows()
+        c0 = _span_counters(db)
+        rs = s.sql(q)
+        rs.rows()
+        c1 = _span_counters(db)
+    finally:
+        s.sql("commit")
+    assert c1["h2d uploads"] - c0["h2d uploads"] == 1
+    assert rs.profile.h2d_bytes == c1["h2d bytes"] - c0["h2d bytes"] > 0
+    assert rs.profile.transfer_bytes >= rs.profile.h2d_bytes
+
+
+def test_host_tax_surfaces_name_the_new_phases(db):
+    """What tools/hosttax_smoke.py held and no test did: the statement
+    sits in __all_virtual_host_tax with its phases, sysstat carries the
+    counters, the audit row carries chip idle."""
+    s = db.session()
+    q = "select max(v) as m from gt where k < 50"
+    s.sql("begin")
+    try:
+        s.sql(q).rows()
+    finally:
+        s.sql("commit")
+    rows = s.sql("select digest, executions, phases_json "
+                 "from __all_virtual_host_tax").rows()
+    named = [json.loads(pj) for _d, _n, pj in rows]
+    assert any({"catalog refresh", "h2d"} <= set(p) for p in named)
+    stat = dict(s.sql("select name, value from __all_virtual_sysstat "
+                      "where name like 'catalog refresh%'").rows())
+    assert stat.get("catalog refresh rows", 0) >= 64
+    assert any(r.chip_idle_us > 0 for r in db.audit.records()
+               if r.stmt_type == "Select")
+
+
 def test_phase_order_covers_wired_phases():
     """Every phase name the serving stack emits renders in canonical
     order — a new phase added to the wiring must join PHASE_ORDER."""
     for name in ("setup", "fast lookup", "batch window", "retry backoff",
-                 "governor reserve", "h2d", "completion fold"):
+                 "governor reserve", "h2d", "completion fold",
+                 "catalog refresh"):
         assert name in GL.PHASE_ORDER

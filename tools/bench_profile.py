@@ -16,6 +16,10 @@ stderr beside its own lines:
                     modules
   named_counters    thread-CPU ms per statement and the front end's pool
                     hand-off wait per statement over the measured window;
+                    the statement thread's off-CPU ms per statement (its
+                    runnable wall less its CPU: the interpreter-lock wait)
+                    and the `catalog refresh ...` / `h2d ...` counters'
+                    deltas (PR 37);
                     the `dict lookup <lowering>`, `clustered agg bounds
                     <shared|gathered>`, `group keys dependent` and `merge
                     join scan-carried` counters since the start;
@@ -66,6 +70,13 @@ def log(obj) -> None:
 
 
 WAIT_PHASE = "device wait"
+# host-tax phases in which the statement's thread does not run on a CPU:
+# the device, the wire, and the parked phases (gap_ledger.BLOCKED_PHASES)
+OFF_THREAD = ("device wait", "d2h", "wire read", "admission queue",
+              "wire write", "tenant permit", "batch window",
+              "governor reserve", "retry backoff")
+SPAN_COUNTERS = ("catalog refreshes", "catalog refresh rows",
+                 "h2d uploads", "h2d bytes")
 
 
 def wait_split(ev: dict, statements: dict, idle_s: dict) -> dict:
@@ -322,6 +333,12 @@ def main(argv) -> int:
             "statements": sum(a["count"] for a in tax.values()),
             "cpu_s": sum(a.get("cpu_s", 0.0) for a in tax.values()),
             "has_cpu": any("cpu_s" in a for a in tax.values()),
+            # wall the statement thread could have run on a CPU: e2e less
+            # the device, the wire and the phases it is parked in by design
+            "runnable_s": sum(a["e2e_s"] - sum(
+                v for ph, v in a["phases"].items() if ph in OFF_THREAD)
+                for a in tax.values()),
+            "spans": {k: db.metrics.counter(k) for k in SPAN_COUNTERS},
             "pool_wait_s": w.total_s if w else None,
             "pool_waits": w.count if w else 0,
             "depth_sum": db.metrics.counter("front pool depth"),
@@ -335,6 +352,14 @@ def main(argv) -> int:
                 "host_cpu_ms_per_stmt":
                     (b["cpu_s"] - a["cpu_s"]) / n * 1000.0
                     if b["has_cpu"] and n else None,
+                # the interpreter-lock wait (PR 37, a reading): runnable
+                # wall less thread CPU
+                "host_offcpu_ms_per_stmt":
+                    ((b["runnable_s"] - a["runnable_s"])
+                     - (b["cpu_s"] - a["cpu_s"])) / n * 1000.0
+                    if b["has_cpu"] and n else None,
+                "spans": {k: b["spans"][k] - a["spans"][k]
+                          for k in SPAN_COUNTERS},
                 "pool_wait_ms_per_stmt":
                     (b["pool_wait_s"] - (a["pool_wait_s"] or 0.0))
                     / waits * 1000.0 if waits else None,

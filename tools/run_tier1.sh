@@ -6,7 +6,7 @@
 #
 #   tools/run_tier1.sh [--chaos] [--awr] [--health] [--advisor] [--warmboot]
 #                      [--elastic] [--oom] [--mesh] [--stream] [--scrub]
-#                      [--hosttax] [--planprof] [--ann] [extra pytest args...]
+#                      [--planprof] [--ann] [extra pytest args...]
 #
 # --chaos additionally runs the slow-marked chaos workload drives
 # (tests/test_chaos.py) with their fixed seeds after the tier-1 pass;
@@ -82,13 +82,6 @@
 # restart returns rows bit-identical to the in-memory model; the JSON
 # artifact (with bench_meta provenance) lands in $BENCH_OUT when set.
 #
-# --hosttax additionally runs the host-tax ledger smoke
-# (tools/hosttax_smoke.py): warm fast-path point reads and a warm Q6
-# aggregate must keep conservation exact (sum(phases) + unattributed ==
-# e2e), the median warm residual under 5%, every phase's median share
-# under its frozen budget, and the VT/sysstat/audit surfaces live; the
-# last stdout line is the JSON verdict.
-#
 # --planprof additionally runs the plan-profile smoke
 # (tools/planprof_smoke.py): a warm TPC-H Q1/Q6/Q3 mix profiled
 # through the segmented per-operator executor must return rows
@@ -130,7 +123,6 @@ oom=0
 mesh=0
 stream=0
 scrub=0
-hosttax=0
 planprof=0
 ann=0
 while true; do
@@ -145,7 +137,6 @@ while true; do
         --mesh) mesh=1; shift ;;
         --stream) stream=1; shift ;;
         --scrub) scrub=1; shift ;;
-        --hosttax) hosttax=1; shift ;;
         --planprof) planprof=1; shift ;;
         --ann) ann=1; shift ;;
         *) break ;;
@@ -211,11 +202,6 @@ fi
 
 if [ "$scrub" = "1" ] && [ "$rc" = "0" ]; then
     timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/chaos_bench.py --disk
-    rc=$?
-fi
-
-if [ "$hosttax" = "1" ] && [ "$rc" = "0" ]; then
-    timeout -k 10 600 env JAX_PLATFORMS=cpu python tools/hosttax_smoke.py
     rc=$?
 fi
 
