@@ -444,6 +444,12 @@ class Executor:
         # TWO tables (fk_ranges) revalidate against both versions, since
         # the key-prefix delete in invalidate_table only covers one
         self._table_version: dict[str, int] = {}
+        # an invalidation (version bump + drop) and an upload's entry into
+        # the cache are atomic to each other: table_batch stores what it
+        # uploaded only while the version it started at still holds, so
+        # an upload of a table a publish replaced meanwhile never outlives
+        # that publish's invalidation
+        self._cache_lock = threading.Lock()
         # lifetime host->device upload bytes (QueryProfile reads the delta
         # around one execution: cache hits upload nothing, which is the
         # point of the per-column device cache)
@@ -654,11 +660,12 @@ class Executor:
 
     def invalidate_table(self, name: str) -> None:
         """Drop cached device batches of one table (its data changed)."""
-        self._table_version[name] = self._table_version.get(name, 0) + 1
-        for key in [k for k in self._batch_cache if k[0] == name]:
-            del self._batch_cache[key]
-        for key in [k for k in self._assembled if k[0] == name]:
-            del self._assembled[key]
+        with self._cache_lock:
+            self._table_version[name] = self._table_version.get(name, 0) + 1
+            for key in [k for k in self._batch_cache if k[0] == name]:
+                del self._batch_cache[key]
+            for key in [k for k in self._assembled if k[0] == name]:
+                del self._assembled[key]
 
     def input_device_bytes(self, input_spec) -> int:
         """Device-resident footprint of a prepared plan's inputs (array
@@ -888,19 +895,29 @@ class Executor:
         cap = max(1024, -(-max(n, 1) // 1024) * 1024)
         cache = self._batch_cache
         skey = (name, "#sel")
-        cold = [f for f in sub_schema.fields if (name, f.name) not in cache]
-        if cold or skey not in cache:
+        have = {f.name: cache.get((name, f.name)) for f in sub_schema.fields}
+        sel = cache.get(skey)
+        cold = [f for f in sub_schema.fields if have[f.name] is None]
+        if cold or sel is None:
             # one span per table batch that moves columns to the device
             with _gap.span("h2d") as sp:
-                sp.moved(self._upload_cold(name, t, cold, cap))
+                up, new_sel, nb = self._upload_cold(t, cold, cap, sel is None)
+                sp.moved(nb)
+            with self._cache_lock:
+                if self._table_version.get(name, 0) == ver:
+                    cache.update({(name, c): v for c, v in up.items()})
+                    if new_sel is not None:
+                        cache[skey] = new_sel
+            have.update(up)
+            if new_sel is not None:
+                sel = new_sel
         dcols: dict[str, jnp.ndarray] = {}
         dvalid: dict[str, jnp.ndarray] = {}
         for f in sub_schema.fields:
-            dev, vdev = cache[(name, f.name)]
+            dev, vdev = have[f.name]
             dcols[f.name] = dev
             if vdev is not None:
                 dvalid[f.name] = vdev
-        sel = cache[skey]
         batch = ColumnBatch(
             cols=dcols,
             valid=dvalid,
@@ -909,16 +926,20 @@ class Executor:
             schema=sub_schema,
             dicts={c: d for c, d in t.dicts.items() if c in cols},
         )
-        self._assembled[(name, cols)] = (ver, batch)
+        with self._cache_lock:
+            if self._table_version.get(name, 0) == ver:
+                self._assembled[(name, cols)] = (ver, batch)
         return batch
 
-    def _upload_cold(self, name: str, t, fields, cap: int) -> int:
-        """Upload the `fields` of `t` the device cache lacks (and its
-        `#sel` plane if missing) into the cache; returns the bytes."""
+    def _upload_cold(self, t, fields, cap: int, with_sel: bool):
+        """Upload the `fields` of `t` the device cache lacks, and its
+        `#sel` plane `with_sel`: ({column: (data, validity)}, the plane or
+        None, bytes), for table_batch to cache."""
         from ..core.column import narrowed_upload
 
         n = t.nrows
-        skey = (name, "#sel")
+        out = {}
+        sel = None
         nb = 0
         for f in fields:
             a = np.asarray(t.data[f.name], dtype=f.dtype.storage_np)
@@ -934,14 +955,13 @@ class Executor:
                     v = np.concatenate(
                         [v, np.zeros(cap - n, dtype=np.bool_)])
                 vdev = jnp.asarray(v)
-            self._batch_cache[(name, f.name)] = (dev, vdev)
+            out[f.name] = (dev, vdev)
             nb += int(dev.nbytes) + (
                 int(vdev.nbytes) if vdev is not None else 0)
-        if skey not in self._batch_cache:
+        if with_sel:
             s = np.zeros(cap, dtype=np.bool_)
             s[:n] = True
             sel = jnp.asarray(s)
-            self._batch_cache[skey] = sel
             nb += int(sel.nbytes)
         self.h2d_bytes += nb
         self.cache_h2d_bytes += nb
@@ -950,7 +970,7 @@ class Executor:
             # a cold-column upload steals device time from the serving
             # stream: transfer interference
             tl.record_transfer(nb)
-        return nb
+        return out, sel, nb
 
     def _build_batch(self, name: str, cols: tuple[str, ...]) -> ColumnBatch:
         t = self.catalog[name]

@@ -119,21 +119,20 @@ class StandbyCluster:
         db = self.db
         # dictionary growth first: row values reference the codes
         apply_dict_appends(self._by_primary_tablet, ch.dict_appends)
-        touched = set()
-        for row in ch.rows:
-            ti = self._by_primary_tablet.get(row.tablet_id)
-            if ti is None:
-                continue  # table not in the backup set
-            for rep in db.cluster.ls_groups[ti.ls_id].values():
-                rep.tablets[ti.tablet_id].active.replay(
-                    row.key, OP_PUT if row.op == "put" else OP_DELETE,
-                    row.values, ch.commit_version)
-            touched.add(ti.name)
-        db.cluster.gts.advance_to(ch.commit_version)
-        for nm in touched:
-            ti = db.tables[nm]
-            ti.data_version += 1
-            ti.cached_data_version = -1
+        touched = {ti.name: db.tables[ti.name] for ti in (
+            self._by_primary_tablet.get(row.tablet_id) for row in ch.rows)
+            if ti is not None}  # a table not in the backup set is skipped
+        # open on the tables until the bump (Database.tx_shared_entry)
+        with db.bulk_write(touched.values()):
+            for row in ch.rows:
+                ti = self._by_primary_tablet.get(row.tablet_id)
+                if ti is None:
+                    continue
+                for rep in db.cluster.ls_groups[ti.ls_id].values():
+                    rep.tablets[ti.tablet_id].active.replay(
+                        row.key, OP_PUT if row.op == "put" else OP_DELETE,
+                        row.values, ch.commit_version)
+            db.cluster.gts.advance_to(ch.commit_version)
         self.applied_scn = max(self.applied_scn, ch.commit_version)
 
     # ------------------------------------------------------------- serving

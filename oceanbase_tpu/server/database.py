@@ -124,6 +124,12 @@ class TableInfo:
     schema_version: int = 0  # set at create time (schema service analog)
     # last data version materialized into the analytic catalog (-1 = stale)
     cached_data_version: int = -1
+    # what Database.tx_shared_entry reads, under Database._snapshot_lock:
+    # the highest commit version a finished writer left in the table, and
+    # the writers still open on it (a transaction from its first write to
+    # its cleanup, a bulk writer from its version to its bump)
+    last_commit_version: int = 0
+    writers: int = 0
     # per-column (dict length at build time, sorted Dictionary, remap array)
     _sorted_cache: dict[str, tuple[int, Dictionary, np.ndarray]] = field(
         default_factory=dict
@@ -413,6 +419,15 @@ class Database:
         # analytic catalog: table name -> snapshot Table (plus any read-only
         # preloaded tables, e.g. benchmark data)
         self.catalog: dict[str, Table] = TxCatalog(extra_catalog or {})
+        # held briefly, never across a scan: a shared entry's publish and
+        # label, a writer's start and end (TableInfo.writers,
+        # last_commit_version, data_version), and tx_shared_entry's
+        # reading of them, so each sees the others whole
+        self._snapshot_lock = threading.Lock()
+        # (tx context, its TableInfos) of writers whose decision was in
+        # flight at their end (writers_end): open until settle_writers
+        # sees it land
+        self._undecided: list = []
         # placeholder entries for restored tables (create_table provides
         # one on the DDL path): the resolver requires every table in the
         # shared catalog even when the first statement reads it through a
@@ -1196,6 +1211,7 @@ class Database:
         self.schema_service.apply_ddl(mutate)
         for ti in tables.values():
             ti.cached_data_version = -1
+            ti.writers = 0  # the snapshot may have caught a writer open
             if not hasattr(ti, "indexes"):  # pre-index node_meta snapshots
                 ti.indexes = {}
             if not hasattr(ti, "partitions") or ti.partitions is None:
@@ -2048,6 +2064,7 @@ class Database:
         snapshot (repeatable reads across the whole statement set); tables
         the tx wrote additionally see their own staged rows via tx_id. Tx
         views are never left in the committed cache."""
+        self.settle_writers()
         for name in names:
             ti = self.tables.get(name)
             if ti is None:
@@ -2055,9 +2072,12 @@ class Database:
             in_tx = tx is not None and tx.ctx is not None
             if not in_tx and ti.cached_data_version == ti.data_version:
                 continue
-            # the snapshot rescan: every statement of a transaction (the
-            # early continue never fires in one), an autocommit one only
-            # after a commit moved the table's data version
+            # the snapshot rescan: a statement of a transaction that
+            # tx_shared_entry did not serve, an autocommit one only after
+            # a commit moved the table's data version. The version is read
+            # BEFORE the scan: a commit that bumps it meanwhile leaves the
+            # entry labelled stale, never current without its rows
+            version = ti.data_version
             with _GL.span("catalog refresh") as sp:
                 touched = in_tx and name in tx.touched_tables
                 snap = (tx.ctx.read_snapshot if in_tx
@@ -2134,16 +2154,22 @@ class Database:
                         self._invalidate(pname)
                     drop_projections(self.catalog, name)
                     self.plan_cache.flush()
-                self.catalog[name] = t
                 vspecs = self._vector_specs.get(name)
                 if vspecs:
                     from ..storage.vector_index import register_vector_index
 
                     for col, (lists, nprobe) in vspecs.items():
                         register_vector_index(
-                            self.catalog, name, col, lists, nprobe)
+                            {name: t}, name, col, lists, nprobe)
+                # the publish, whole to tx_shared_entry: the entry, its
+                # device batches dropped, its label
+                with self._snapshot_lock:
+                    self.catalog[name] = t
+                    self._invalidate(name)
+                    ti.cached_data_version = version
+                if vspecs:
                     # DML invalidated the built IVF artifacts (the
-                    # _invalidate below drops the executor's #ivfh/#ivfd
+                    # _invalidate above dropped the executor's #ivfh/#ivfd
                     # caches): re-queue background rebuilds so the next
                     # ANN query probes warm instead of k-means inline
                     self.metrics.add(
@@ -2153,8 +2179,6 @@ class Database:
                             name, list(vspecs))
                     except Exception:  # noqa: BLE001 - advisory path
                         pass
-                self._invalidate(name)
-                ti.cached_data_version = ti.data_version
                 if requeue is not None:
                     try:
                         # only now that the refreshed snapshot landed: a
@@ -2164,6 +2188,124 @@ class Database:
                     except Exception:  # noqa: BLE001 - advisory path
                         pass
                 self._enforce_memory(keep=name)
+
+    def tx_shared_entry(self, name: str, tx) -> "tuple[Table, int] | None":
+        """(the shared committed entry of `name`, the data version it was
+        found at) where that entry answers exactly as a rescan at `tx`'s
+        BEGIN snapshot would; None where the statement must rescan.
+
+        Checked under `_snapshot_lock`: `tx` staged nothing in the table;
+        no writer is open on it, so no commit of it is in flight; the
+        entry's label is the data version read before its scan, and still
+        current, so every writer finished before that read, and the
+        entry's snapshot, drawn after it, holds all their commits; and the
+        highest version they committed at is at or below `tx`'s snapshot.
+        Both snapshots then hold every commit of the table and nothing
+        else, and a commit after the check draws a version above both
+        (they were drawn before it). A stale entry of a quiet table is
+        published anew, as an autocommit read would; what changes while
+        the statement runs is `tx_shared_holds`'."""
+        ti = self.tables.get(name)
+        if ti is None or name in tx.touched_tables:
+            return None
+        snap = tx.ctx.read_snapshot
+        self.settle_writers()
+        for refreshed in (False, True):
+            with self._snapshot_lock:
+                if ti.writers or ti.last_commit_version > snap:
+                    return None
+                if ti.cached_data_version == ti.data_version:
+                    t = dict.get(self.catalog, name)
+                    return None if t is None else (t, ti.data_version)
+            if refreshed:
+                return None
+            self.refresh_catalog([name], tx=None)
+
+    def tx_shared_holds(self, shared: dict) -> bool:
+        """True while every entry `tx_shared_entry` gave is still the
+        catalog's and its table's data version has not moved: a statement
+        that read them stands, else it runs again on its rescan."""
+        with self._snapshot_lock:
+            return all(
+                dict.get(self.catalog, n) is t
+                and self.tables[n].data_version == v
+                for n, (t, v) in shared.items())
+
+    def writer_open(self, tx, ti: TableInfo) -> None:
+        """`tx` writes `ti` for the first time: open on it until
+        `writers_close`, so no transaction reads its shared entry."""
+        with self._snapshot_lock:
+            if ti.name in tx.touched_tables:
+                return
+            ti.writers += 1
+            tx.touched_tables.add(ti.name)
+            tx.writing.append(ti)
+
+    def writers_close(self, tis, commit_version: int | None) -> None:
+        """Writers of `tis` end; `commit_version` is what they committed
+        at (None: nothing committed). The version is noted before the
+        bump and the writer counted out after it, in one step."""
+        with self._snapshot_lock:
+            for ti in tis:
+                if commit_version is not None:
+                    ti.last_commit_version = max(
+                        ti.last_commit_version, commit_version)
+                    ti.data_version += 1
+                ti.cached_data_version = -1
+                ti.writers -= 1
+
+    def writers_end(self, ctx, tis) -> None:
+        """The transaction `ctx` stops writing `tis`: closed at its
+        commit version once decided; else (a commit wait that timed out,
+        its decision in flight) left open until `settle_writers` sees the
+        decision land, so no reader takes a shared entry that the late
+        commit would leave without its rows."""
+        from ..tx.txn import TxState
+
+        tis = list(tis)
+        if not tis:
+            return
+        if ctx.is_done:
+            self.writers_close(tis, ctx.commit_version
+                               if ctx.state is TxState.COMMITTED else None)
+            return
+        with self._snapshot_lock:
+            for ti in tis:
+                ti.cached_data_version = -1
+            self._undecided.append((ctx, tis))
+
+    def settle_writers(self) -> None:
+        """Close the writers `writers_end` left open whose decision has
+        landed since."""
+        if not self._undecided:
+            return
+        keep, done = [], []
+        with self._snapshot_lock:
+            for w in self._undecided:
+                (done if w[0].is_done else keep).append(w)
+            self._undecided = keep
+        for ctx, tis in done:
+            self.writers_end(ctx, tis)
+
+    @contextlib.contextmanager
+    def bulk_write(self, tis):
+        """A writer outside a transaction is open on `tis` from before it
+        draws its version until after it bumps: the bump counts it as
+        committed at the GTS's current value, at or above any version it
+        drew. Every path that changes a table's rows outside a
+        transaction comes through here (direct load, standby apply, a
+        recovered XA branch's decision, restore and PITR replay); a
+        transaction's writes go through `writer_open` and `writers_end`.
+        A path that bumps `data_version` itself would let
+        `tx_shared_entry` serve an entry without its rows."""
+        tis = list(tis)
+        with self._snapshot_lock:
+            for ti in tis:
+                ti.writers += 1
+        try:
+            yield
+        finally:
+            self.writers_close(tis, self.cluster.gts.current())
 
     # ------------------------------------------------------ follower reads
     #: bound on replica-snapshot catch-up waits before a bounded-staleness
@@ -2345,12 +2487,14 @@ class Database:
             t = self.catalog.get(name)
             if t is None or not t.data or ti.cached_data_version < 0:
                 continue
-            self.catalog[name] = Table(name, ti.schema, {
+            empty = Table(name, ti.schema, {
                 f.name: np.zeros(0, f.dtype.storage_np)
                 for f in ti.schema.fields
             })
-            ti.cached_data_version = -1
-            self._invalidate(name)
+            with self._snapshot_lock:
+                self.catalog[name] = empty
+                ti.cached_data_version = -1
+                self._invalidate(name)
             if self._resident_bytes() <= limit:
                 return
         if self._resident_bytes() > limit:
@@ -2467,6 +2611,8 @@ class _OpenTx:
         self.svc = db.cluster.services[home]
         self.ctx = self.svc.begin()
         self.touched_tables: set[str] = set()
+        # the TableInfos this tx is counted open on (Database.writer_open)
+        self.writing: list[TableInfo] = []
         # tx-private catalog views (BEGIN snapshot + own staged rows),
         # activated per-statement through TxCatalog.tx_scope
         self.views: dict[str, Table] = {}
@@ -3936,8 +4082,6 @@ class DbSession:
         re-staged rows / replays pending redo). `snapshot` is the handle's
         registry snapshot: a retry after a failed decide can finish
         cleanup from it even once the live registry entry has popped."""
-        from ..tx.records import RecordType, TxRecord
-
         e = self.db._xa_registry.get(xid) or snapshot
         if e is None:
             return  # decision already applied (e.g. raced another session)
@@ -3952,6 +4096,21 @@ class DbSession:
                 f"xid {xid!r} already deciding {prior}; retry that",
                 code=1399)
         e["decision"] = want
+        if not commit:
+            self._xa_decide_recovered(xid, e, commit)
+            return
+        by_tab = {ti.tablet_id: ti for ti in self.db.tables.values()}
+        tis = {by_tab[t].name: by_tab[t] for t in e["tablets"]
+               if t in by_tab}
+        # open on the branch's tables from before its version is drawn
+        # until the bump (Database.tx_shared_entry)
+        with self.db.bulk_write(tis.values()):
+            self._xa_decide_recovered(xid, e, commit)
+        self.db.run_maintenance()
+
+    def _xa_decide_recovered(self, xid: str, e: dict, commit: bool) -> None:
+        from ..tx.records import RecordType, TxRecord
+
         tx_id, parts = e["tx_id"], tuple(e["parts"])
         if xid in self.db._xa_registry:
             # first attempt (or retry whose records never reached a log):
@@ -3987,17 +4146,6 @@ class DbSession:
         if not self.db.cluster.drive_until(all_applied):
             raise SqlError(f"XA decision for xid {xid!r} did not apply")
         self.db.lock_mgr.release_all(tx_id)
-        if commit:
-            self._xa_bump_versions(e)
-
-    def _xa_bump_versions(self, e: dict) -> None:
-        by_tab = {ti.tablet_id: ti for ti in self.db.tables.values()}
-        for tab in e["tablets"]:
-            ti = by_tab.get(tab)
-            if ti is not None:
-                ti.data_version += 1
-                ti.cached_data_version = -1
-        self.db.run_maintenance()
 
     # -------------------------------------------------- stored procedures
     def _create_procedure(self, text: str) -> ResultSet:
@@ -4255,8 +4403,9 @@ class DbSession:
         key) with equality literals reads the few matching rows through the
         host index path instead of materializing the whole table to the
         device. Returns a statement-scoped {table: pruned Table} view, or
-        None to fall back to the full-scan path. Autocommit reads only —
-        in-tx statements keep their BEGIN-snapshot materialization."""
+        None to fall back to the full-scan path. Inside a transaction it
+        reads at the BEGIN snapshot, and only a table the transaction has
+        not written (its staged rows live in the rescan's private view)."""
         if not isinstance(ast, A.Select) or len(ast.from_) != 1:
             return None
         tref = ast.from_[0]
@@ -4268,6 +4417,9 @@ class DbSession:
             return None
         ti = self.db.tables.get(tref.name)
         if ti is None or ast.where is None:
+            return None
+        tx = self._tx
+        if tx is not None and tref.name in tx.touched_tables:
             return None
         alias = tref.alias or tref.name
         from ..sql.planner import split_ast_conjuncts
@@ -4305,7 +4457,8 @@ class DbSession:
 
         if not eqs:
             return None
-        snap = self.db.cluster.gts.current()
+        snap = (tx.ctx.read_snapshot if tx is not None
+                else self.db.cluster.gts.current())
         rep = self.db._leader_replica(ti)
         rows: list[tuple] | None = None
         used_idx = None
@@ -4317,7 +4470,9 @@ class DbSession:
         else:
             best = None
             for idx in ti.indexes.values():
-                if idx.status != "ready":
+                # an index built after the snapshot holds none of the
+                # rows it backfilled at that snapshot
+                if idx.status != "ready" or idx.build_version > snap:
                     continue
                 m = 0
                 for c in idx.cols:
@@ -4464,20 +4619,56 @@ class DbSession:
             if rs is not None:
                 return rs
             # bound unmet / no reachable follower: strong leader path below
+        tx = self._tx
+        in_tx = tx is not None and tx.ctx is not None
+        # a single-chip SELECT of a transaction may take the autocommit
+        # route, read at the transaction's snapshot: the index route for a
+        # table it has not written, else the shared committed entry of
+        # each table tx_shared_entry finds clean for that snapshot
+        shared_ok = (in_tx and not any_vt
+                     and self._vars.get("ob_px_dop", 0) == 0
+                     and isinstance(ast, A.Select))
         route = None
-        if self._tx is None and not any_vt and isinstance(ast, A.Select):
+        if shared_ok or (tx is None and not any_vt
+                         and isinstance(ast, A.Select)):
             route = self._index_route(ast)
-        if route is not None:
+        if route is not None and not (in_tx and set(names) - set(route)):
             self.db.refresh_catalog(
                 [n for n in names if n not in route], tx=None
             )
             with self.db.catalog.tx_scope(route):
                 rs = self.db.engine.run_ast(ast, norm_key)
             self._scan_rs = rs
+            if in_tx:
+                self.db.metrics.add("tx snapshot shared reads")
             return rs
-        self.db.refresh_catalog(names, tx=self._tx)
-        in_tx = self._tx is not None and self._tx.ctx is not None
-        views = self._tx.views if in_tx else None
+        shared = {}
+        if shared_ok:
+            for n in names:
+                e = self.db.tx_shared_entry(n, tx)
+                if e is not None:
+                    shared[n] = e
+        if shared:
+            self.db.refresh_catalog(
+                [n for n in names if n not in shared], tx=tx)
+            views = {n: v for n, v in tx.views.items() if n not in shared}
+            with self.db.catalog.tx_scope(views):
+                rs = self.db.engine.run_ast(ast, norm_key)
+            if self.db.tx_shared_holds(shared):
+                self._scan_rs = rs
+                self.db.metrics.add(
+                    "tx snapshot shared reads"
+                    if all(n in shared for n in names
+                           if n in self.db.tables)
+                    else "tx snapshot private reads")
+                return rs
+            # an entry was replaced, or the table written, while the
+            # statement ran: its answer is thrown away, the rescan below
+            # answers instead
+        self.db.refresh_catalog(names, tx=tx)
+        views = tx.views if in_tx else None
+        if in_tx and any(n in self.db.tables for n in names):
+            self.db.metrics.add("tx snapshot private reads")
         # PX routing: non-virtual statements of a session with a DOP
         # variable run on the distributed executor. In-tx reads are safe:
         # the PX executor bypasses its shared input cache for tx-private
@@ -4700,14 +4891,9 @@ class DbSession:
         touched = tx.touched_tables
         # locks hold through the commit decision, then release
         self.db.lock_mgr.release_all(tx.ctx.tx_id)
-        by_tablet = {}
-        for name in touched:
-            ti = self.db.tables.get(name)
-            if ti is not None:
-                by_tablet[ti.tablet_id] = ti
-                if committed_ok:
-                    ti.data_version += 1
-                ti.cached_data_version = -1
+        by_tablet = {ti.tablet_id: ti for ti in tx.writing}
+        self.db.writers_end(tx.ctx, tx.writing)
+        tx.writing = []
         if committed_ok:
             # the appends are durable now (committed_ok, NOT the commit
             # intent: a failed commit logged nothing): later commits
@@ -4768,7 +4954,7 @@ class DbSession:
                 tx.svc.write(tx.ctx, ls_id, tab_id, key, op, vals)
             for tab_id, key, op, vals in index_muts:
                 tx.svc.write(tx.ctx, ti.ls_id, tab_id, key, op, vals)
-            tx.touched_tables.add(ti.name)
+            self.db.writer_open(tx, ti)
         return len(muts)
 
     @staticmethod

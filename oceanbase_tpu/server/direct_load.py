@@ -102,45 +102,46 @@ def direct_load(db, table_name: str, data: dict[str, object]) -> int:
         ],
         dtype=np.int64,
     )
-    version = db.cluster.gts.next_ts()
-    for p_idx, (pls, ptab) in enumerate(ti.all_partitions()):
-        m = part_ids == p_idx
-        if not m.any():
-            continue
-        pcols = {c: v[m] for c, v in cols.items()}
-        pk2d = keys2d[m]
-        # existing-key collision check through the tablet's read path
-        rep = db._leader_replica_ls(pls)
-        tablet = rep.tablets[ptab]
-        if tablet.nrows_estimate:
-            maybe = np.zeros(len(pk2d), dtype=bool)
-            for st in ([tablet.base] if tablet.base else []) + list(tablet.deltas):
-                maybe |= st.may_contain_keys(pk2d)
-            for mt in [tablet.active] + list(tablet.frozen):
-                if mt.nkeys:
-                    for i in np.flatnonzero(~maybe):
-                        if mt.get(tuple(pk2d[i]), 2**62) is not None:
-                            maybe[i] = True
-            for i in np.flatnonzero(maybe):
-                if tablet.get(tuple(pk2d[i]), 2**62) is not None:
-                    raise DirectLoadError(
-                        f"primary key {tuple(pk2d[i])} already exists"
+    # open on the table from before its version is drawn until the bump
+    # (Database.tx_shared_entry)
+    with db.bulk_write([ti]):
+        version = db.cluster.gts.next_ts()
+        for p_idx, (pls, ptab) in enumerate(ti.all_partitions()):
+            m = part_ids == p_idx
+            if not m.any():
+                continue
+            pcols = {c: v[m] for c, v in cols.items()}
+            pk2d = keys2d[m]
+            # existing-key collision check through the tablet's read path
+            rep = db._leader_replica_ls(pls)
+            tablet = rep.tablets[ptab]
+            if tablet.nrows_estimate:
+                maybe = np.zeros(len(pk2d), dtype=bool)
+                for st in ([tablet.base] if tablet.base else []) + list(tablet.deltas):
+                    maybe |= st.may_contain_keys(pk2d)
+                for mt in [tablet.active] + list(tablet.frozen):
+                    if mt.nkeys:
+                        for i in np.flatnonzero(~maybe):
+                            if mt.get(tuple(pk2d[i]), 2**62) is not None:
+                                maybe[i] = True
+                for i in np.flatnonzero(maybe):
+                    if tablet.get(tuple(pk2d[i]), 2**62) is not None:
+                        raise DirectLoadError(
+                            f"primary key {tuple(pk2d[i])} already exists"
+                        )
+            blob = write_sstable(
+                ti.schema, ti.key_cols, pcols,
+                versions=np.full(int(m.sum()), version, np.int64),
+                ops=np.zeros(int(m.sum()), np.int8),
+                base_version=0, end_version=version,
+            )
+            # install on every replica (the data-movement replication analog)
+            for r in db.cluster.ls_groups[pls].values():
+                t = r.tablets[ptab]
+                with t._meta_lock:
+                    t.deltas.append(
+                        SSTable(blob, ti.schema, ti.key_cols, cache=db.block_cache)
                     )
-        blob = write_sstable(
-            ti.schema, ti.key_cols, pcols,
-            versions=np.full(int(m.sum()), version, np.int64),
-            ops=np.zeros(int(m.sum()), np.int8),
-            base_version=0, end_version=version,
-        )
-        # install on every replica (the data-movement replication analog)
-        for r in db.cluster.ls_groups[pls].values():
-            t = r.tablets[ptab]
-            with t._meta_lock:
-                t.deltas.append(
-                    SSTable(blob, ti.schema, ti.key_cols, cache=db.block_cache)
-                )
-    ti.data_version += 1
-    ti.cached_data_version = -1
     return int(n)
 
 

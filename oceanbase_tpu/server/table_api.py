@@ -120,19 +120,20 @@ class TableApi:
                                 (idx.tablet_id, old_ik, OP_DELETE, None))
                         index_muts.append(
                             (idx.tablet_id, new_ik, OP_PUT, new_iv))
+            # open on the table until the bump (Database.tx_shared_entry)
+            self.db.writer_open(tx, ti)
             for ls_id, tab_id, key, op, vals in routed:
                 tx.svc.write(tx.ctx, ls_id, tab_id, key, op, vals)
             for tab_id, key, op, vals in index_muts:
                 tx.svc.write(tx.ctx, ti.ls_id, tab_id, key, op, vals)
             self.db.cluster.commit_sync(tx.svc, tx.ctx)
-            ti.data_version += 1
         except Exception:
             if not tx.ctx.is_done:
                 tx.svc.abort(tx.ctx)
             raise
         finally:
             self.db.lock_mgr.release_all(tx.ctx.tx_id)
-            ti.cached_data_version = -1
+            self.db.writers_end(tx.ctx, tx.writing)
 
     def put(self, row: dict) -> None:
         """Upsert one row (HBase-put semantics: blind write)."""

@@ -127,10 +127,14 @@ def restore_database(root: str, n_nodes: int = 3, n_ls: int = 2,
         ss = load_sstable(os.path.join(root, f"{tmeta['name']}.sst"),
                           schema, ti.key_cols, cache=db.block_cache)
         blob = bytes(ss.buf)
-        for rep in db.cluster.ls_groups[ti.ls_id].values():
-            t = rep.tablets[ti.tablet_id]
-            t.base = SSTable(blob, schema, ti.key_cols, cache=db.block_cache)
-        ti.data_version += 1
+        # the snapshot's rows commit at or below backup_scn
+        # (Database.bulk_write)
+        with db.bulk_write([ti]):
+            for rep in db.cluster.ls_groups[ti.ls_id].values():
+                t = rep.tablets[ti.tablet_id]
+                t.base = SSTable(blob, schema, ti.key_cols,
+                                 cache=db.block_cache)
+            db.cluster.gts.advance_to(backup_scn)
         old_to_new[tmeta["tablet_id"]] = (ti, schema)
 
     db.cluster.gts.advance_to(backup_scn)
@@ -173,20 +177,19 @@ def restore_database(root: str, n_nodes: int = 3, n_ls: int = 2,
                 continue  # already inside the backup snapshot
             if restore_scn is not None and ch.commit_version > restore_scn:
                 continue
-            for row in ch.rows:
-                hit = old_to_new.get(row.tablet_id)
-                if hit is None:
-                    continue  # table not in the backup set
-                ti, _schema = hit
-                for rep in db.cluster.ls_groups[ti.ls_id].values():
-                    rep.tablets[ti.tablet_id].active.replay(
-                        row.key, OP_PUT if row.op == "put" else OP_DELETE,
-                        row.values, ch.commit_version,
-                    )
-            db.cluster.gts.advance_to(ch.commit_version)
-            ti_names = {old_to_new[r.tablet_id][0].name
-                        for r in ch.rows if r.tablet_id in old_to_new}
-            for nm in ti_names:
-                db.tables[nm].data_version += 1
+            tis = {old_to_new[r.tablet_id][0].name: old_to_new[r.tablet_id][0]
+                   for r in ch.rows if r.tablet_id in old_to_new}
+            with db.bulk_write(tis.values()):
+                for row in ch.rows:
+                    hit = old_to_new.get(row.tablet_id)
+                    if hit is None:
+                        continue  # table not in the backup set
+                    ti, _schema = hit
+                    for rep in db.cluster.ls_groups[ti.ls_id].values():
+                        rep.tablets[ti.tablet_id].active.replay(
+                            row.key, OP_PUT if row.op == "put" else OP_DELETE,
+                            row.values, ch.commit_version,
+                        )
+                db.cluster.gts.advance_to(ch.commit_version)
 
     return db

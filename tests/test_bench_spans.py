@@ -56,8 +56,9 @@ def test_decoder_agrees_with_profile_data(tmp_path):
 
 
 def test_tx_select_leaves_carry_the_statement(tmp_path):
-    """PR 37: a SELECT inside BEGIN writes `ob:catalog refresh` and
-    `ob:h2d` leaves with its `stmt` stat; one leaf at a time on the
+    """PR 37: a SELECT inside BEGIN of a table the transaction wrote
+    writes `ob:catalog refresh` and `ob:h2d` leaves with its `stmt`
+    stat; one leaf at a time on the
     thread, and the `device dispatch` leaf the upload interrupted opens
     again when the upload ends."""
     from jax.profiler import TraceAnnotation
@@ -72,6 +73,7 @@ def test_tx_select_leaves_carry_the_statement(tmp_path):
             f"({i}, {i})" for i in range(100)))
         q = "select sum(v) as s from lt where k < 60"
         s.sql("begin")
+        s.sql("update lt set v = v where k = 0")  # its reads rescan
         s.sql(q).rows()  # compiles outside the trace
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0

@@ -629,13 +629,15 @@ def _span_counters(db):
 
 
 def test_tx_select_names_the_rescan_and_the_upload(db):
-    """A SELECT inside BEGIN rescans its tablet and uploads a private
-    table: both are named phases, carved (the dispatch keeps only what is
-    not the upload, no clamp), and counted once in sysstat."""
+    """A SELECT inside BEGIN of a table the transaction wrote rescans its
+    tablet and uploads a private table: both are named phases, carved (the
+    dispatch keeps only what is not the upload, no clamp), and counted
+    once in sysstat."""
     s = db.session()
     q = "select sum(v) as s from gt where k < 40"
     s.sql("begin")
     try:
+        s.sql("update gt set v = v where k = 0")
         s.sql(q).rows()  # the plan's first run measures its footprint
         ex = db.engine.executor
         c0, b0 = _span_counters(db), ex.h2d_bytes
@@ -677,6 +679,7 @@ def test_private_upload_reaches_the_transfer_bytes(db):
     q = "select count(*) as n from gt where v > 30"
     s.sql("begin")
     try:
+        s.sql("update gt set v = v where k = 0")  # its reads rescan
         s.sql(q).rows()
         c0 = _span_counters(db)
         rs = s.sql(q)
