@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .dictionary import Dictionary
+from .dictionary import Dictionary, DictPin
 from .dtypes import DataType, Field, Schema, TypeKind
 
 
@@ -84,6 +84,43 @@ class ColumnBatch:
             schema=Schema(fields),
             dicts={n: d for n, d in self.dicts.items() if n in names},
         )
+
+
+def pin_dicts(inputs: dict, deps: dict) -> dict:
+    """One call's inputs to a plan's programs with each batch's
+    dictionaries pinned (`DictPin`, sharing the plan's `deps`): one pin
+    per dictionary, so columns that shared a dictionary still share it."""
+    pins: dict[int, DictPin] = {}
+    out = {}
+    for alias, b in inputs.items():
+        if isinstance(b, ColumnBatch) and b.dicts:
+            ds = {}
+            for c, d in b.dicts.items():
+                p = pins.get(id(d))
+                if p is None:
+                    p = pins[id(d)] = (d if type(d) is DictPin
+                                       else DictPin(d, deps))
+                ds[c] = p
+            b = replace(b, dicts=ds)
+        out[alias] = b
+    return out
+
+
+def unpin_dicts(out: ColumnBatch, inputs: dict) -> ColumnBatch:
+    """A program's output with the dictionaries of THIS call's inputs in
+    place of its pins: a reused program hands back the pins of the call
+    that traced it, which may hold an older version of a lineage."""
+    if not any(type(d) is DictPin for d in out.dicts.values()):
+        return out
+    now = {}
+    for b in inputs.values():
+        if isinstance(b, ColumnBatch):
+            for d in b.dicts.values():
+                if type(d) is DictPin:
+                    now[d._key()] = d.dictionary
+    return replace(out, dicts={
+        c: (now.get(d._key(), d.dictionary) if type(d) is DictPin else d)
+        for c, d in out.dicts.items()})
 
 
 def batch_rows_storage(batch, names) -> dict:

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.column import ColumnBatch, batch_to_host
+from ..core.column import ColumnBatch, batch_to_host, pin_dicts, unpin_dicts
 from ..core.dtypes import DataType, Field, Schema, TypeKind
 from ..expr import ir as E
 from ..expr.compile import (
@@ -388,7 +388,9 @@ def _dict_domain(batch: ColumnBatch, e: E.Expr) -> int | None:
     if isinstance(e, E.ColRef):
         d = batch.dicts.get(e.name)
         if d is not None:
-            return len(d)
+            # all the direct path asks of the domain: a pinned dictionary
+            # that grows past the cap serves the same program
+            return d.domain(DIRECT_GROUPBY_MAX_DOMAIN)
         t = batch.schema[e.name]
         if t.kind is TypeKind.BOOL:
             return 2
@@ -3718,6 +3720,14 @@ class PreparedPlan(Dispatchable):
         # prepare(); restored from ArtifactMeta on warm hydrate) — the
         # estimate half of the operator profiler's calibration pairs
         self.node_estimates: dict[int, int] = {}
+        # what this plan's traces read of each dictionary lineage (the
+        # `DictPin`s of its calls share it): a dictionary that grew serves
+        # the compiled programs unless they read its strings
+        self._dict_deps: dict = {}
+        # an AOT-hydrated executable's (key function, tables, key it was
+        # exported under): its output dictionaries are the exported ones,
+        # right while the key reads the same
+        self._warm_key: tuple | None = None
 
     def recompile(self) -> None:
         """Refresh the jitted executable after a capacity/spec change.
@@ -3746,7 +3756,7 @@ class PreparedPlan(Dispatchable):
 
     def _inputs(self):
         try:
-            return {
+            inputs = {
                 alias: self.executor.input_batch(alias, table, cols)
                 for alias, table, cols in self.input_spec
             }
@@ -3755,10 +3765,20 @@ class PreparedPlan(Dispatchable):
             # recompile (spec re-detection drops the fast path) and
             # assemble again
             self.recompile()
-            return {
-                alias: self.executor.input_batch(alias, table, cols)
-                for alias, table, cols in self.input_spec
-            }
+            return self._inputs()
+        w = self._warm_key
+        if w is not None and not self._traceable and w[0](w[1]) != w[2]:
+            # a dictionary grew under an AOT-hydrated executable: one
+            # honest recompile, as for any drift
+            self.recompile()
+            return self._inputs()
+        return inputs
+
+    def pinned(self, inputs) -> dict:
+        """`inputs` as this plan's programs are called with them: their
+        dictionaries pinned (`pin_dicts`), so a string appended to a
+        column traces nothing again unless a program read the strings."""
+        return pin_dicts(inputs, self._dict_deps)
 
     def jit_call(self, inputs, qparams):
         """Every dispatch funnels through here. A warm (artifact-loaded)
@@ -3768,11 +3788,14 @@ class PreparedPlan(Dispatchable):
         never a stale program over wrong-shaped buffers."""
         from .plan_artifact import ArtifactStale
 
+        inputs = self.pinned(inputs)
         try:
-            return self.jitted(inputs, qparams)
+            out, ovf_vec = self.jitted(inputs, qparams)
         except ArtifactStale:
             self.recompile()
-            return self.jitted(self._inputs(), qparams)
+            inputs = self.pinned(self._inputs())
+            out, ovf_vec = self.jitted(inputs, qparams)
+        return unpin_dicts(out, inputs), ovf_vec
 
     def dispatch(self, qparams: tuple = (), max_retries: int = 3,
                  fused: bool = True) -> "DeviceResult":
@@ -3895,7 +3918,7 @@ class PreparedPlan(Dispatchable):
             # inputs before the executable: assembling them re-proves the
             # clustered premises and may recompile, which drops every
             # narrow executable built on the old program
-            inputs = self._inputs()
+            inputs = self.pinned(self._inputs())
             fn = self._narrow.get(ncap)
             if fn is None:
                 if not self._traceable:
@@ -3903,7 +3926,7 @@ class PreparedPlan(Dispatchable):
                     # a fresh jit — one honest recompile restores
                     # traceability (the backend hits the XLA disk cache)
                     self.recompile()
-                    inputs = self._inputs()
+                    inputs = self.pinned(self._inputs())
                 # build + first-trace under the lock: tracing re-enters
                 # plan emission's process-global parameter frame, exactly
                 # like the batched buckets
@@ -3913,14 +3936,15 @@ class PreparedPlan(Dispatchable):
                         fn = self._build_narrow(ncap)
                         self.executor.narrow_compiles += 1
                         try:
-                            res = fn(inputs, qparams)
+                            out, ovf_vec, novf = fn(inputs, qparams)
                         except ArtifactStale:
                             self.recompile()
                             continue
                         self._narrow[ncap] = fn
-                        return res
+                        return unpin_dicts(out, inputs), ovf_vec, novf
             try:
-                return fn(inputs, qparams)
+                out, ovf_vec, novf = fn(inputs, qparams)
+                return unpin_dicts(out, inputs), ovf_vec, novf
             except ArtifactStale:
                 self._narrow.pop(ncap, None)
                 self.recompile()
@@ -3955,7 +3979,7 @@ class PreparedPlan(Dispatchable):
         for attempt in range(max_retries + 1):
             checkpoint()
             # inputs before the executable, as in _run_narrow
-            inputs = self._inputs()
+            inputs = self.pinned(self._inputs())
             fn = self._batched.get(bucket)
             if fn is None and not self._traceable:
                 # warm (artifact-loaded) plan: vmap over a deserialized
@@ -3970,7 +3994,7 @@ class PreparedPlan(Dispatchable):
                     self._batched[bucket] = fn
                 else:
                     self.recompile()
-                    inputs = self._inputs()
+                    inputs = self.pinned(self._inputs())
             if fn is None:
                 # build + first-trace under the lock: tracing re-enters
                 # plan emission, which installs the process-global active
@@ -4005,7 +4029,8 @@ class PreparedPlan(Dispatchable):
                 (ovf_vec, out.cols, out.valid, out.sel))
             overflows = self._overflows(np.asarray(hovf).max(axis=0))
             if not overflows:
-                return hcols, hvalid, hsel, out.schema, out.dicts
+                return (hcols, hvalid, hsel, out.schema,
+                        unpin_dicts(out, inputs).dicts)
             self._grow(overflows, attempt, max_retries)
         raise AssertionError
 

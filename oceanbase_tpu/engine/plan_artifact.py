@@ -709,9 +709,10 @@ class PlanArtifactStore:
         """Rebuild a live PreparedPlan from one artifact. Returns
         (meta, prepared) or None; every rejection bumps its own counter
         and the caller falls back to a clean compile. `key_extra_fn`
-        (boot path) re-derives the schema/dict-version key material and
-        rejects on mismatch — schema-bump invalidation semantics are
-        identical to the in-memory tiers."""
+        re-derives the schema/dict-version key material and rejects on
+        mismatch — schema-bump invalidation semantics are identical to the
+        in-memory tiers — and the plan recompiles once it reads otherwise
+        (a dictionary grown under the exported executable)."""
         if not self.readable:
             return None
         with self._lock:
@@ -787,6 +788,9 @@ class PlanArtifactStore:
             return None
         prepared.jitted = warm
         prepared._traceable = False
+        if key_extra_fn is not None:
+            prepared._warm_key = (key_extra_fn, meta.tables,
+                                  meta.art_key[4])
         prepared.artifact_ref = (self, aid)
         prepared._art_proto = meta.out_proto
         prepared.node_estimates = dict(

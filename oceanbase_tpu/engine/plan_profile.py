@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.column import unpin_dicts
 from .executor import (
     ROOT_COMPACT,
     PreparedPlan,
@@ -412,7 +413,10 @@ def run_profiled(prepared, qparams=()):
     seg = getattr(prepared, "_segmented", None)
     if seg is None or seg.stale(prepared):
         seg = prepared._segmented = SegmentedPlan(prepared)
-    return seg.run(inputs, qparams)
+    # the stages are the plan's programs too: called as it calls them
+    inputs = prepared.pinned(inputs)
+    out, ovf_vec, samples = seg.run(inputs, qparams)
+    return unpin_dicts(out, inputs), ovf_vec, samples
 
 
 def profile_eligible(prepared) -> bool:

@@ -11,8 +11,9 @@ snapshot watermark, so a repeat serves decoded host columns with ZERO
 device dispatches.
 
 Identity = (logical entry key, bound literal values, snapshot watermark):
-- the logical key embeds schema + dictionary versions via key_extra, so a
-  schema bump or dictionary growth changes the key (never a stale serve);
+- the logical key embeds schema versions via key_extra, so a schema bump
+  changes the key (a dictionary that grew decodes the same strings: the
+  entry holds decoded columns);
 - the watermark is the referenced tables' committed data versions (the
   server wires it), so committed DML changes the key;
 - DML/flush additionally REMOVE entries eagerly (invalidate_tables /

@@ -151,7 +151,7 @@ class _FastHit:
     fe: FastEntry
     values: list
     entry: CacheEntry
-    # logical cache key of the entry (embeds schema/dict versions via
+    # logical cache key of the entry (embeds schema versions via
     # key_extra) — the result cache reuses it as its identity base
     key: tuple | None = None
 
@@ -175,8 +175,7 @@ class Session:
         # not per-session: ob_plan_cache.h:227)
         self.plan_cache = plan_cache if plan_cache is not None else PlanCache()
         # hook: extra cache-key material per referenced table set (the
-        # DML-backed catalog keys entries on table dict versions, since
-        # string literals bake dictionary lookups at trace time)
+        # DML-backed catalog keys entries on table schema versions)
         self.key_extra_fn = key_extra_fn
         # hook: ob_enable_plan_cache (a disabled cache compiles every time)
         self.cache_enabled_fn = cache_enabled_fn
@@ -206,6 +205,9 @@ class Session:
         # hook: tables -> snapshot watermark tuple (the server supplies
         # per-table committed data versions; staleness = key mismatch)
         self.result_watermark_fn = None
+        # hook: key material of an exported artifact (key_extra_fn's
+        # when unset; the server adds the dictionary versions)
+        self.artifact_extra_fn = None
 
     def materialize(self, text: str, name: str) -> Table:
         """Run a SELECT and materialize its result as a storage-domain
@@ -312,11 +314,10 @@ class Session:
     def result_cache_key(self, hit: "_FastHit"):
         """Result-cache identity for a fast hit, or None when the
         statement is uncacheable (not a SELECT, cache off, unhashable
-        literal values). The key embeds the logical entry key (schema +
-        dictionary versions ride key_extra) plus the bound literals and
-        the referenced tables' snapshot watermark — any committed DML,
-        schema bump or dict growth changes the key instead of serving a
-        stale frame."""
+        literal values). The key embeds the logical entry key (schema
+        versions ride key_extra) plus the bound literals and the
+        referenced tables' snapshot watermark — any committed DML or
+        schema bump changes the key instead of serving a stale frame."""
         rc = self.result_cache
         if rc is None or not rc.enabled() or hit.key is None:
             return None
@@ -491,11 +492,11 @@ class Session:
         """Restart-stable identity of a compiled artifact: the logical
         cache key minus process-local ids — id(catalog) drops (the store
         is scoped per database), and a PX override contributes its shard
-        count instead of its executor's object id. The schema/dict
-        versions in `extra` still invalidate exactly like the in-memory
-        key."""
-        extra = self.key_extra_fn(tables) if self.key_extra_fn is not None \
-            else ()
+        count instead of its executor's object id. `extra` is
+        `artifact_extra_fn`'s where set: the schema and dictionary
+        versions an exported executable was made under."""
+        fn = self.artifact_extra_fn or self.key_extra_fn
+        extra = fn(tables) if fn is not None else ()
         tag: tuple = ()
         if executor is not None and executor is not self.executor:
             nsh = getattr(executor, "nsh", 0)
@@ -607,7 +608,9 @@ class Session:
             if tl is not None:
                 tl.leaf("plan compile")
             t0 = time.perf_counter()
-            got = art_store.hydrate(art_store.key_id(art_key), ex)
+            got = art_store.hydrate(
+                art_store.key_id(art_key), ex,
+                key_extra_fn=self.artifact_extra_fn or self.key_extra_fn)
             if tl is not None:
                 tl.leaf_end()
             if got is not None:

@@ -31,7 +31,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..core.dictionary import Dictionary
+from ..core.dictionary import Dictionary, SortedViews
 from ..core.dtypes import DataType, Field, Schema, TypeKind
 from ..core.table import Table
 from ..log.palf import leader_of as _leader_of
@@ -130,10 +130,8 @@ class TableInfo:
     # its cleanup, a bulk writer from its version to its bump)
     last_commit_version: int = 0
     writers: int = 0
-    # per-column (dict length at build time, sorted Dictionary, remap array)
-    _sorted_cache: dict[str, tuple[int, Dictionary, np.ndarray]] = field(
-        default_factory=dict
-    )
+    # per-column sorted views of `dicts`, kept as they grow
+    _sorted_views: dict[str, SortedViews] = field(default_factory=dict)
 
     # per-column dictionary length already carried by COMMITTED records:
     # codes beyond this must ride the next commit's dict_appends (codes
@@ -161,19 +159,48 @@ class TableInfo:
         return tuple(sorted((c, len(d)) for c, d in self.dicts.items()))
 
     def sorted_dict(self, col: str) -> tuple[Dictionary, np.ndarray]:
-        """Sorted view + old-code -> sorted-code remap, cached per length.
+        """Sorted view + old-code -> sorted-code remap of one snapshot of
+        the column's dictionary, taken once: a view as long as the
+        dictionary, or longer (another session extended it meanwhile).
 
-        Returning the SAME Dictionary object while the length is unchanged
-        matters: dictionaries are static metadata of device batches, so a
-        stable object keeps the jit cache warm across data refreshes."""
+        Returning the SAME Dictionary object while nothing was appended
+        keeps the device-cache entries and the plans built on it; a grown
+        dictionary's view is the last one with the new strings placed by
+        bisection (span `dict view`, counters `dict view inserts` and
+        `dict view sorts`, the latter only for a column's first view)."""
         d = self.dicts[col]
-        hit = self._sorted_cache.get(col)
-        if hit is not None and hit[0] == len(d):
-            return hit[1], hit[2]
-        codes = np.arange(len(d), dtype=np.int32)
-        sd, remap = d.finalize_sorted(codes)
-        self._sorted_cache[col] = (len(d), sd, remap)
-        return sd, remap
+        sv = self._sorted_views.get(col)
+        if sv is None or sv.source is not d:
+            sv = self._sorted_views.setdefault(col, SortedViews(d))
+            if sv.source is not d:  # the column's dictionary was replaced
+                sv = self._sorted_views[col] = SortedViews(d)
+        n = len(d)
+        view = sv.current(n)
+        if view is not None:
+            return view
+        with _GL.span("dict view") as sp:
+            view, how, placed = sv.extend_to(n)
+            if how == "sort":
+                sp.count("dict view sorts")
+            elif how == "insert":
+                sp.count("dict view inserts", placed)
+        return view
+
+    def remap_sorted(self, data: dict) -> dict[str, Dictionary]:
+        """Rewrite the append-order codes of a scan's dict columns in
+        `data` to sorted codes, in place; the sorted dictionaries. A view
+        shorter than the codes it is applied to is a fault, never masked."""
+        dicts = {}
+        for col in self.dicts:
+            sd, remap = self.sorted_dict(col)
+            codes = data[col]
+            if len(codes):
+                assert int(codes.max()) < len(remap), (
+                    f"{self.name}.{col}: code {int(codes.max())} past a "
+                    f"sorted view of {len(remap)}")
+                data[col] = remap[codes]
+            dicts[col] = sd
+        return dicts
 
 
 class TxCatalog(dict):
@@ -782,6 +809,7 @@ class Database:
             tracer=self.tracer,
             profile_enabled_fn=lambda: self.config["enable_query_profile"],
         )
+        self.engine.artifact_extra_fn = self._artifact_extra
         # workload access heat folds per execution inside the engine
         self.engine.access = self.access
         # sampled per-operator profiling decisions + calibration folds
@@ -1391,16 +1419,27 @@ class Database:
         return self._px_admission_obj
 
     def _key_extra(self, table_names: tuple[str, ...]) -> tuple:
-        """Plan-cache key material: schema + dictionary versions of the
-        referenced DML-backed tables (string literals bake dictionary
-        lookups at trace time; a grown dictionary needs a fresh trace)."""
+        """Plan-cache key material: schema versions of the referenced
+        DML-backed tables. A grown dictionary keeps the entry: its
+        programs trace again only where they read the strings (string
+        literals bake dictionary lookups at trace time; `DictPin`)."""
         out = []
         tables = self.tables
         for t in table_names:
             ti = tables.get(t)
             if ti is not None:
-                out.append((t, ti.schema_version, ti.dict_sig))
+                out.append((t, ti.schema_version))
         return tuple(out)
+
+    def _artifact_extra(self, table_names: tuple[str, ...]) -> tuple:
+        """Plan-artifact key material: schema and dictionary versions. An
+        exported executable hands back the dictionaries it was exported
+        with, so it serves those versions only."""
+        tables = self.tables
+        return tuple(
+            (t, ti.schema_version, ti.dict_sig)
+            for t in table_names
+            if (ti := tables.get(t)) is not None)
 
     def _result_watermark(self, table_names) -> tuple:
         """Result-cache key material: the referenced tables' committed
@@ -1475,7 +1514,7 @@ class Database:
                     continue
                 if getattr(ex, "nsh", 0) != meta.px_nsh:
                     continue  # mesh shape moved; entry stays for ro tools
-            got = store.hydrate(aid, ex, key_extra_fn=self._key_extra,
+            got = store.hydrate(aid, ex, key_extra_fn=self._artifact_extra,
                                 meta=meta)
             if got is None:
                 continue
@@ -2049,12 +2088,7 @@ class Database:
                 c: np.concatenate([p[c] for p in parts])
                 for c in parts[0]
             }
-        dicts = {}
-        for col in ti.dicts:
-            sd, remap = ti.sorted_dict(col)
-            if len(data[col]):
-                data[col] = remap[data[col]]
-            dicts[col] = sd
+        dicts = ti.remap_sorted(data)
         return Table(name, ti.schema, data, dicts)
 
     def refresh_catalog(self, names, tx=None) -> None:
@@ -2098,12 +2132,7 @@ class Database:
                         c: np.concatenate([p[c] for p in parts])
                         for c in parts[0]
                     }
-                dicts = {}
-                for col in ti.dicts:
-                    sd, remap = ti.sorted_dict(col)
-                    if len(data[col]):
-                        data[col] = remap[data[col]]
-                    dicts[col] = sd
+                dicts = ti.remap_sorted(data)
                 for f in ti.schema.fields:
                     # tablet cells store vectors as tuples, so the scan
                     # yields a 1-D object column; every downstream
@@ -2428,12 +2457,7 @@ class Database:
             data = {
                 c: np.concatenate([p[c] for p in parts]) for c in parts[0]
             }
-        dicts = {}
-        for col in ti.dicts:
-            sd, remap = ti.sorted_dict(col)
-            if len(data[col]):
-                data[col] = remap[data[col]]
-            dicts[col] = sd
+        dicts = ti.remap_sorted(data)
         t = Table(name, ti.schema, data, dicts)
         while len(self._follower_views) >= self._FOLLOWER_VIEW_CACHE_MAX:
             self._follower_views.pop(next(iter(self._follower_views)))
@@ -4404,8 +4428,10 @@ class DbSession:
         host index path instead of materializing the whole table to the
         device. Returns a statement-scoped {table: pruned Table} view, or
         None to fall back to the full-scan path. Inside a transaction it
-        reads at the BEGIN snapshot, and only a table the transaction has
-        not written (its staged rows live in the rescan's private view)."""
+        reads at the BEGIN snapshot; of a table the transaction has written
+        only by the full primary key, the row its own staged writes leave
+        (the replica they went to, read with its tx id), as the rescan's
+        private view would show it."""
         if not isinstance(ast, A.Select) or len(ast.from_) != 1:
             return None
         tref = ast.from_[0]
@@ -4419,8 +4445,7 @@ class DbSession:
         if ti is None or ast.where is None:
             return None
         tx = self._tx
-        if tx is not None and tref.name in tx.touched_tables:
-            return None
+        touched = tx is not None and tref.name in tx.touched_tables
         alias = tref.alias or tref.name
         from ..sql.planner import split_ast_conjuncts
 
@@ -4465,8 +4490,15 @@ class DbSession:
         if set(ti.key_cols) <= set(eqs):
             pk = tuple(int(eqs[k]) for k in ti.key_cols)
             pls, ptab = ti.partition_for_key(pk)
-            hit = self.db._leader_replica_ls(pls).tablets[ptab].get(pk, snap)
+            if touched:
+                hit = tx.svc.replicas[pls].tablets[ptab].get(
+                    pk, snap, tx.ctx.tx_id)
+            else:
+                hit = self.db._leader_replica_ls(pls).tablets[ptab].get(
+                    pk, snap)
             rows = [hit[1]] if hit is not None else []
+        elif touched:
+            return None
         else:
             best = None
             for idx in ti.indexes.values():
@@ -4513,12 +4545,7 @@ class DbSession:
             c: np.array([r[j] for r in rows], dtype=ti.schema[c].storage_np)
             for j, c in enumerate(names)
         }
-        dicts = {}
-        for col in ti.dicts:
-            sd, remap = ti.sorted_dict(col)
-            if len(data[col]):
-                data[col] = remap[data[col]]
-            dicts[col] = sd
+        dicts = ti.remap_sorted(data)
         if used_idx is not None:
             used_idx.reads += 1
         if self.db.access.enabled:
@@ -4640,7 +4667,11 @@ class DbSession:
                 rs = self.db.engine.run_ast(ast, norm_key)
             self._scan_rs = rs
             if in_tx:
-                self.db.metrics.add("tx snapshot shared reads")
+                # a written table's row carries the transaction's own writes
+                self.db.metrics.add(
+                    "tx snapshot private reads"
+                    if any(n in tx.touched_tables for n in route)
+                    else "tx snapshot shared reads")
             return rs
         shared = {}
         if shared_ok:
@@ -4851,7 +4882,8 @@ class DbSession:
                         if d is not None:
                             d.check()  # unwind before staging the decision
                             max_wait = min(max_wait, d.remaining())
-                        with m.waiting("tx commit log sync"):
+                        with m.waiting("tx commit log sync"), \
+                                _GL.span("commit wait"):
                             try:
                                 self.db.cluster.commit_sync(
                                     tx.svc, tx.ctx, max_time=max_wait)
@@ -4860,7 +4892,9 @@ class DbSession:
                                     f"commit wait timed out: {te}"
                                 ) from te
                     else:
-                        tx.svc.commit(tx.ctx)  # empty tx: finishes immediately
+                        # an empty tx finishes at once: its wait is ~0
+                        with _GL.span("commit wait"):
+                            tx.svc.commit(tx.ctx)
                 except Exception:
                     # commit failed before a decision was logged: abort so the
                     # staged rows don't stay undecided forever (which would
@@ -5130,7 +5164,9 @@ class DbSession:
             from_=(A.TableRef(ti.name),),
             where=st.where,
         )
-        return self._select(sel, _norm_stmt(f"$dml:{ti.name}", st))
+        # keyed by the scan it runs: a constant assignment (`SET c='...'`,
+        # evaluated on the host) is no part of it
+        return self._select(sel, _norm_stmt(f"$dml:{ti.name}", sel))
 
     def _update(self, st: A.Update, tx: _OpenTx) -> int:
         ti = self.db.tables.get(st.table)
@@ -5278,7 +5314,8 @@ _LIT_MASK_RE = None
 
 
 def _norm_stmt(tag: str, st) -> str:
-    """Literal-normalized cache key for a generated DML qualification scan.
+    """Literal-normalized cache key for a generated DML qualification scan
+    (`st` the SELECT it runs).
 
     Numeric/date literals become runtime parameters during parameterize(),
     so masking them here lets point UPDATE/DELETE loops share one compiled

@@ -516,7 +516,7 @@ class span:
     engine can carve an upload out of the phase it times around it.
     Outside a statement two clock reads, nothing else."""
 
-    __slots__ = ("phase", "led", "back", "clock", "t0")
+    __slots__ = ("phase", "led", "back", "clock", "t0", "outer")
 
     def __init__(self, phase: str):
         self.phase = phase
@@ -532,11 +532,18 @@ class span:
             self.back = led._ann_phase
             led._mark(self.phase)
         self.clock = led.clock if led is not None else time.perf_counter
+        # seconds of the spans nested in the one this opens inside
+        self.outer = getattr(_tls, "nested_s", 0.0)
+        _tls.nested_s = 0.0
         self.t0 = self.clock()
         return self
 
     def __exit__(self, *_exc) -> bool:
-        dt = self.clock() - self.t0
+        wall = self.clock() - self.t0
+        # a span nested in this one (`dict view` inside `catalog
+        # refresh`) has its own phase: this one keeps the rest
+        dt = wall - _tls.nested_s
+        _tls.nested_s = self.outer + wall
         if self.phase == "h2d":
             _tls.h2d_s = getattr(_tls, "h2d_s", 0.0) + dt
         led = self.led

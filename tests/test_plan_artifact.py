@@ -214,3 +214,28 @@ def test_store_flush_forgets_artifacts(tmp_path):
     assert rows == rows0
     assert compiles == 1  # nothing hydrates back after the flush
     db.close()
+
+
+def test_a_dictionary_grown_after_warm_boot_recompiles(tmp_path):
+    """A hydrated executable hands back the dictionaries it was exported
+    with: after a string lands in its table it recompiles once (and is then
+    a traceable program like any other), never decodes through the old."""
+    q = "select id, s from art_s order by s"
+    db = _boot(tmp_path)
+    s = db.session()
+    s.sql("alter system set ob_plan_artifact_mode = 'rw'")
+    s.sql("create table art_s (id bigint primary key, s varchar(8))")
+    s.sql("insert into art_s values (1, 'b'), (2, 'd')")
+    assert s.sql(q).rows() == [(1, "b"), (2, "d")]
+    db._save_node_meta()
+    db.close()
+    db = _boot(tmp_path)
+    assert db.metrics.counters_snapshot().get("plan artifact warm load", 0)
+    s = db.session()
+    assert s.sql(q).rows() == [(1, "b"), (2, "d")]
+    ex = db.engine.executor
+    c0 = ex.compiles
+    s.sql("insert into art_s values (3, 'a'), (4, 'c')")
+    assert s.sql(q).rows() == [(3, "a"), (1, "b"), (4, "c"), (2, "d")]
+    assert ex.compiles - c0 == 1
+    db.close()

@@ -303,3 +303,38 @@ def test_a_commit_in_flight_at_its_end_keeps_its_writer_open(
     s1.sql("commit")
     assert ti.writers == 0 and ti.data_version == v0 + 1
     assert ti.last_commit_version == ctx.commit_version
+
+
+def test_a_written_table_is_read_by_its_primary_key_with_own_writes(db):
+    """After a transaction's first write of a table, a read by the full
+    primary key (a DML's qualification among them) takes the key at the
+    BEGIN snapshot with the transaction's own staged rows over it: no
+    rescan, and the answers the rescan gave."""
+    s1, s2 = db.session(), db.session()
+    (k8, c8), = s2.sql("select k, c from sr_t where id = 8").rows()
+    (c9,), = s2.sql("select c from sr_t where id = 9").rows()
+    s1.sql("begin")
+    s1.sql("update sr_t set c = 'mine' where id = 8")
+    s2.sql("update sr_t set c = 'theirs' where id = 9")  # after the BEGIN
+    c0 = _counts(db)
+    scans0 = db.metrics.counter("catalog refreshes")
+    assert s1.sql("select c from sr_t where id = 8").rows() == [("mine",)]
+    assert s1.sql("select c from sr_t where id = 9").rows() == [(c9,)]
+    assert s1.sql("update sr_t set k = k + 1 where id = 8").affected == 1
+    assert s1.sql("select k, c from sr_t where id = 8").rows() == [
+        (k8 + 1, "mine")]
+    s1.sql("delete from sr_t where id = 8")
+    assert s1.sql("select c from sr_t where id = 8").rows() == []
+    assert s1.sql("update sr_t set k = 0 where id = 8").affected == 0
+    s1.sql("insert into sr_t values (8, 7, 'again')")
+    assert s1.sql("select k, c from sr_t where id = 8").rows() == [
+        (7, "again")]
+    # eight reads by the key (three of them qualifications), none rescanned
+    assert _moved(db, c0) == (0, 8)
+    assert db.metrics.counter("catalog refreshes") == scans0
+    # first committer wins: the key another session changed after BEGIN
+    with pytest.raises(Exception, match="modified at .* > snapshot"):
+        s1.sql("update sr_t set k = 1 where id = 9")
+    s1.sql("rollback")
+    s2.sql(f"update sr_t set c = '{c9}' where id = 9")
+    assert s2.sql("select k, c from sr_t where id = 8").rows() == [(k8, c8)]
