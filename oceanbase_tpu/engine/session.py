@@ -462,6 +462,29 @@ class Session:
         # re-trace + re-compile the plan (review finding)
         return entry, entry.prepared.bind(pz.values, entry.dtypes)
 
+    def prepare_shapes(self, ast, norm_key: str) -> bool:
+        """Trace and compile the programs the statement's cached plan runs
+        over the inputs the catalog resolves to now (a transaction's
+        scoped view): its dispatch and, where the plan profiles, the
+        stages of a sampled run. What they return is dropped. False where
+        no plan is cached for the statement yet."""
+        from . import plan_profile as _PP
+
+        planned = self.planner.plan(ast)
+        pz = parameterize(planned.plan)
+        entry = self.plan_cache.get(self._cache_key(norm_key, pz))
+        if entry is None:
+            return False
+        prepared = entry.prepared
+        qparams = prepared.bind(pz.values, entry.dtypes)
+        _ = prepared.dispatch(qparams).nrows
+        pp = self.plan_profiler
+        if pp is not None and pp.enabled and _PP.profile_eligible(prepared):
+            import jax
+
+            jax.block_until_ready(_PP.run_profiled(prepared, qparams)[0])
+        return True
+
     def _cache_key(self, norm_key: str, pz, executor=None) -> tuple:
         return self._key_parts(norm_key, pz, executor)[0]
 

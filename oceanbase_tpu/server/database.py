@@ -455,6 +455,11 @@ class Database:
         # flight at their end (writers_end): open until settle_writers
         # sees it land
         self._undecided: list = []
+        # DbSession._prepare_range_shapes, per statement text: True once
+        # its plan is compiled over a range-route-sized table (or no key
+        # range can answer it, or no plan was cached at a second try),
+        # False after a first try found no plan cached
+        self.range_shapes: dict[str, bool] = {}
         # placeholder entries for restored tables (create_table provides
         # one on the DDL path): the resolver requires every table in the
         # shared catalog even when the first statement reads it through a
@@ -2133,19 +2138,7 @@ class Database:
                         for c in parts[0]
                     }
                 dicts = ti.remap_sorted(data)
-                for f in ti.schema.fields:
-                    # tablet cells store vectors as tuples, so the scan
-                    # yields a 1-D object column; every downstream
-                    # consumer (IVF build, route costing, H2D upload, mesh
-                    # sharding) wants the dense (n, d) float32 form —
-                    # normalize once
-                    if f.dtype.kind is TypeKind.VECTOR:
-                        a = data[f.name]
-                        dim = int(f.dtype.precision)
-                        data[f.name] = (
-                            np.asarray(a.tolist(), dtype=np.float32)
-                            .reshape(len(a), dim)
-                            if len(a) else np.zeros((0, dim), np.float32))
+                _dense_vectors(ti.schema, data)
                 t = Table(name, ti.schema, data, dicts)
                 sp.count("catalog refreshes")
                 sp.count("catalog refresh rows", t.nrows)
@@ -4421,17 +4414,10 @@ class DbSession:
     # ------------------------------------------------------------ select
     _INDEX_ROUTE_MAX_ROWS = 4096
 
-    def _index_route(self, ast: A.Select) -> dict[str, Table] | None:
-        """DAS index/PK lookup analog (src/sql/das/iter): a single-table
-        statement whose WHERE pins an index prefix (or the full primary
-        key) with equality literals reads the few matching rows through the
-        host index path instead of materializing the whole table to the
-        device. Returns a statement-scoped {table: pruned Table} view, or
-        None to fall back to the full-scan path. Inside a transaction it
-        reads at the BEGIN snapshot; of a table the transaction has written
-        only by the full primary key, the row its own staged writes leave
-        (the replica they went to, read with its tx id), as the rescan's
-        private view would show it."""
+    def _route_table(self, ast) -> "tuple[A.TableRef, TableInfo] | None":
+        """(FROM's table reference, its table) of a single-table SELECT
+        over a served table with a WHERE and no subquery or CTE: the
+        statements the index and range routes may read a few rows for."""
         if not isinstance(ast, A.Select) or len(ast.from_) != 1:
             return None
         tref = ast.from_[0]
@@ -4444,6 +4430,23 @@ class DbSession:
         ti = self.db.tables.get(tref.name)
         if ti is None or ast.where is None:
             return None
+        return tref, ti
+
+    def _index_route(self, ast: A.Select) -> dict[str, Table] | None:
+        """DAS index/PK lookup analog (src/sql/das/iter): a single-table
+        statement whose WHERE pins an index prefix (or the full primary
+        key) with equality literals reads the few matching rows through the
+        host index path instead of materializing the whole table to the
+        device. Returns a statement-scoped {table: pruned Table} view, or
+        None to fall back to the full-scan path. Inside a transaction it
+        reads at the BEGIN snapshot; of a table the transaction has written
+        only by the full primary key, the row its own staged writes leave
+        (the replica they went to, read with its tx id), as the rescan's
+        private view would show it."""
+        got = self._route_table(ast)
+        if got is None:
+            return None
+        tref, ti = got
         tx = self._tx
         touched = tx is not None and tref.name in tx.touched_tables
         alias = tref.alias or tref.name
@@ -4554,6 +4557,106 @@ class DbSession:
             self.db.access.record_das(tref.name, len(rows))
         return {tref.name: Table(tref.name, ti.schema, data, dicts)}
 
+    def _range_bounds(self, ast) -> "tuple[str, TableInfo, int, int] | None":
+        """(table, its info, lo, hi) where a single-table statement's WHERE
+        bounds a single-column integer primary key on both sides with
+        literals (BETWEEN, or a pair of >= > <= <): the statements the
+        range route reads; bounds inclusive, lo > hi where none lies
+        between them."""
+        got = self._route_table(ast)
+        if got is None:
+            return None
+        tref, ti = got
+        if len(ti.key_cols) != 1 or not ti.schema[ti.key_cols[0]].is_integer:
+            return None
+        from ..sql.planner import split_ast_conjuncts
+
+        pk = ti.key_cols[0]
+        alias = tref.alias or tref.name
+        lo = hi = None
+        for c in split_ast_conjuncts(ast.where):
+            b = _pk_bounds(c, pk, alias)
+            if b is None:
+                continue
+            if b[0] is not None:
+                lo = b[0] if lo is None else max(lo, b[0])
+            if b[1] is not None:
+                hi = b[1] if hi is None else min(hi, b[1])
+        if lo is None or hi is None:
+            return None
+        # past the column's int64: none of its keys (lo > hi reads none)
+        return tref.name, ti, max(lo, _INT64.min), min(hi, _INT64.max)
+
+    def _range_route(self, ast: A.Select) -> dict[str, Table] | None:
+        """A primary-key range read at a transaction's BEGIN snapshot: a
+        statement `_range_bounds` accepts reads only the tablet rows of
+        its key range, with the transaction's own staged rows over them
+        exactly as the rescan's private view shows them. The whole WHERE
+        still runs on the device over the few rows. Returns a
+        statement-scoped {table: Table} view, or None where the rescan
+        answers: no such range, or more than _INDEX_ROUTE_MAX_ROWS rows
+        in it."""
+        got = self._range_bounds(ast)
+        if got is None:
+            return None
+        name, ti, lo, hi = got
+        pk = ti.key_cols[0]
+        tx = self._tx
+        db = self.db
+        with _GL.span("range route"):
+            db.settle_writers()
+            # the replica and tx id the rescan reads (refresh_catalog)
+            touched = name in tx.touched_tables
+            tx_id = tx.ctx.tx_id if touched else 0
+            snap = tx.ctx.read_snapshot
+            ranges = {pk: (float(lo), float(hi))}
+            parts = []
+            for ls_id, tablet_id in ti.all_partitions():
+                rep = (tx.svc.replicas[ls_id] if touched
+                       else db._leader_replica_ls(ls_id))
+                parts.append(rep.tablets[tablet_id].scan(
+                    snap, ranges=ranges, tx_id=tx_id))
+            data = {c: np.concatenate([p[c] for p in parts])
+                    for c in parts[0]}
+            # the exact bound in integers: the scan's float bounds round
+            # past 2**53
+            k = data[pk]
+            inside = (k >= lo) & (k <= hi)
+            if not inside.all():
+                data = {c: a[inside] for c, a in data.items()}
+            if len(data[pk]) > self._INDEX_ROUTE_MAX_ROWS:
+                return None
+            _dense_vectors(ti.schema, data)
+            t = Table(name, ti.schema, data, ti.remap_sorted(data))
+        return {name: t}
+
+    def _prepare_range_shapes(self, ast: A.Select, norm_key: str) -> None:
+        """Once per statement text the range route could answer, from its
+        second execution on (its plan cached by the first): compile that
+        plan's programs over a table of the route's size too, whichever
+        route answers now, so whether a table has open writers never
+        decides a compile."""
+        done = self.db.range_shapes
+        tried = done.get(norm_key)
+        if tried:
+            return
+        got = self._range_bounds(ast)
+        if got is None:
+            done[norm_key] = True
+            return
+        name, ti = got[:2]
+        # no rows: the program of every route read up to 1,024 rows
+        data = {f.name: np.zeros(0, f.dtype.storage_np)
+                for f in ti.schema.fields}
+        _dense_vectors(ti.schema, data)
+        t = Table(name, ti.schema, data, ti.remap_sorted(data))
+        try:
+            with self.db.catalog.tx_scope({name: t}):
+                ok = self.db.engine.prepare_shapes(ast, norm_key)
+        except Exception:  # noqa: BLE001 - a compile ahead of need
+            ok = True  # never fails the statement; not tried again
+        done[norm_key] = ok or tried is False
+
     def _follower_select(self, ast: A.Select, norm_key: str,
                          names) -> "ResultSet | None":
         """Serve a non-strong SELECT from follower replicas: statement-
@@ -4655,6 +4758,8 @@ class DbSession:
         shared_ok = (in_tx and not any_vt
                      and self._vars.get("ob_px_dop", 0) == 0
                      and isinstance(ast, A.Select))
+        if shared_ok:
+            self._prepare_range_shapes(ast, norm_key)
         route = None
         if shared_ok or (tx is None and not any_vt
                          and isinstance(ast, A.Select)):
@@ -4696,6 +4801,16 @@ class DbSession:
             # an entry was replaced, or the table written, while the
             # statement ran: its answer is thrown away, the rescan below
             # answers instead
+        if shared_ok:
+            # the alternative below is a rescan of the whole table at the
+            # snapshot: a key range reads its own rows instead
+            route = self._range_route(ast)
+            if route is not None:
+                with self.db.catalog.tx_scope(route):
+                    rs = self.db.engine.run_ast(ast, norm_key)
+                self._scan_rs = rs
+                self.db.metrics.add("tx range route reads")
+                return rs
         self.db.refresh_catalog(names, tx=tx)
         views = tx.views if in_tx else None
         if in_tx and any(n in self.db.tables for n in names):
@@ -5344,6 +5459,68 @@ def apply_dict_appends(by_tab: dict, dict_appends) -> None:
         ti.logged_dict_len[col] = max(
             ti.logged_dict_len.get(col, 0), code + 1
         )
+
+
+def _dense_vectors(schema: Schema, data: dict) -> None:
+    """Tablet cells store vectors as tuples, so a scan yields a 1-D object
+    column; every downstream consumer (IVF build, route costing, H2D
+    upload, mesh sharding) wants the dense (n, d) float32 form: normalize
+    `data` in place, once."""
+    for f in schema.fields:
+        if f.dtype.kind is TypeKind.VECTOR:
+            a = data[f.name]
+            dim = int(f.dtype.precision)
+            data[f.name] = (
+                np.asarray(a.tolist(), dtype=np.float32).reshape(len(a), dim)
+                if len(a) else np.zeros((0, dim), np.float32))
+
+
+_INT64 = np.iinfo(np.int64)
+# a comparison as `<column> op <literal>`: the op with its sides swapped
+_FLIPPED = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}
+
+
+def _pk_bounds(c: A.Node, pk: str, alias: str):
+    """(lo, hi) in integers, either None where open, that conjunct `c`
+    puts on integer column `pk` when it is a BETWEEN or a comparison with
+    literal numbers; None where it bounds nothing. A bound that is not a
+    whole number widens to the next one outward: the statement's own WHERE
+    still decides each row."""
+    import math
+
+    def col(n):
+        return (isinstance(n, A.Name) and n.parts[-1] == pk
+                and (len(n.parts) == 1 or n.parts == (alias, pk)))
+
+    def num(n):
+        try:
+            v = _eval_const(n)
+        except (SqlError, TypeError, ArithmeticError):
+            return None
+        if isinstance(v, bool) or not isinstance(v, (int, float)):
+            return None
+        return v if math.isfinite(v) else None
+
+    if isinstance(c, A.BetweenOp) and not c.negated and col(c.expr):
+        a, b = num(c.low), num(c.high)
+        if a is None or b is None:
+            return None
+        return math.floor(a), math.ceil(b)
+    if not (isinstance(c, A.BinOp) and c.op in _FLIPPED):
+        return None
+    op, lhs, rhs = c.op, c.left, c.right
+    if not col(lhs):
+        op, lhs, rhs = _FLIPPED[op], rhs, lhs
+    if not col(lhs):
+        return None
+    v = num(rhs)
+    if v is None:
+        return None
+    if isinstance(v, int):
+        bound = v + 1 if op == ">" else v - 1 if op == "<" else v
+    else:
+        bound = math.floor(v) if op[0] == ">" else math.ceil(v)
+    return (bound, None) if op[0] == ">" else (None, bound)
 
 
 def _eval_const(node: A.Node):

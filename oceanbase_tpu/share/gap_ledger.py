@@ -75,6 +75,7 @@ PHASE_ORDER = (
     "governor reserve",
     "plan compile",
     "catalog refresh",
+    "range route",
     "param pack",
     "h2d",
     "device dispatch",

@@ -176,11 +176,18 @@ class Memtable:
                     return (node.op, node.values)
             return None
 
-    def snapshot_rows(self, snapshot: int, tx_id: int = 0) -> dict[tuple, tuple[int, tuple]]:
-        """All visible rows at `snapshot` -> {key: (op, values)} (incl. deletes)."""
+    def snapshot_rows(self, snapshot: int, tx_id: int = 0,
+                      key_ranges: list[tuple[int, float, float]] | None = None
+                      ) -> dict[tuple, tuple[int, tuple]]:
+        """All visible rows at `snapshot` -> {key: (op, values)} (incl.
+        deletes); with `key_ranges` ((key position, lo, hi), bounds
+        inclusive) only the keys inside every range."""
         out = {}
         with self._lock:
-            for key, chain in self._rows.items():
+            rows = self._rows.items()
+            for j, lo, hi in key_ranges or ():
+                rows = [(k, c) for k, c in rows if lo <= k[j] <= hi]
+            for key, chain in rows:
                 for node in chain:
                     if (node.tx_id == tx_id and tx_id != 0) or (
                         node.tx_id == 0 and 0 < node.version <= snapshot

@@ -22,9 +22,12 @@ from .sstable import OP_COL, OP_PUT, VERSION_COL, SSTable
 
 
 def _memtable_arrays(
-    mt: Memtable, schema: Schema, snapshot: int, tx_id: int
+    mt: Memtable, schema: Schema, snapshot: int, tx_id: int,
+    key_ranges: dict[str, tuple[float, float]] | None = None,
 ) -> dict[str, np.ndarray]:
-    rows = mt.snapshot_rows(snapshot, tx_id)
+    rows = mt.snapshot_rows(snapshot, tx_id, key_ranges=[
+        (mt.key_cols.index(c), lo, hi) for c, (lo, hi) in key_ranges.items()
+    ] if key_ranges else None)
     names = schema.names()
 
     def _empty(n):
@@ -80,6 +83,11 @@ def scan_merge(
     columns are applied only when exactly one non-empty source exists — with
     deltas present, pruning a base block on a value predicate could hide the
     base version of a key whose delta row fails the predicate.
+
+    Key ranges are exact: memtable rows outside them are never built, and
+    the rows a kept sstable block holds outside them are dropped before the
+    merge, so a key's versions are all in or all out. Value-column ranges
+    only prune: their exact filter is the caller's.
     """
     names = columns if columns is not None else schema.names()
     need = list(dict.fromkeys(list(key_cols) + list(names)))
@@ -100,7 +108,7 @@ def scan_merge(
         ranks.append(np.full(len(got[VERSION_COL]), rank, np.int32))
         rank += 1
     for mt in memtables:
-        got = _memtable_arrays(mt, schema, snapshot, tx_id)
+        got = _memtable_arrays(mt, schema, snapshot, tx_id, key_ranges)
         if need != schema.names():
             got = {c: got[c] for c in need + [VERSION_COL, OP_COL]}
         parts.append(got)
@@ -112,6 +120,13 @@ def scan_merge(
 
     cat = {c: np.concatenate([p[c] for p in parts]) for c in need + [VERSION_COL, OP_COL]}
     rank_arr = np.concatenate(ranks) if ranks else np.zeros(0, np.int32)
+    if key_ranges:
+        inside = np.ones(len(rank_arr), dtype=bool)
+        for c, (lo, hi) in key_ranges.items():
+            inside &= (cat[c] >= lo) & (cat[c] <= hi)
+        if not inside.all():
+            cat = {c: a[inside] for c, a in cat.items()}
+            rank_arr = rank_arr[inside]
     n = len(rank_arr)
     if n == 0:
         return {c: cat[c] for c in names}
