@@ -264,6 +264,17 @@ class VectorTopNSpec:
     cost_basis: str = "flops"  # "measured" when calibration records won
 
 
+def ann_adds(vector_topns: dict, lanes: int) -> tuple:
+    """The counters of `lanes` executions of a plan with IVF probes:
+    `ann probes` (lists probed) and `ann candidate rows` (the rows of the
+    probed windows re-ranked: nprobe x max_list a probe, padding
+    included, the count the program knows without a sync)."""
+    vts = vector_topns.values()
+    return (("ann probes", lanes * sum(v.nprobe for v in vts)),
+            ("ann candidate rows",
+             lanes * sum(v.nprobe * v.max_list for v in vts)))
+
+
 @dataclass(frozen=True)
 class ClusteredAggSpec:
     """One Aggregate-over-PK-FK-join collapsed into segment reductions
@@ -780,7 +791,11 @@ class Executor:
         if hit is not None and hit[0] == v and hit[2]() is arr:
             return hit[1]
         t0 = time.perf_counter()
-        idx = build_ivf(np.asarray(arr), lists=spec.lists)
+        with _gap.span("ivf build") as sp:
+            # the k-means on the device and the permutation
+            idx = build_ivf(np.asarray(arr), lists=spec.lists)
+            sp.count("ivf builds")
+            sp.count("ivf build rows", len(arr))
         # weakref: a strong array ref would double-count host bytes in
         # the device census walk; the catalog holds the array anyway
         self._batch_cache[key] = (v, idx, weakref.ref(arr))

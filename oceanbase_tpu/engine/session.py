@@ -29,7 +29,7 @@ from ..sql.plan_cache import (
     plan_fingerprint,
 )
 from ..sql.planner import Planner
-from .executor import Executor
+from .executor import Executor, ann_adds
 
 
 @dataclass
@@ -970,8 +970,7 @@ class Session:
             params = prepared.params
             vts = getattr(params, "vector_topns", None)
             if vts:
-                m.add("ann probes",
-                      sum(v.nprobe for v in vts.values()))
+                m.bulk(adds=ann_adds(vts, 1))
                 esc = getattr(params, "ann_escalations", 0) - ann0
                 if esc > 0:
                     m.add("ann over-probe escalations", esc)

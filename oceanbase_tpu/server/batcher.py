@@ -67,6 +67,7 @@ from collections import OrderedDict, deque
 
 import numpy as np
 
+from ..engine.executor import ann_adds
 from ..ops.hashing import next_pow2
 from ..share import gap_ledger as _gl
 
@@ -77,6 +78,13 @@ class BatcherShutdown(RuntimeError):
 
 # sentinel error for "group degenerated to one lane — run it solo"
 _SOLO = RuntimeError("solo")
+
+
+def _ann_lanes(prepared, lanes: int) -> tuple:
+    """The IVF probe counters of a cohort's lanes, as the solo route
+    counts them a statement (engine/session.py)."""
+    vts = getattr(prepared.params, "vector_topns", None)
+    return ann_adds(vts, lanes) if vts else ()
 
 
 # Fused-pair executables for bucket-shape coalescing: two DIFFERENT
@@ -800,7 +808,8 @@ class StatementBatcher:
                 (f"stmt batch size {next_pow2(na)}", 1),
                 ("stmt batch coalesced dispatches", 1),
                 ("stmt batch coalesced lanes", na + nr),
-            ))
+            ) + _ann_lanes(b.entry.prepared, na)
+              + _ann_lanes(rb.entry.prepared, nr))
             m.gauge_max("stmt sched queue depth hwm", depth)
         tl = self.timeline
         if tl is not None and tl.enabled:
@@ -847,7 +856,7 @@ class StatementBatcher:
                     ("stmt batched dispatches", 1),
                     ("stmt batched statements", nb),
                     (f"stmt batch size {next_pow2(nb)}", 1),
-                ))
+                ) + _ann_lanes(prepared, nb))
                 m.gauge_max("stmt sched queue depth hwm", depth)
             tl = self.timeline
             if tl is not None and tl.enabled:

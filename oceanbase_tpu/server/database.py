@@ -5462,14 +5462,16 @@ def apply_dict_appends(by_tab: dict, dict_appends) -> None:
 
 
 def _dense_vectors(schema: Schema, data: dict) -> None:
-    """Tablet cells store vectors as tuples, so a scan yields a 1-D object
-    column; every downstream consumer (IVF build, route costing, H2D
-    upload, mesh sharding) wants the dense (n, d) float32 form: normalize
-    `data` in place, once."""
+    """Every consumer of a VECTOR column (IVF build, route costing, H2D
+    upload, mesh sharding) wants the dense (n, d) float32 form, which a
+    tablet scan gives (sstable blocks and memtable rows alike); a column
+    of cells in any other form is normalized in place, once."""
     for f in schema.fields:
         if f.dtype.kind is TypeKind.VECTOR:
             a = data[f.name]
             dim = int(f.dtype.precision)
+            if a.ndim == 2 and a.dtype == np.float32:
+                continue
             data[f.name] = (
                 np.asarray(a.tolist(), dtype=np.float32).reshape(len(a), dim)
                 if len(a) else np.zeros((0, dim), np.float32))

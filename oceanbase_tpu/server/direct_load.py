@@ -71,6 +71,14 @@ def direct_load(db, table_name: str, data: dict[str, object]) -> int:
             if np.issubdtype(arr.dtype, np.floating):
                 arr = np.round(arr * f.dtype.decimal_factor)
             v = arr.astype(f.dtype.storage_np)
+        elif f.dtype.kind is TypeKind.VECTOR:
+            # (n, d) float32, one row a vector, as the sstable stores it
+            v = np.asarray(a, dtype=np.float32)
+            dim = int(f.dtype.precision)
+            if v.ndim != 2 or v.shape[1] != dim:
+                raise DirectLoadError(
+                    f"column {f.name} is VECTOR({dim}): got an array "
+                    f"of shape {v.shape}, not (rows, {dim})")
         else:
             v = np.asarray(a, dtype=f.dtype.storage_np)
         if n is None:

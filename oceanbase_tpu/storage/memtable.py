@@ -25,7 +25,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..core.dtypes import Schema
+from ..core.dtypes import Schema, TypeKind
 from .sstable import OP_DELETE, OP_PUT
 
 
@@ -229,7 +229,10 @@ class Memtable:
                         keys.append(key)
                         rows.append(node)
         if not rows:
-            empty = {n: np.zeros(0, dtype=self.schema[n].storage_np) for n in names}
+            empty = {n: np.zeros((0, int(self.schema[n].precision))
+                                 if self.schema[n].kind is TypeKind.VECTOR
+                                 else 0, dtype=self.schema[n].storage_np)
+                     for n in names}
             return empty, np.zeros(0, np.int64), np.zeros(0, np.int8)
         keys_arr = np.array(keys, dtype=np.int64).reshape(len(rows), -1)
         vers = np.array([r.version for r in rows], dtype=np.int64)
@@ -243,9 +246,13 @@ class Memtable:
             if n in key_idx:
                 data[n] = keys_arr[order, key_idx[n]].astype(dt)
             else:
+                # a tombstone's filler keeps a VECTOR cell's (d,) shape
+                f = self.schema[n]
+                fill = ((0.0,) * int(f.precision)
+                        if f.kind is TypeKind.VECTOR else 0)
                 vals = []
                 for i in order:
                     node = rows[i]
-                    vals.append(node.values[ci] if node.op == OP_PUT else 0)
+                    vals.append(node.values[ci] if node.op == OP_PUT else fill)
                 data[n] = np.asarray(vals, dtype=dt)
         return data, vers, ops

@@ -11,10 +11,15 @@ of the device marshalling boundary — see core/table.py).
 Layout (little-endian):
   u32 magic 0x0B5EB10C | u16 version | u16 ncols | u32 nrows | u32 reserved
   ncols * ColumnDesc {
-     u8 enc | u8 dtype_code | u8 flags(bit0 has_nulls) | u8 for_width
-     i64 for_min
+     u8 enc | u8 dtype_code | u8 flags(bit0 has_nulls, bit1 row width)
+     u8 for_width
+     i64 for_min (with bit1: the values a row holds)
      u32 data_off | u32 data_len | u32 null_off | u32 null_len
   }
+A column of fixed-width rows (a VECTOR(d) column: `(nrows, d)` float32)
+is stored RAW, row after row, with bit1 set and `d` in the `for_min` slot;
+it has no zone map (its zone reads -inf..inf, so no range prunes it). A
+column of one value a row never sets bit1, so its bytes are as before.
   payload streams...
   u32 crc32 (over everything before the trailer)
 """
@@ -53,6 +58,7 @@ _DTYPE_CODES: dict[np.dtype, int] = {
 _CODE_DTYPES = {v: k for k, v in _DTYPE_CODES.items()}
 
 DEFAULT_BLOCK_ROWS = 16384
+_ROW_WIDTH = 2  # ColumnDesc flag: `for_min` holds the values of one row
 
 
 @dataclass(frozen=True)
@@ -79,7 +85,11 @@ def write_block(
     for i, (a, valid) in enumerate(zip(columns, valids)):
         a = np.ascontiguousarray(a)
         hint = hints[i] if hints is not None else None
-        if a.dtype == np.bool_:
+        if a.ndim == 2:
+            e, params = enc.ENC_RAW, {"min": a.shape[1]}
+            data = a.tobytes()
+            zones.append(ColumnZone(-np.inf, np.inf))
+        elif a.dtype == np.bool_:
             a8 = a.astype(np.int8)
             stats = enc.analyze_ints(a8)
             picked = enc.hinted_encoding(a8, stats, hint) if hint else None
@@ -105,7 +115,7 @@ def write_block(
             (
                 e,
                 _DTYPE_CODES[a.dtype if a.dtype != np.bool_ else np.dtype(np.int8)],
-                1 if has_nulls else 0,
+                (1 if has_nulls else 0) | (_ROW_WIDTH if a.ndim == 2 else 0),
                 params.get("width", 0),
                 params.get("min", 0),
                 pos,
@@ -183,13 +193,18 @@ class BlockReader:
         return BlockReader(mv, nrows, ncols, descs, off)
 
     def column(self, i: int, as_bool: bool = False) -> tuple[np.ndarray, np.ndarray | None]:
-        """Decode column i -> (values, validity-or-None)."""
+        """Decode column i -> (values, validity-or-None); a column of
+        fixed-width rows comes back `(nrows, width)`."""
         (e, dcode, flags, width, vmin, doff, dlen, noff, nlen) = self._descs[i]
         dtype = _CODE_DTYPES[dcode]
         start = self._payload_off + doff
         data = self.buf[start : start + dlen]
-        params = {"min": vmin, "width": width} if e == enc.ENC_FOR else {}
-        vals = enc.decode_column(data, e, params, dtype, self.nrows)
+        if flags & _ROW_WIDTH:
+            vals = enc.decode_column(data, e, {}, dtype, self.nrows * vmin)
+            vals = vals.reshape(self.nrows, vmin)
+        else:
+            params = {"min": vmin, "width": width} if e == enc.ENC_FOR else {}
+            vals = enc.decode_column(data, e, params, dtype, self.nrows)
         if as_bool:
             vals = vals.astype(np.bool_)
         valid = None

@@ -7,6 +7,8 @@ and delete tombstones, major sstables one flattened version per key
 (storage/compaction). The rebuild stores:
 
   * rows sorted by rowkey, chunked into micro blocks (microblock.py);
+  * a VECTOR(d) column as `(rows, d)` float32, row after row, without a
+    zone map (microblock.py);
   * two hidden columns: __version (commit version of the row) and __op
     (0 = PUT, 1 = DELETE tombstone) — the multi-version/tombstone model;
   * a footer-addressed block index: per block {offset, len, nrows, end key}
@@ -26,7 +28,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from ..core.dtypes import Schema
+from ..core.dtypes import Schema, TypeKind
 from . import encoding as enc
 from .microblock import DEFAULT_BLOCK_ROWS, BlockReader, write_block
 
@@ -233,6 +235,10 @@ class SSTable:
         self._col_dtype = {n: schema[n].storage_np for n in schema.names()}
         self._col_dtype[VERSION_COL] = np.dtype(np.int64)
         self._col_dtype[OP_COL] = np.dtype(np.int8)
+        # the empty read of a VECTOR(d) column keeps its row shape
+        self._col_shape = {f.name: (0, int(f.dtype.precision))
+                           for f in schema.fields
+                           if f.dtype.kind is TypeKind.VECTOR}
 
     # Checkpoint serialization (storage/slog_ckpt analog): persist the raw
     # blob only — memoryviews/np views/cache are rebuilt by __init__; the
@@ -295,7 +301,8 @@ class SSTable:
                     self.cache.put((self.uid, int(b), c), vals)
                 parts[c].append(vals)
         return {
-            c: (np.concatenate(v) if v else np.zeros(0, dtype=self._col_dtype[c]))
+            c: (np.concatenate(v) if v else np.zeros(
+                self._col_shape.get(c, 0), dtype=self._col_dtype[c]))
             for c, v in parts.items()
         }
 

@@ -155,7 +155,9 @@ class Tablet:
         rows = np.flatnonzero(kmask)
         if len(rows):
             r = rows[0]
-            return (0, tuple(got[n][r] for n in names))
+            # a VECTOR cell as the memtable holds it: a tuple of floats
+            return (0, tuple(tuple(got[n][r].tolist()) if got[n].ndim == 2
+                             else got[n][r] for n in names))
         return None
 
     # ---------------------------------------------------- LSM maintenance

@@ -140,10 +140,67 @@ def test_build_ivf_structure():
     assert idx.centroids.shape == (16, 8)
     assert sorted(idx.perm.tolist()) == list(range(1000))
     assert int(idx.lengths.sum()) == 1000
-    assert idx.max_list == int(idx.lengths.max())
+    # the read window: the longest list rounded up to 2^k or 3 x 2^(k-1)
+    longest = int(idx.lengths.max())
+    assert longest <= idx.max_list < longest * 4 // 3 + 1
+    assert idx.max_list in {1 << k for k in range(12)} | {
+        3 << k for k in range(11)}
     # offsets delimit the lists
     ends = idx.offsets + idx.lengths
     assert int(ends.max()) == 1000
+
+
+def test_cap_lists_moves_rows_to_the_nearest_list_with_room():
+    from oceanbase_tpu.storage.vector_index import _cap_lists
+
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(600, 8)).astype(np.float32)
+    cent = rng.normal(size=(6, 8)).astype(np.float32)
+    d2 = ((x[:, None, :] - cent[None, :, :]) ** 2).sum(axis=2)
+    a0 = np.argmin(d2, axis=1)
+    cap = 110
+    assert np.bincount(a0, minlength=6).max() > cap
+    a = _cap_lists(x, cent, a0, cap)
+    counts = np.bincount(a, minlength=6)
+    assert counts.max() <= cap and counts.sum() == 600
+    own = d2[np.arange(600), a]
+    for r in range(600):
+        # a row is away from a nearer list only where that list is full
+        nearer = np.flatnonzero(d2[r] < own[r] - 1e-4)
+        assert (counts[nearer] == cap).all()
+    # an over-full list kept the rows nearest its centroid
+    for li in np.flatnonzero(np.bincount(a0, minlength=6) > cap):
+        kept = d2[a == li, li]
+        gone = d2[(a0 == li) & (a != li), li]
+        assert kept.max() <= gone.min() + 1e-4
+
+
+def test_build_ivf_caps_lists(monkeypatch):
+    """A capped index: no list over the cap, every row in one list, and
+    the probe answers the nearest rows of the lists it probes."""
+    from oceanbase_tpu.storage import vector_index
+
+    monkeypatch.setattr(vector_index, "LIST_CAP_FACTOR", 1)
+    cat, x, rng = _vec_table(n=3000)
+    idx = build_ivf(x, lists=16)
+    cap = vector_index._list_cap(3000, 16)
+    assert int(idx.lengths.max()) <= cap
+    assert idx.max_list <= cap
+    assert sorted(idx.perm.tolist()) == list(range(3000))
+    register_vector_index(cat, "docs", "emb", lists=16, nprobe=8)
+    sess = Session(cat)
+    c = idx.centroids
+    for _ in range(5):
+        q = x[rng.integers(0, len(x))] + 0.05
+        rs = sess.sql(_qtext(q, 5))
+        entry, _ = sess.cached_entry(_qtext(q, 5))
+        assert entry.prepared.params.vector_topns, "ANN path did not engage"
+        probes = np.argsort((c * c).sum(axis=1) - 2.0 * (c @ q))[:8]
+        rows = np.concatenate([
+            idx.perm[idx.offsets[p]:idx.offsets[p] + idx.lengths[p]]
+            for p in probes])
+        want = rows[_exact(x[rows], q, 5)]
+        assert {int(v) for v in rs.columns["id"]} == {int(v) for v in want}
 
 
 def test_server_ddl_and_query():

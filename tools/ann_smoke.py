@@ -70,12 +70,11 @@ def _qtext(q, where=""):
 
 
 def build_db(n: int):
-    """Preloaded docs table (clustered embeddings + a selectivity
-    column) with a registered IVF index, on a 1-node Database."""
-    from oceanbase_tpu.core.dtypes import DataType, Field, Schema, TypeKind
-    from oceanbase_tpu.core.table import Table
+    """The docs table (clustered embeddings + a selectivity column) on a
+    1-node Database, as a deployment makes it: DDL, direct_load, CREATE
+    VECTOR INDEX."""
     from oceanbase_tpu.server.database import Database
-    from oceanbase_tpu.storage.vector_index import register_vector_index
+    from oceanbase_tpu.server.direct_load import direct_load
 
     rng = np.random.default_rng(11)
     centers = rng.normal(size=(LISTS, D)).astype(np.float32) * 4
@@ -83,16 +82,13 @@ def build_db(n: int):
          + rng.normal(size=(n, D)).astype(np.float32))
     grp = np.arange(n, dtype=np.int64) % 10
     db = Database(n_nodes=1, n_ls=1)
-    db.catalog["docs"] = Table("docs", Schema((
-        Field("id", DataType(TypeKind.INT64)),
-        Field("grp", DataType(TypeKind.INT64)),
-        Field("emb", DataType.vector(D)),
-    )), {"id": np.arange(n, dtype=np.int64), "grp": grp, "emb": x})
-    # preloaded read-only table: register the index spec directly (the
-    # DDL path wants a served table; the advisor leg covers that flow)
-    db._vector_specs.setdefault("docs", {})["emb"] = (LISTS, NPROBE)
-    register_vector_index(db.catalog, "docs", "emb",
-                          lists=LISTS, nprobe=NPROBE)
+    s = db.session()
+    s.sql(f"create table docs (id bigint primary key, grp bigint, "
+          f"emb vector({D}))")
+    direct_load(db, "docs", {"id": np.arange(n, dtype=np.int64),
+                             "grp": grp, "emb": x})
+    s.sql(f"create vector index ix on docs (emb) "
+          f"with (lists = {LISTS}, nprobe = {NPROBE})")
     queries = (x[rng.integers(0, n, 64)]
                + rng.normal(size=(64, D)).astype(np.float32) * 0.05)
     return db, x, grp, queries
